@@ -34,6 +34,7 @@ import numpy as np
 from repro.core import isa
 from repro.core.cycle_model import FPGA_CLOCK_HZ
 from repro.core.network_compiler import compile_network
+from repro.kernels.compile_cache import enable_compile_cache
 from repro.models.cifar_cnn import (calibrate_shifts,
                                     cifar_cnn_random_weights,
                                     cifar_cnn_specs, reference_forward_int8,
@@ -65,6 +66,7 @@ def main():
     if args.batch > 1 and args.backend != "fast":
         ap.error("--batch > 1 runs the batched engine; "
                  "--backend oracle is per-image only (use --batch 1)")
+    enable_compile_cache()
 
     weights = cifar_cnn_random_weights(seed=0)
     print("calibrating static requant shifts (§4.2)...")

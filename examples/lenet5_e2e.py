@@ -17,9 +17,10 @@ compiler pipeline with batched requests — the paper's own workload (§4.3).
 ``--backend fast`` (the default) serves on the vectorised plan-compiling
 simulator; ``--backend oracle`` uses the per-struct reference interpreter
 (per-image serving only); ``--backend pallas`` lowers each layer to the
-``vta_gemm`` MXU kernel (``interpret=True`` off-TPU, and batched serving
-via ``--batch``).  All paths are bit-exact — batching just gets there
-sooner (EXPERIMENTS.md §Serving).
+``vta_gemm`` MXU kernel (compiled on a TPU, interpreted on the CPU; batched
+serving via ``--batch``).  All paths are bit-exact — batching just gets
+there sooner (EXPERIMENTS.md §Serving).  JAX's compile cache lives where
+``JAX_COMPILATION_CACHE_DIR`` says, else in ``.jax_cache/``.
 """
 
 import argparse
@@ -29,6 +30,7 @@ import numpy as np
 
 from repro.core.cycle_model import FPGA_CLOCK_HZ
 from repro.core.network_compiler import compile_network
+from repro.kernels.compile_cache import enable_compile_cache
 from repro.models.lenet import (lenet5_random_weights, lenet5_specs,
                                 reference_forward_float,
                                 reference_forward_int8)
@@ -56,6 +58,7 @@ def main():
     if args.batch > 1 and args.backend == "oracle":
         ap.error("--batch > 1 runs the batched engine; "
                  "--backend oracle is per-image only (use --batch 1)")
+    enable_compile_cache()
 
     weights = lenet5_random_weights(seed=0)
     print("compiling LeNet-5 through the VTA pipeline...")

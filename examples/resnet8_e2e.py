@@ -22,8 +22,8 @@ a 1×1 mixing conv — ADD-pair rounds + one SHR, all on the TensorAlu.
                                                   [--skip-oracle]
 
 ``--backend pallas`` runs every layer through the ``vta_gemm`` MXU kernel
-(``interpret=True`` off-TPU) — residual joins, strided chunks and the GAP
-head all execute bit-identically to the simulators.
+(compiled on a TPU, interpreted on the CPU) — residual joins, strided
+chunks and the GAP head all execute bit-identically to the simulators.
 """
 
 import argparse
@@ -32,6 +32,7 @@ import time
 import numpy as np
 
 from repro.core import isa
+from repro.kernels.compile_cache import enable_compile_cache
 from repro.models.resnet8 import (compile_resnet8, reference_forward_int8,
                                   synthetic_image)
 
@@ -68,6 +69,7 @@ def main():
     if args.batch > 1 and args.backend == "oracle":
         ap.error("--batch > 1 runs the batched engine; "
                  "--backend oracle is per-image only (use --batch 1)")
+    enable_compile_cache()
 
     print("calibrating weight scales + requant shifts, compiling the "
           "resnet8 DAG...")

@@ -28,6 +28,7 @@ import time
 import numpy as np
 
 from repro.core import isa
+from repro.kernels.compile_cache import enable_compile_cache
 from repro.models.resnet_tiny import (compile_resnet_tiny,
                                       reference_forward_int8,
                                       synthetic_image)
@@ -62,6 +63,7 @@ def main():
     if args.batch > 1 and args.backend != "fast":
         ap.error("--batch > 1 runs the batched engine; "
                  "--backend oracle is per-image only (use --batch 1)")
+    enable_compile_cache()
 
     print("calibrating weight scales + requant shifts, compiling the "
           "resnet_tiny DAG...")

@@ -28,6 +28,7 @@ import sys
 import numpy as np
 
 from repro.core.network_compiler import compile_network
+from repro.kernels.compile_cache import enable_compile_cache
 from repro.models.lenet import (lenet5_random_weights, lenet5_specs,
                                 synthetic_digit)
 from repro.serving.vta import (BatchPolicy, QueueFull, VTAServingEngine,
@@ -52,6 +53,7 @@ def main():
                          "(batched workers only)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("compiling LeNet-5 through the VTA pipeline...")
     net = compile_network(lenet5_specs(lenet5_random_weights(0)),

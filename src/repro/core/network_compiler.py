@@ -391,7 +391,7 @@ class NetworkProgram:
         ``backend="batched"`` (default) runs the vectorised instruction
         interpreter; ``backend="pallas"`` executes each layer as a fused
         MXU kernel call over the whole stack
-        (:mod:`repro.core.pallas_backend`, ``interpret=True`` off-TPU) —
+        (:mod:`repro.core.pallas_backend`; interpreted only on the CPU) —
         bit-identical to the simulators on its truncation path.
 
         Returns ``(stacked outputs, per-layer batch-total reports)``: the

@@ -3,8 +3,9 @@
 The fourth backend (DESIGN.md §2): where ``oracle``/``fast``/``batched``
 *interpret* the instruction stream, this backend executes the *semantics* a
 compiled :class:`~repro.core.program.VTAProgram` encodes — one
-``kernels.vta_gemm`` MXU call per program (``interpret=True`` off-TPU, so
-CPU-only CI runs the same kernel body) plus a bit-exact TensorAlu epilogue —
+``kernels.vta_gemm`` MXU call per program (compiled on the TPU; interpreted
+on the CPU the tests run on, so they run the same kernel body) plus a
+bit-exact TensorAlu epilogue —
 and commits the result to the same DRAM OUT region the simulators write.
 Because it reads the INP/WGT/ACC/RES segments and writes OUT bytes through
 the §3.2 layout (block-major vectors), it is a drop-in
@@ -155,6 +156,50 @@ def plan_pallas(prog) -> PallasPlan:
              if "res" in prog.regions else None))
     prog._pallas_plan = plan
     return plan
+
+
+@dataclasses.dataclass(frozen=True)
+class KernelCall:
+    """The unpadded operands and epilogue of one ``ops.vta_matmul`` call."""
+
+    m: int
+    k: int
+    n: int
+    bias: bool
+    relu: bool
+    shift: int
+    out_dtype: object
+
+    def vta_gemm_args(self, sharding=None):
+        """``(operand shapes, static kwargs)`` of the padded ``vta_gemm``
+        call ``ops.vta_matmul`` makes for this call (serving's truncating
+        commit, ``interpret`` left to the caller) — what ``vta_gemm.lower``
+        takes."""
+        from repro.kernels.ops import gemm_blocks
+        g = gemm_blocks(self.m, self.k, self.n)
+        shapes = [((g.m, g.k), jnp.int8), ((g.k, g.n), jnp.int8),
+                  ((g.n,), jnp.int32) if self.bias else None]
+        args = [jax.ShapeDtypeStruct(*s, sharding=sharding) if s else None
+                for s in shapes]
+        statics = dict(relu=self.relu, shift=self.shift, saturate=False,
+                       out_dtype=self.out_dtype, block_m=g.block_m,
+                       block_n=g.block_n, block_k=g.block_k)
+        return args, statics
+
+
+def kernel_call(p: PallasPlan, batch: int) -> KernelCall:
+    """The kernel call serving makes for ``p`` over a stack of ``batch``
+    compiled images (shared weights, broadcast bias, zero pad rows): the
+    whole program inside the kernel when its epilogue fuses, else the bare
+    int32 GEMM that :func:`apply_alu_epilogue` finishes.  The chip-compile
+    rehearsal compiles exactly these calls."""
+    mp, np_ = p.padded_shape
+    shape = dict(m=batch * mp, k=p.lam * p.block_size, n=np_)
+    if p.fused:
+        return KernelCall(**shape, bias=p.acc is not None, relu=p.relu,
+                          shift=p.shift, out_dtype=jnp.int8)
+    return KernelCall(**shape, bias=False, relu=False, shift=0,
+                      out_dtype=jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -331,9 +376,9 @@ def _kernel_gemm(a: np.ndarray, b: np.ndarray, bias: Optional[np.ndarray],
                  *, relu: bool, shift: int, saturate: bool, out_dtype,
                  gemm_backend: str) -> np.ndarray:
     """One fused-kernel call (the MXU leg).  ``gemm_backend`` is forwarded
-    to ``ops.vta_matmul``: "pallas" runs the real kernel (interpret mode
-    off-TPU), "xla" the semantically identical lowered reference, "auto"
-    picks per platform."""
+    to ``ops.vta_matmul``: "pallas" (what serving uses) runs the real
+    kernel, compiled on the TPU and interpreted only on the CPU; "xla" the
+    semantically identical lowered reference; "auto" picks per platform."""
     from repro.kernels import ops as kernel_ops
     out = kernel_ops.vta_matmul(
         jnp.asarray(a), jnp.asarray(b),

@@ -497,7 +497,7 @@ def make_simulator(cfg: VTAConfig, dram: np.ndarray, *,
     stream once over all images (DESIGN.md §Batching), bit-identical to
     looping ``"oracle"`` over the stack's rows.  ``"pallas"`` executes
     compiled programs as fused MXU kernel calls
-    (:mod:`repro.core.pallas_backend`, ``interpret=True`` off-TPU) —
+    (:mod:`repro.core.pallas_backend`; interpreted only on the CPU) —
     bit-identical to the oracle on its default truncation path.
     """
     if backend == "oracle":

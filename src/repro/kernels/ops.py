@@ -1,20 +1,19 @@
 """Jitted public wrappers around the Pallas kernels.
 
-Handles shape padding to block multiples, backend selection (real Pallas on
-TPU, ``interpret=True`` elsewhere — this container is CPU-only so every test
-runs the kernel bodies in interpret mode), and the pure-JAX fallbacks used
-by the dry-run path (XLA lowers those for the roofline analysis; see
-DESIGN.md §2).
+Handles shape padding to block multiples (:func:`gemm_blocks`), the one
+decision of whether a kernel runs compiled or interpreted
+(:func:`pallas_interpret`: compiled on the TPU, interpreted only on the
+CPU the tests run on), and the pure-JAX fallbacks used by the dry-run path
+(XLA lowers those for the roofline analysis; see DESIGN.md §2).
 """
 
 from __future__ import annotations
 
-import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax._src import config as _jax_config
 
 from repro.core.errors import CompileError
 
@@ -35,8 +34,57 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def pallas_interpret() -> bool:
+    """Whether the Pallas kernels run in interpret mode in this process.
+
+    Only on the ``cpu`` platform, which is how the tests run.  On ``tpu``
+    they run compiled.  Any other platform, or a TPU process that forced
+    interpret mode (``pltpu.force_tpu_interpret_mode``), raises: a run
+    that lost its chip must fail, not serve correct bytes from the host."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform != "tpu":
+        raise CompileError(
+            f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+            f"this process's platform is {platform!r}",
+            constraint="pallas-platform")
+    if _jax_config.pallas_tpu_interpret_mode_context_manager.value is not None:
+        raise CompileError(
+            "TPU interpret mode is forced in a process that holds a TPU; "
+            "the kernels must run compiled there",
+            constraint="pallas-interpret-on-tpu")
+    return False
+
+
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+class GemmBlocks(NamedTuple):
+    """Kernel block sizes and the padded operand dims of one GEMM."""
+
+    block_m: int
+    block_k: int
+    block_n: int
+    m: int
+    k: int
+    n: int
+
+
+def gemm_blocks(m: int, k: int, n: int, *, block_m: int = 256,
+                block_n: int = 256, block_k: int = 256) -> GemmBlocks:
+    """Blocks and padding for an int8 ``(m, k) @ (k, n)`` ``vta_gemm`` call.
+
+    M rounds up to int8's 32-row sublane tile, K and N to the 128-wide
+    lanes; each block is capped at the requested size and each dim padded
+    to a whole number of blocks.  A block is then either the requested
+    size or the whole padded dim."""
+    bm = min(block_m, _round_up(m, 32))
+    bk = min(block_k, _round_up(k, 128))
+    bn = min(block_n, _round_up(n, 128))
+    return GemmBlocks(bm, bk, bn,
+                      _round_up(m, bm), _round_up(k, bk), _round_up(n, bn))
 
 
 def vta_matmul(a: jax.Array, b: jax.Array,
@@ -47,9 +95,10 @@ def vta_matmul(a: jax.Array, b: jax.Array,
                backend: str = "auto") -> jax.Array:
     """Fused W8A8 GEMM (the paper's datapath as a TPU feature).
 
-    backend: "pallas" | "xla" | "auto" (pallas on TPU, interpret elsewhere
-    only if explicitly requested — interpret mode is for tests; "auto" off
-    TPU uses the XLA reference, which is semantically identical).
+    backend: "pallas" | "xla" | "auto".  "pallas" runs the kernel, compiled
+    on the TPU and interpreted on the CPU (:func:`pallas_interpret`);
+    "auto" off the TPU uses the XLA reference, which is semantically
+    identical.
     """
     _check_backend(backend)
     m, k = a.shape
@@ -61,17 +110,15 @@ def vta_matmul(a: jax.Array, b: jax.Array,
     if backend == "xla" or (backend == "auto" and not _on_tpu()):
         return _ref.vta_gemm_ref(a, b, bias, relu=relu, shift=shift,
                                  saturate=saturate, out_dtype=out_dtype)
-    interpret = not _on_tpu()
-    bm = min(block_m, _round_up(m, 8))
-    bn = min(block_n, _round_up(n, 128))
-    bk = min(block_k, _round_up(k, 128))
-    mp, kp, np_ = _round_up(m, bm), _round_up(k, bk), _round_up(n, bn)
-    a_p = jnp.pad(a, ((0, mp - m), (0, kp - k)))
-    b_p = jnp.pad(b, ((0, kp - k), (0, np_ - n)))
-    bias_p = (jnp.pad(bias, (0, np_ - n)) if bias is not None else None)
+    g = gemm_blocks(m, k, n, block_m=block_m, block_n=block_n,
+                    block_k=block_k)
+    a_p = jnp.pad(a, ((0, g.m - m), (0, g.k - k)))
+    b_p = jnp.pad(b, ((0, g.k - k), (0, g.n - n)))
+    bias_p = (jnp.pad(bias, (0, g.n - n)) if bias is not None else None)
     out = _vta_gemm(a_p, b_p, bias_p, relu=relu, shift=shift,
                     saturate=saturate, out_dtype=out_dtype,
-                    block_m=bm, block_n=bn, block_k=bk, interpret=interpret)
+                    block_m=g.block_m, block_n=g.block_n, block_k=g.block_k,
+                    interpret=pallas_interpret())
     return out[:m, :n]
 
 
@@ -87,7 +134,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if backend == "xla" or (backend == "auto" and not _on_tpu()):
         return _ref.attention_ref(q, k, v, causal=causal, sm_scale=sm_scale,
                                   window=window, q_offset=q_offset)
-    interpret = not _on_tpu()
+    interpret = pallas_interpret()
     bq = min(block_q, _round_up(sq, 8))
     bk = min(block_k, _round_up(skv, 8))
     sq_p, skv_p = _round_up(sq, bq), _round_up(skv, bk)
@@ -101,33 +148,10 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def vta_matmul_pallas(a, b, bias=None, **kw):
-    """Force the Pallas path (interpret off-TPU) — used by kernel tests."""
-    kw.setdefault("backend", "pallas")
-    m, k = a.shape
-    _, n = b.shape
-    bm = min(kw.pop("block_m", 256), _round_up(m, 8))
-    bn = min(kw.pop("block_n", 256), _round_up(n, 128))
-    bk = min(kw.pop("block_k", 256), _round_up(k, 128))
-    kw.pop("backend")
-    mp, kp, np_ = _round_up(m, bm), _round_up(k, bk), _round_up(n, bn)
-    a_p = jnp.pad(a, ((0, mp - m), (0, kp - k)))
-    b_p = jnp.pad(b, ((0, kp - k), (0, np_ - n)))
-    bias_p = (jnp.pad(bias, (0, np_ - n)) if bias is not None else None)
-    out = _vta_gemm(a_p, b_p, bias_p, block_m=bm, block_n=bn, block_k=bk,
-                    interpret=not _on_tpu(), **kw)
-    return out[:m, :n]
+    """Force the Pallas path (interpreted on the CPU) — used by kernel tests."""
+    return vta_matmul(a, b, bias, backend="pallas", **kw)
 
 
 def attention_pallas(q, k, v, **kw):
-    """Force the Pallas path (interpret off-TPU) — used by kernel tests."""
-    b, h, sq, d = q.shape
-    _, hkv, skv, _ = k.shape
-    bq = min(kw.pop("block_q", 128), _round_up(sq, 8))
-    bk = min(kw.pop("block_k", 128), _round_up(skv, 8))
-    sq_p, skv_p = _round_up(sq, bq), _round_up(skv, bk)
-    q_p = jnp.pad(q, ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
-    k_p = jnp.pad(k, ((0, 0), (0, 0), (0, skv_p - skv), (0, 0)))
-    v_p = jnp.pad(v, ((0, 0), (0, 0), (0, skv_p - skv), (0, 0)))
-    out = _flash(q_p, k_p, v_p, block_q=bq, block_k=bk,
-                 interpret=not _on_tpu(), **kw)
-    return out[:, :, :sq, :]
+    """Force the Pallas path (interpreted on the CPU) — used by kernel tests."""
+    return attention(q, k, v, backend="pallas", **kw)

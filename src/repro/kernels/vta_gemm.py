@@ -37,10 +37,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.errors import CompileError
 
-# jax 0.4.x exposes this as TPUCompilerParams; newer releases renamed it.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _gemm_kernel(a_ref, b_ref, bias_ref, out_ref, acc_ref, *,
                  n_k: int, relu: bool, shift: int, saturate: bool,
@@ -133,7 +129,7 @@ def vta_gemm(a: jax.Array, b: jax.Array,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

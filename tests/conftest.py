@@ -15,3 +15,16 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if "hypothesis" in item.keywords:
             item.add_marker(pytest.mark.fuzz)
+
+
+@pytest.fixture(scope="session")
+def chip_smoke():
+    """The repo-root ``chip_smoke.py`` script, imported as a module (its
+    entry point runs only under ``__main__``)."""
+    import importlib.util
+    from pathlib import Path
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module

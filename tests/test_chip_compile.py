@@ -1,0 +1,102 @@
+"""Compile the serving path's ``vta_gemm`` calls for a TPU v5e, no chip
+attached.
+
+Every kernel call ``NetworkProgram.serve(backend="pallas")`` makes for
+lenet5 and resnet8 at batch-ladder rungs 1, 4 and 8 is compiled with
+``interpret=False`` for a described ``v5e:2x2`` topology: what the chip's
+compiler would refuse (a misaligned block, too much VMEM) fails here at no
+chip time.  The shapes come from ``plan_pallas`` → ``kernel_call`` →
+``gemm_blocks``; ``test_rehearsal_calls_are_what_serving_runs`` pins that
+serving makes exactly those calls.  The topology is described inside a
+fixture, never at import, so every test worker collects the same tests.
+"""
+
+import jax
+import pytest
+
+from repro.core.pallas_backend import kernel_call, plan_pallas
+from repro.kernels import ops
+from repro.kernels.vta_gemm import vta_gemm
+
+NETS = ("lenet5", "resnet8")
+RUNGS = (1, 4, 8)           # the ladder rungs chip_smoke.py serves at
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep the cache off meanwhile."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def topo(no_persistent_cache):
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def nets(chip_smoke):
+    """The nets ``chip_smoke.py`` serves: (net, 16 request images)."""
+    return {name: getattr(chip_smoke, name)()[:2] for name in NETS}
+
+
+def _kernel_calls(net, rung):
+    """The distinct ``vta_gemm`` calls a pallas serve of ``rung`` images
+    makes: ``{(operand shapes, static kwargs)}``."""
+    calls = set()
+    for layer in net.layers:
+        args, statics = kernel_call(plan_pallas(layer.program),
+                                    rung).vta_gemm_args()
+        shapes = tuple(a.shape if a is not None else None for a in args)
+        calls.add((shapes, frozenset(statics.items())))
+    return calls
+
+
+@pytest.mark.parametrize("rung", RUNGS)
+@pytest.mark.parametrize("net_name", NETS)
+def test_vta_gemm_compiles_for_v5e(net_name, rung, nets, one_chip):
+    net, _ = nets[net_name]
+    calls = {kernel_call(plan_pallas(layer.program), rung)
+             for layer in net.layers}
+    for call in calls:
+        args, statics = call.vta_gemm_args(sharding=one_chip)
+        compiled = vta_gemm.lower(*args, interpret=False,
+                                  **statics).compile()
+        assert "tpu_custom_call" in compiled.as_text(), call
+
+
+@pytest.mark.parametrize("net_name", NETS)
+def test_rehearsal_calls_are_what_serving_runs(net_name, nets, monkeypatch):
+    """Record every kernel call of real pallas serves (interpreted on the
+    CPU) and compare with what the compile test above compiles."""
+    net, images = nets[net_name]
+    seen = set()
+    real = ops._vta_gemm
+
+    def spy(a, b, bias, **kw):
+        kw.pop("interpret")
+        seen.add(((a.shape, b.shape, None if bias is None else bias.shape),
+                  frozenset(kw.items())))
+        return real(a, b, bias, interpret=True, **kw)
+
+    monkeypatch.setattr(ops, "_vta_gemm", spy)
+    for rung in RUNGS:
+        net.serve(images[:rung], backend="pallas")
+    assert seen == set().union(*(_kernel_calls(net, r) for r in RUNGS))
