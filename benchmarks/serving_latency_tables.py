@@ -14,8 +14,8 @@ For lenet5 and resnet8 on the batched backend:
    outputs for a seeded request set must equal a direct
    ``NetworkProgram.serve`` of the same images bit-for-bit;
 4. ``servelat/<net>/deterministic_replay`` (EXACT): two same-seed
-   virtual-clock runs must produce identical request traces and latency
-   histograms.
+   virtual-clock runs must produce identical request traces and
+   summaries.
 
 ``SERVING_CAMPAIGN_N`` scales the per-load request count (default 200;
 CI smoke runs a small N).  Timing-derived rows are reported, not gated —
@@ -109,11 +109,8 @@ def _deterministic_replay(net, model, policy, slo_s, n) -> str:
                                         / model.service_s(MAX_BATCH),
                                         n, seed=42),
                           policy, model, workers=WORKERS, slo_s=slo_s)
-        runs.append((result.trace(),
-                     result.metrics.latency_histogram(),
-                     result.metrics.summary()))
-    same = (runs[0][0] == runs[1][0] and runs[0][1] == runs[1][1]
-            and runs[0][2] == runs[1][2])
+        runs.append((result.trace(), result.metrics.summary()))
+    same = runs[0] == runs[1]
     return "PASS" if same else "FAIL"
 
 
@@ -165,5 +162,5 @@ def all_tables(data: Dict) -> List[Dict]:
         rows.append({"name": f"servelat/{tag}/deterministic_replay",
                      "value": entry["deterministic_replay"],
                      "paper": "PASS",
-                     "note": "same seed => identical trace+histogram"})
+                     "note": "same seed => identical trace+summary"})
     return rows

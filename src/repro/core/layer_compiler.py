@@ -102,6 +102,13 @@ class CompiledLayer:
         return self.program.gemm_loops()
 
     @property
+    def macs(self) -> int:
+        """Multiply-accumulates of one image's GEMM at its valid shape
+        (M·K·N, M counted before any pooling)."""
+        m, k = self.input_matrix.shape
+        return m * k * self.weight_matrix.shape[1]
+
+    @property
     def n_chunks(self) -> int:
         """SRAM chunks the layer's GEMM was tiled into (§3.3 repetition)."""
         plan = self.program.chunk_plan

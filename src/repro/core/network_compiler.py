@@ -38,6 +38,7 @@ from .layout import (batch_matrix_to_binary, matrix_to_binary,
                      should_pad_height)
 from .simulator import (SimReport, decode_out_region, decode_out_region_batch,
                         make_simulator, run_instructions)
+from repro.trace import span
 
 # The real backend sets, enumerated once so refusal messages, the serving
 # engine (repro.serving.vta) and the tests never drift out of sync again:
@@ -419,41 +420,56 @@ class NetworkProgram:
                 f"serve_one()'s), got {backend!r}",
                 constraint="serve-backend")
         imgs = self._as_image_list(images)
+        rows = len(imgs)
+        with span("vta.serve", rows=rows):
+            return self._serve_stack(imgs, backend, fault_hook,
+                                     count_overflows)
+
+    def _serve_stack(self, imgs: List[np.ndarray], backend: str, fault_hook,
+                     count_overflows: bool):
+        """:meth:`serve`'s layer loop over one ``(batch, nbytes)`` stack,
+        with a span at each boundary (``vta.layer`` > ``vta.stage``, the
+        backend's own spans, ``vta.readout``)."""
         from .fast_simulator import BatchFastSimulator, plan_for
-        base = self.dram_image()
-        stack = np.broadcast_to(base, (len(imgs), base.size)).copy()
-        self._stage_layer_input_batch(stack, self.layers[0], imgs)
+        rows = len(imgs)
+        with span("vta.stage"):
+            base = self.dram_image()
+            stack = np.broadcast_to(base, (rows, base.size)).copy()
         reports: List[SimReport] = []
         all_sems: List[List[np.ndarray]] = []   # per layer, per request
         srcs, rsrcs = self._sources(), self._res_sources()
         for k, layer in enumerate(self.layers):
-            if k > 0:
-                src_sems = imgs if srcs[k] < 0 else all_sems[srcs[k]]
-                self._stage_layer_input_batch(stack, layer, src_sems)
-            if rsrcs[k] is not None:
-                res_sems = imgs if rsrcs[k] < 0 else all_sems[rsrcs[k]]
-                self._stage_residual_batch(stack, layer, res_sems)
-            # the loop owns ``stack`` and re-reads it from ``sim.dram``, so
-            # the engine's defensive copy is skipped
-            if backend == "pallas":
-                from .pallas_backend import BatchPallasSimulator
-                sim = BatchPallasSimulator(self.config, stack,
-                                           copy_dram=False)
-                reports.append(sim.run_program(
-                    layer.program,
-                    fault_hook=self._layer_hook(fault_hook, k)))
-            else:
-                sim = BatchFastSimulator(self.config, stack,
-                                         copy_dram=False,
-                                         count_overflows=count_overflows)
-                reports.append(sim.run(layer.program.instructions,
-                                       plan=plan_for(layer.program),
-                                       fault_hook=self._layer_hook(
-                                           fault_hook, k)))
-            stack = sim.dram
-            out_mats = decode_out_region_batch(layer.program, stack)
-            all_sems.append([decode_layer_output(layer, m)
-                             for m in out_mats])
+            with span("vta.layer", layer=k, useful_macs=rows * layer.macs):
+                with span("vta.stage"):
+                    src_sems = (imgs if k == 0 or srcs[k] < 0
+                                else all_sems[srcs[k]])
+                    self._stage_layer_input_batch(stack, layer, src_sems)
+                    if rsrcs[k] is not None:
+                        res_sems = (imgs if rsrcs[k] < 0
+                                    else all_sems[rsrcs[k]])
+                        self._stage_residual_batch(stack, layer, res_sems)
+                # the loop owns ``stack`` and re-reads it from ``sim.dram``,
+                # so the engine's defensive copy is skipped
+                if backend == "pallas":
+                    from .pallas_backend import BatchPallasSimulator
+                    sim = BatchPallasSimulator(self.config, stack,
+                                               copy_dram=False)
+                    reports.append(sim.run_program(
+                        layer.program,
+                        fault_hook=self._layer_hook(fault_hook, k)))
+                else:
+                    sim = BatchFastSimulator(self.config, stack,
+                                             copy_dram=False,
+                                             count_overflows=count_overflows)
+                    reports.append(sim.run(layer.program.instructions,
+                                           plan=plan_for(layer.program),
+                                           fault_hook=self._layer_hook(
+                                               fault_hook, k)))
+                stack = sim.dram
+                with span("vta.readout"):
+                    out_mats = decode_out_region_batch(layer.program, stack)
+                    all_sems.append([decode_layer_output(layer, m)
+                                     for m in out_mats])
         return np.stack(all_sems[-1]), reports
 
 

@@ -45,6 +45,7 @@ from .gemm_compiler import (AluImmOp, AluIndexedImmOp, AluPairOp,
 from .hwconfig import VTAConfig
 from .layout import truncate_int8
 from .simulator import SimReport
+from repro.trace import span
 
 try:  # jax + the kernels layer are optional at import time (clean skips)
     import jax  # noqa: F401
@@ -380,12 +381,15 @@ def _kernel_gemm(a: np.ndarray, b: np.ndarray, bias: Optional[np.ndarray],
     kernel, compiled on the TPU and interpreted only on the CPU; "xla" the
     semantically identical lowered reference; "auto" picks per platform."""
     from repro.kernels import ops as kernel_ops
-    out = kernel_ops.vta_matmul(
-        jnp.asarray(a), jnp.asarray(b),
-        jnp.asarray(bias) if bias is not None else None,
-        relu=relu, shift=shift, saturate=saturate, out_dtype=out_dtype,
-        backend=gemm_backend)
-    return np.array(out)          # writable copy (jax buffers are read-only)
+    with span("vta.kernel"):
+        with span("vta.kernel.put"):
+            operands = (jnp.asarray(a), jnp.asarray(b),
+                        jnp.asarray(bias) if bias is not None else None)
+        out = kernel_ops.vta_matmul(
+            *operands, relu=relu, shift=shift, saturate=saturate,
+            out_dtype=out_dtype, backend=gemm_backend)
+        with span("vta.kernel.fetch"):
+            return np.array(out)  # writable copy (jax buffers are read-only)
 
 
 def _commit_int8(acc: np.ndarray, saturate: bool) -> np.ndarray:
@@ -405,35 +409,36 @@ def _execute_stack(prog, stack: np.ndarray, *, saturate: bool,
     b = stack.shape[0]
     mp, np_ = p.padded_shape
     m, n = p.valid_shape
-    a = _decode_inp(stack, p)                       # (B, Mp, Kp)
-    w = _decode_wgt(stack, p)                       # (B, Kp, Np)
-    x = _decode_acc32(stack, p, p.acc) if p.acc else None
-    res = _decode_acc32(stack, p, p.res) if p.res else None
-    uniform_w = b == 1 or bool((w == w[0]).all())
+    with span("vta.decode"):
+        a = _decode_inp(stack, p)                   # (B, Mp, Kp)
+        w = _decode_wgt(stack, p)                   # (B, Kp, Np)
+        x = _decode_acc32(stack, p, p.acc) if p.acc else None
+        res = _decode_acc32(stack, p, p.res) if p.res else None
+        uniform_w = b == 1 or bool((w == w[0]).all())
 
-    # A row-broadcast preload (the bias form every compiled layer uses)
-    # fuses into the kernel.  The kernel broadcasts the bias to *every*
-    # row including the §3.2 padding rows, where the oracle adds the
-    # stored X pad rows instead — fusing therefore also requires A's pad
-    # rows to be zero (true for every compiled image; the conformance
-    # fuzz violates it with random bytes and takes the general path), so
-    # the pad rows' oracle value is exactly 0 and can be committed
-    # directly.  Pad *columns* need no special-casing in either form:
-    # the kernel computes them from the same decoded WGT/bias bytes the
-    # oracle reads.
-    bias = None
-    fuse_bias = x is None
-    if x is not None and p.fused:
-        rows_equal = bool((x[:, :m] == x[:, :1]).all())
-        x_pad_zero = bool((x[:, m:] == 0).all())
-        a_pad_zero = bool((a[:, m:] == 0).all())
-        if rows_equal and x_pad_zero and a_pad_zero:
-            bias, fuse_bias = x[:, 0], True
+        # A row-broadcast preload (the bias form every compiled layer
+        # uses) fuses into the kernel.  The kernel broadcasts the bias to
+        # *every* row including the §3.2 padding rows, where the oracle
+        # adds the stored X pad rows instead — fusing therefore also
+        # requires A's pad rows to be zero (true for every compiled image;
+        # the conformance fuzz violates it with random bytes and takes the
+        # general path), so the pad rows' oracle value is exactly 0 and
+        # can be committed directly.  Pad *columns* need no special-casing
+        # in either form: the kernel computes them from the same decoded
+        # WGT/bias bytes the oracle reads.
+        bias = None
+        fuse_bias = x is None
+        if x is not None and p.fused:
+            rows_equal = bool((x[:, :m] == x[:, :1]).all())
+            x_pad_zero = bool((x[:, m:] == 0).all())
+            a_pad_zero = bool((a[:, m:] == 0).all())
+            if rows_equal and x_pad_zero and a_pad_zero:
+                bias, fuse_bias = x[:, 0], True
+        uniform_bias = bias is None or b == 1 or bool((bias == bias[0]).all())
 
     if p.fused and fuse_bias:
         # -- whole program inside the kernel --------------------------------
-        if uniform_w and (bias is None or b == 1
-                          or bool((bias == bias[0]).all())):
+        if uniform_w and uniform_bias:
             out = _kernel_gemm(
                 a.reshape(b * mp, -1), w[0],
                 bias[0] if bias is not None else None,
@@ -462,14 +467,17 @@ def _execute_stack(prog, stack: np.ndarray, *, saturate: bool,
                              saturate=False, out_dtype=jnp.int32,
                              gemm_backend=gemm_backend)
                 for i in range(b)])
-        if x is not None:                           # ACC preload (C = A·B+X)
-            acc = _wrap_int32(acc.astype(np.int64) + x.astype(np.int64))
-        vec = _to_vectors(acc, p)
-        res_vec = _to_vectors(res, p) if res is not None else None
-        vec = apply_alu_epilogue(vec, p.alu_ops, res_vec)
-        out = _commit_int8(_to_matrix(vec, p), saturate)
+        with span("vta.epilogue"):
+            if x is not None:                       # ACC preload (C = A·B+X)
+                acc = _wrap_int32(acc.astype(np.int64)
+                                  + x.astype(np.int64))
+            vec = _to_vectors(acc, p)
+            res_vec = _to_vectors(res, p) if res is not None else None
+            vec = apply_alu_epilogue(vec, p.alu_ops, res_vec)
+            out = _commit_int8(_to_matrix(vec, p), saturate)
 
-    _encode_out(stack, p, out)
+    with span("vta.encode"):
+        _encode_out(stack, p, out)
     report = SimReport()
     report.gemm_loops = b * prog.gemm_loops()
     report.alu_loops = b * prog.alu_loops()

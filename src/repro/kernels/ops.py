@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from jax._src import config as _jax_config
 
 from repro.core.errors import CompileError
+from repro.trace import span
 
 from . import ref as _ref
 from .flash_attention import flash_attention as _flash
@@ -112,14 +113,15 @@ def vta_matmul(a: jax.Array, b: jax.Array,
                                  saturate=saturate, out_dtype=out_dtype)
     g = gemm_blocks(m, k, n, block_m=block_m, block_n=block_n,
                     block_k=block_k)
-    a_p = jnp.pad(a, ((0, g.m - m), (0, g.k - k)))
-    b_p = jnp.pad(b, ((0, g.k - k), (0, g.n - n)))
-    bias_p = (jnp.pad(bias, (0, g.n - n)) if bias is not None else None)
-    out = _vta_gemm(a_p, b_p, bias_p, relu=relu, shift=shift,
-                    saturate=saturate, out_dtype=out_dtype,
-                    block_m=g.block_m, block_n=g.block_n, block_k=g.block_k,
-                    interpret=pallas_interpret())
-    return out[:m, :n]
+    with span("vta.kernel.dispatch", issued_macs=g.m * g.k * g.n):
+        a_p = jnp.pad(a, ((0, g.m - m), (0, g.k - k)))
+        b_p = jnp.pad(b, ((0, g.k - k), (0, g.n - n)))
+        bias_p = (jnp.pad(bias, (0, g.n - n)) if bias is not None else None)
+        out = _vta_gemm(a_p, b_p, bias_p, relu=relu, shift=shift,
+                        saturate=saturate, out_dtype=out_dtype,
+                        block_m=g.block_m, block_n=g.block_n,
+                        block_k=g.block_k, interpret=pallas_interpret())
+        return out[:m, :n]
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

@@ -132,4 +132,5 @@ def vta_gemm(a: jax.Array, b: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="vta_gemm",          # the kernel's name in traces and HLO
     )(*args)

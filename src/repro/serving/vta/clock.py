@@ -8,7 +8,7 @@ Two implementations share one two-method interface (``now()`` /
 * :class:`VirtualClock` — a manually-advanced monotonic counter; what the
   discrete-event simulation (:mod:`repro.serving.vta.simulate`) and the
   seeded load generator run on, so latency traces are *hermetic*: the
-  same seed produces bit-identical request traces and latency histograms
+  same seed produces bit-identical request traces and latency summaries
   on any machine, because no wall time ever enters the computation.
 """
 

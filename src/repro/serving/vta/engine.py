@@ -45,6 +45,7 @@ import numpy as np
 
 from repro.core.errors import CompileError
 from repro.core.network_compiler import SERVE_BACKENDS
+from repro.trace import span
 
 from .clock import WallClock
 from .metrics import RequestRecord, ServingMetrics
@@ -162,10 +163,15 @@ class VTAServingEngine:
     # ---------------------------------------------------------- workers
     def _worker(self, widx: int, backend: str) -> None:
         while True:
-            batch = self._queue.take_batch(self.clock)
+            with span("engine.batch_form"):
+                batch = self._queue.take_batch(self.clock)
             if batch is None:
                 return
-            self._execute(batch, widx, backend)
+            # the request ids link a RequestRecord to the batch it rode in
+            with span("engine.execute", worker=widx,
+                      rows=padded_size(len(batch), self._ladder),
+                      real=len(batch), first_rid=batch[0].rid):
+                self._execute(batch, widx, backend)
 
     def _execute(self, batch: List[Ticket], widx: int,
                  backend: str) -> None:

@@ -118,20 +118,6 @@ class ServingMetrics:
         with self._lock:
             return sorted(r.latency_s for r in self.records)
 
-    def latency_histogram(self, n_bins: int = 20) -> List[int]:
-        """Fixed-bin latency histogram over [0, max]; purely a function
-        of the recorded latencies, so same-seed virtual-clock runs
-        produce identical lists."""
-        lats = self.latencies_s()
-        if not lats:
-            return [0] * n_bins
-        top = lats[-1] or 1e-12
-        counts = [0] * n_bins
-        for lat in lats:
-            idx = min(int(n_bins * lat / top), n_bins - 1)
-            counts[idx] += 1
-        return counts
-
     def summary(self) -> Dict[str, float]:
         lats = self.latencies_s()
         with self._lock:

@@ -6,7 +6,7 @@ queue/batch-former policy the threaded engine runs
 driven by a :class:`~repro.serving.vta.clock.VirtualClock` over a seeded
 arrival source, with batch service times taken from a deterministic
 :class:`ServiceModel` instead of wall time.  Same seed + same model ⇒
-bit-identical request traces and latency histograms on any machine —
+bit-identical request traces and latency summaries on any machine —
 the ``servelat/*/deterministic_replay`` benchmark row asserts exactly
 that (EXPERIMENTS.md §Serving-latency).
 

@@ -11,6 +11,8 @@ serving makes exactly those calls.  The topology is described inside a
 fixture, never at import, so every test worker collects the same tests.
 """
 
+import re
+
 import jax
 import pytest
 
@@ -80,6 +82,20 @@ def test_vta_gemm_compiles_for_v5e(net_name, rung, nets, one_chip):
         compiled = vta_gemm.lower(*args, interpret=False,
                                   **statics).compile()
         assert "tpu_custom_call" in compiled.as_text(), call
+
+
+def test_vta_gemm_keeps_its_names_for_the_trace(nets, one_chip):
+    """The benchmark finds the kernel in a chip trace by its module
+    (``jit_vta_gemm``) and its op (``%vta_gemm.N = ... custom-call``); the
+    ``name`` the ``pallas_call`` carries must leave both as they read."""
+    net, _ = nets["lenet5"]
+    args, statics = kernel_call(plan_pallas(net.layers[0].program),
+                                1).vta_gemm_args(sharding=one_chip)
+    text = vta_gemm.lower(*args, interpret=False, **statics) \
+        .compile().as_text()
+    assert re.search(r"^HloModule jit_vta_gemm,", text, re.M)
+    assert re.search(r"%vta_gemm\.\d+ = [^\n]*custom-call\([^\n]*"
+                     r"tpu_custom_call", text)
 
 
 @pytest.mark.parametrize("net_name", NETS)
