@@ -13,8 +13,9 @@ widths with seeded weights, it
 2. serves 16 requests through ``VTAServingEngine`` with two pallas workers,
    in bursts that use several rungs of the batch ladder, and checks every
    answer against a direct serve and the metrics audit;
-3. lowers one layer's ``vta_gemm`` call as serving makes it and checks that
-   it is a compiled TPU kernel (``tpu_custom_call``).
+3. lowers one layer's kernel call as serving makes it (one program: the
+   pads, ``vta_gemm``, the slice) and checks that it holds a compiled TPU
+   kernel (``tpu_custom_call``).
 
 JAX's persistent compilation cache lives where ``JAX_COMPILATION_CACHE_DIR``
 says, or else in ``.jax_cache/`` of the checkout; the compile lines report
@@ -41,7 +42,7 @@ BATCH = 8
 BURSTS = (8, 3, 4, 1)
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-KERNEL_FUN = "jit(vta_gemm)"
+KERNEL_FUN = "jit(_padded_vta_gemm)"
 
 
 class SmokeFailure(RuntimeError):
@@ -93,7 +94,7 @@ class CompileLog:
         kernels = [s for name, s in done if name == KERNEL_FUN]
         return (f"{len(done)} backend compiles in "
                 f"{sum(s for _, s in done):.3f} s, {len(kernels)} of them "
-                f"vta_gemm in {sum(kernels):.3f} s; "
+                f"kernel calls in {sum(kernels):.3f} s; "
                 f"{hits} persistent-cache hits")
 
 
@@ -172,20 +173,20 @@ def engine_phase(name, net, images, log) -> None:
 
 
 def kernel_phase(name, net) -> None:
-    """Lower the first layer's kernel call at batch 8 as serving makes it."""
+    """Lower the first layer's kernel call at batch 8 as serving makes it:
+    one program holding the pads, ``vta_gemm`` and the slice."""
     from repro.core.pallas_backend import kernel_call, plan_pallas
-    from repro.kernels.ops import pallas_interpret
-    from repro.kernels.vta_gemm import vta_gemm
+    from repro.kernels.ops import _padded_vta_gemm, pallas_interpret
 
     args, statics = kernel_call(plan_pallas(net.layers[0].program),
-                                BATCH).vta_gemm_args()
-    text = vta_gemm.lower(*args, interpret=pallas_interpret(),
-                          **statics).as_text()
+                                BATCH).matmul_args()
+    text = _padded_vta_gemm.lower(*args, interpret=pallas_interpret(),
+                                  **statics).as_text()
     require("tpu_custom_call" in text,
-            f"{name}: the lowered vta_gemm holds no tpu_custom_call")
-    print(f"{name}: layer 0 vta_gemm {args[0].shape} @ {args[1].shape} "
-          f"lowers to a tpu_custom_call (compiled Mosaic kernel, not "
-          f"interpreted)", flush=True)
+            f"{name}: the lowered kernel call holds no tpu_custom_call")
+    print(f"{name}: layer 0 kernel call {args[0].shape} @ {args[1].shape} "
+          f"(pads, vta_gemm, slice) lowers to a tpu_custom_call (compiled "
+          f"Mosaic kernel, not interpreted)", flush=True)
 
 
 def main() -> None:

@@ -171,21 +171,35 @@ class KernelCall:
     shift: int
     out_dtype: object
 
+    def matmul_args(self, sharding=None):
+        """``(operand shapes, static kwargs)`` of the one jitted call
+        (``ops._padded_vta_gemm``: pads, ``vta_gemm``, slice) that
+        ``ops.vta_matmul`` makes for this call (serving's truncating commit,
+        ``vta_matmul``'s default blocks, ``interpret`` left to the caller)
+        — what its ``lower`` takes."""
+        shapes = [((self.m, self.k), jnp.int8), ((self.k, self.n), jnp.int8),
+                  ((self.n,), jnp.int32) if self.bias else None]
+        return _shape_structs(shapes, sharding), dict(
+            relu=self.relu, shift=self.shift, saturate=False,
+            out_dtype=self.out_dtype, block_m=256, block_n=256, block_k=256)
+
     def vta_gemm_args(self, sharding=None):
         """``(operand shapes, static kwargs)`` of the padded ``vta_gemm``
-        call ``ops.vta_matmul`` makes for this call (serving's truncating
-        commit, ``interpret`` left to the caller) — what ``vta_gemm.lower``
+        call inside :meth:`matmul_args`'s program — what ``vta_gemm.lower``
         takes."""
         from repro.kernels.ops import gemm_blocks
         g = gemm_blocks(self.m, self.k, self.n)
         shapes = [((g.m, g.k), jnp.int8), ((g.k, g.n), jnp.int8),
                   ((g.n,), jnp.int32) if self.bias else None]
-        args = [jax.ShapeDtypeStruct(*s, sharding=sharding) if s else None
-                for s in shapes]
-        statics = dict(relu=self.relu, shift=self.shift, saturate=False,
-                       out_dtype=self.out_dtype, block_m=g.block_m,
-                       block_n=g.block_n, block_k=g.block_k)
-        return args, statics
+        _, statics = self.matmul_args()
+        statics.update(block_m=g.block_m, block_n=g.block_n,
+                       block_k=g.block_k)
+        return _shape_structs(shapes, sharding), statics
+
+
+def _shape_structs(shapes, sharding):
+    return [jax.ShapeDtypeStruct(*s, sharding=sharding) if s else None
+            for s in shapes]
 
 
 def kernel_call(p: PallasPlan, batch: int) -> KernelCall:
@@ -383,13 +397,12 @@ def _kernel_gemm(a: np.ndarray, b: np.ndarray, bias: Optional[np.ndarray],
     from repro.kernels import ops as kernel_ops
     with span("vta.kernel"):
         with span("vta.kernel.put"):
-            operands = (jnp.asarray(a), jnp.asarray(b),
-                        jnp.asarray(bias) if bias is not None else None)
+            operands = jax.device_put((a, b, bias))
         out = kernel_ops.vta_matmul(
             *operands, relu=relu, shift=shift, saturate=saturate,
             out_dtype=out_dtype, backend=gemm_backend)
         with span("vta.kernel.fetch"):
-            return np.array(out)  # writable copy (jax buffers are read-only)
+            return np.asarray(out)      # read-only, as jax buffers are
 
 
 def _commit_int8(acc: np.ndarray, saturate: bool) -> np.ndarray:
@@ -452,7 +465,8 @@ def _execute_stack(prog, stack: np.ndarray, *, saturate: bool,
                              relu=p.relu, shift=p.shift, saturate=saturate,
                              out_dtype=jnp.int8, gemm_backend=gemm_backend)
                 for i in range(b)])
-        if bias is not None:
+        if bias is not None and m < mp:
+            out = np.require(out, requirements="W")
             out[:, m:, :] = 0          # oracle pad rows: 0·B + 0 preload
     else:
         # -- kernel GEMM + vectorised TensorAlu epilogue --------------------

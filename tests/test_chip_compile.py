@@ -3,11 +3,13 @@ attached.
 
 Every kernel call ``NetworkProgram.serve(backend="pallas")`` makes for
 lenet5 and resnet8 at batch-ladder rungs 1, 4 and 8 is compiled with
-``interpret=False`` for a described ``v5e:2x2`` topology: what the chip's
-compiler would refuse (a misaligned block, too much VMEM) fails here at no
-chip time.  The shapes come from ``plan_pallas`` → ``kernel_call`` →
-``gemm_blocks``; ``test_rehearsal_calls_are_what_serving_runs`` pins that
-serving makes exactly those calls.  The topology is described inside a
+``interpret=False`` for a described ``v5e:2x2`` topology, both as the
+program serving runs (``ops._padded_vta_gemm``: pads, ``vta_gemm``, slice)
+and as the bare padded ``vta_gemm``: what the chip's compiler would refuse
+(a misaligned block, too much VMEM) fails here at no chip time.  The
+shapes come from ``plan_pallas`` → ``kernel_call``;
+``test_rehearsal_calls_are_what_serving_runs`` pins that serving makes
+exactly those calls.  The topology is described inside a
 fixture, never at import, so every test worker collects the same tests.
 """
 
@@ -60,12 +62,12 @@ def nets(chip_smoke):
 
 
 def _kernel_calls(net, rung):
-    """The distinct ``vta_gemm`` calls a pallas serve of ``rung`` images
-    makes: ``{(operand shapes, static kwargs)}``."""
+    """The distinct kernel programs a pallas serve of ``rung`` images
+    runs: ``{(operand shapes, static kwargs)}``."""
     calls = set()
     for layer in net.layers:
         args, statics = kernel_call(plan_pallas(layer.program),
-                                    rung).vta_gemm_args()
+                                    rung).matmul_args()
         shapes = tuple(a.shape if a is not None else None for a in args)
         calls.add((shapes, frozenset(statics.items())))
     return calls
@@ -82,6 +84,35 @@ def test_vta_gemm_compiles_for_v5e(net_name, rung, nets, one_chip):
         compiled = vta_gemm.lower(*args, interpret=False,
                                   **statics).compile()
         assert "tpu_custom_call" in compiled.as_text(), call
+
+
+@pytest.mark.parametrize("rung", RUNGS)
+@pytest.mark.parametrize("net_name", NETS)
+def test_padded_vta_gemm_compiles_for_v5e(net_name, rung, nets, one_chip):
+    """The program serving dispatches, pads and slice included."""
+    net, _ = nets[net_name]
+    calls = {kernel_call(plan_pallas(layer.program), rung)
+             for layer in net.layers}
+    for call in calls:
+        args, statics = call.matmul_args(sharding=one_chip)
+        compiled = ops._padded_vta_gemm.lower(*args, interpret=False,
+                                              **statics).compile()
+        assert "tpu_custom_call" in compiled.as_text(), call
+
+
+def test_padded_vta_gemm_keeps_the_kernels_names(nets, one_chip):
+    """The benchmark finds the kernel's time by ``vta_gemm`` in the module
+    name and by the op ``%vta_gemm.N = ... custom-call``: the program that
+    holds the pads and the slice must still read both."""
+    net, _ = nets["lenet5"]
+    args, statics = kernel_call(plan_pallas(net.layers[0].program),
+                                1).matmul_args(sharding=one_chip)
+    text = ops._padded_vta_gemm.lower(*args, interpret=False, **statics) \
+        .compile().as_text()
+    module, = re.findall(r"^HloModule (\S+),", text, re.M)
+    assert "vta_gemm" in module, module
+    assert re.search(r"%vta_gemm\.\d+ = [^\n]*custom-call\([^\n]*"
+                     r"tpu_custom_call", text)
 
 
 def test_vta_gemm_keeps_its_names_for_the_trace(nets, one_chip):
@@ -101,18 +132,19 @@ def test_vta_gemm_keeps_its_names_for_the_trace(nets, one_chip):
 @pytest.mark.parametrize("net_name", NETS)
 def test_rehearsal_calls_are_what_serving_runs(net_name, nets, monkeypatch):
     """Record every kernel call of real pallas serves (interpreted on the
-    CPU) and compare with what the compile test above compiles."""
+    CPU) at the jitted program's boundary, where a repeat call still shows,
+    and compare with what the compile tests above compile."""
     net, images = nets[net_name]
     seen = set()
-    real = ops._vta_gemm
+    real = ops._padded_vta_gemm
 
     def spy(a, b, bias, **kw):
         kw.pop("interpret")
-        seen.add(((a.shape, b.shape, None if bias is None else bias.shape),
+        seen.add((tuple(None if x is None else x.shape for x in (a, b, bias)),
                   frozenset(kw.items())))
         return real(a, b, bias, interpret=True, **kw)
 
-    monkeypatch.setattr(ops, "_vta_gemm", spy)
+    monkeypatch.setattr(ops, "_padded_vta_gemm", spy)
     for rung in RUNGS:
         net.serve(images[:rung], backend="pallas")
     assert seen == set().union(*(_kernel_calls(net, r) for r in RUNGS))
