@@ -21,7 +21,8 @@ property tests against a direct convolution oracle.
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+import functools
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -162,11 +163,11 @@ class PoolPlan:
     handled by the layer compiler).  ``mode="avg"`` accumulates the 4
     window members into the *first* member's vector (3 ADD pairs), then
     divides by 4 with one SHR-2 (exact for the sum of four int32s in
-    range).  ``mode="max"`` reduces the window with 3 MAX pairs and needs
+    range).  ``mode="max"`` reduces the window with MAX pairs and needs
     no division.  ``mode="gap"`` is global average pooling (DESIGN.md
     §Strided-lowering): a binary tree of ADD pairs folds every spatial
-    position into row 0, then one SHR by ``div_shift = log2(H·W)`` divides
-    exactly — which is why GAP requires a power-of-two position count.
+    position into row 0, then one SHR by ``div_shift = floor(log2(H·W))``
+    — the rest of the ÷(H·W) belongs to the following requant's scale.
     ``keep_rows`` lists the surviving matrix rows, in pooled row-major
     order — the host-side decode extracts exactly these rows (which is how
     the paper's layer-1 output is "decoded into a 196×6 matrix").  On
@@ -175,11 +176,16 @@ class PoolPlan:
     pair groups pin the whole α range into a single chunk — a result too
     large for one ACC residency raises at compile time, never wrong bytes.
 
-    ``rounds`` (GAP only) groups ``add_pairs`` into dependency levels of
-    the reduction tree: pairs within one round touch disjoint vectors, so
+    ``rounds`` groups ``add_pairs`` into dependency levels: pairs within
+    one round write disjoint vectors that no pair of the round reads, so
     each round lowers to one vectorisable ALU instruction, while pairs in
-    *different* rounds carry the read-after-write chain of the tree.
-    Empty ``rounds`` means all pairs are independent (the 2×2 windows).
+    *different* rounds carry the read-after-write chain (the GAP tree's
+    levels; the padded 3×3/s2 max pool's column and row passes).  Empty
+    ``rounds`` means all pairs are independent (the 2×2 windows).
+
+    ``row_gather`` (padded 3×3/s2 max pool only) is the band layout of
+    the GEMM's rows: entry ``r`` names the conv output position whose
+    im2row row becomes matrix row ``r``, or ``-1`` for a zero row.
     """
 
     add_pairs: Tuple[Tuple[int, int], ...]
@@ -190,6 +196,8 @@ class PoolPlan:
     mode: str = "avg"              # "avg" | "max" | "gap"
     div_shift: int = 2             # log2 of the ÷ folded into the requant SHR
     rounds: Tuple[Tuple[Tuple[int, int], ...], ...] = ()
+    row_gather: Optional[np.ndarray] = dataclasses.field(default=None,
+                                                         compare=False)
 
 
 def _pool2x2_windows(in_h: int, in_w: int):
@@ -225,29 +233,107 @@ def maxpool2x2_plan(in_h: int, in_w: int) -> PoolPlan:
 
 def global_avgpool_plan(in_h: int, in_w: int) -> PoolPlan:
     """Global average pooling over an ``in_h × in_w`` map (DESIGN.md
-    §Strided-lowering): a ``log2(H·W)``-round binary tree of ADD pairs
-    reduces every position's ACC vector into row 0, and one SHR by
-    ``log2(H·W)`` turns the sum into the (floor) average — the ResNet/
-    YOLO-NAS classification head, entirely on the TensorAlu.
+    §Strided-lowering): a ``ceil(log2(H·W))``-round binary tree of ADD
+    pairs reduces every position's ACC vector into row 0 (a position with
+    no partner in a round waits for the next), and one SHR by
+    ``floor(log2(H·W))`` starts the division — the ResNet/YOLO-NAS
+    classification head, entirely on the TensorAlu.  On a power-of-two
+    map that SHR is the whole ÷(H·W); otherwise the remainder
+    ``H·W / 2^floor(log2(H·W))`` is left to the following requant's
+    scale (``plan_requant``), as any other power-of-two rounding is.
 
-    Requires a square power-of-two map so the division is exact in a
-    single arithmetic shift; the layer compiler turns violations into
+    Requires a square map; the layer compiler turns violations into
     typed :class:`~repro.core.errors.CompileError`\\ s.
     """
     n = in_h * in_w
     if in_h != in_w:
         raise ValueError(f"global avg pool needs a square map, got "
                          f"{in_h}x{in_w}")
-    if n <= 0 or n & (n - 1):
-        raise ValueError(f"global avg pool needs a power-of-two position "
-                         f"count for the SHR division, got {in_h}x{in_w}")
+    if n <= 0:
+        raise ValueError(f"global avg pool needs a non-empty map, got "
+                         f"{in_h}x{in_w}")
     rounds: list = []
     step = 1
     while step < n:
         rounds.append(tuple((base, base + step)
-                            for base in range(0, n, 2 * step)))
+                            for base in range(0, n - step, 2 * step)))
         step *= 2
     flat = tuple(p for rnd in rounds for p in rnd)
     return PoolPlan(add_pairs=flat, shr_indices=(0,), keep_rows=(0,),
                     out_h=1, out_w=1, mode="gap",
                     div_shift=n.bit_length() - 1, rounds=tuple(rounds))
+
+
+def pooled_extent(extent: int) -> int:
+    """Output extent of the padded 3×3/s2 max pool (pad 1, floor grid):
+    ``(extent + 2 - 3) // 2 + 1``."""
+    return (extent - 1) // 2 + 1
+
+
+@functools.lru_cache(maxsize=32)
+def maxpool3x3s2_plan(in_h: int, in_w: int, row_align: int) -> PoolPlan:
+    """Padded 3×3/stride-2 max pool (pad 1; ResNet's stem pool) as a MAX
+    pair program over *bands* of GEMM rows (DESIGN.md §Strided-lowering).
+
+    Window ``(i, j)`` reads image rows ``2i-1 … 2i+1`` and columns
+    ``2j-1 … 2j+1``; a neighbour outside the map is the padding, and is
+    skipped (max over what is there — exact against zero padding because
+    the pool follows a ReLU).  Neighbouring windows share a row and a
+    column, so their pairs chain across the whole map and no chunk could
+    hold one window group.  The GEMM's rows are therefore laid out as one
+    band per output row ``i``: image rows ``2i-1`` (the one-row halo it
+    shares with band ``i-1``), ``2i`` and ``2i+1``, ``in_w`` rows each,
+    padded with zero rows to a multiple of ``row_align`` (the block row
+    height) so that bands never share a block row.  The GEMM computes a
+    halo row twice, once per band that reads it, and each band is one
+    independent pair group, so chunk boundaries fall between bands.
+
+    Two rounds, in place: (1) on every row of the band, each even column
+    takes the MAX of its odd neighbours; (2) on the band's middle row,
+    each even column takes the MAX of the same column in the halo row
+    and the row below.  Odd positions are only ever read.  ``keep_rows``
+    are the middle row's even columns, band after band.
+    """
+    out_h, out_w = pooled_extent(in_h), pooled_extent(in_w)
+    band = -(-3 * in_w // row_align) * row_align
+    gather = np.full(out_h * band, -1, dtype=np.int64)
+    cols, rows, keep = [], [], []
+    for i in range(out_h):
+        base = i * band
+        present = []
+        for slot in range(3):
+            r = 2 * i - 1 + slot
+            if 0 <= r < in_h:
+                first = base + slot * in_w
+                gather[first:first + in_w] = np.arange(r * in_w,
+                                                       (r + 1) * in_w)
+                present.append(slot)
+        for slot in present:
+            first = base + slot * in_w
+            for j in range(out_w):
+                c = 2 * j
+                for nb in (c - 1, c + 1):
+                    if 0 <= nb < in_w:
+                        cols.append((first + c, first + nb))
+        for j in range(out_w):
+            dst = base + in_w + 2 * j
+            for slot in (0, 2):
+                if slot in present:
+                    rows.append((dst, base + slot * in_w + 2 * j))
+            keep.append(dst)
+    gather.setflags(write=False)
+    rounds = tuple(r for r in (tuple(cols), tuple(rows)) if r)
+    return PoolPlan(add_pairs=tuple(cols) + tuple(rows),
+                    shr_indices=tuple(keep), keep_rows=tuple(keep),
+                    out_h=out_h, out_w=out_w, mode="max", div_shift=0,
+                    rounds=rounds, row_gather=gather)
+
+
+def gather_rows(mat: np.ndarray, gather: np.ndarray) -> np.ndarray:
+    """``mat`` (..., M, K) → (..., len(gather), K): row ``r`` is
+    ``mat[..., gather[r], :]``, or zeros where ``gather[r]`` is -1 (the
+    band layout of :func:`maxpool3x3s2_plan`)."""
+    out = np.zeros(mat.shape[:-2] + (len(gather), mat.shape[-1]), mat.dtype)
+    valid = gather >= 0
+    out[..., valid, :] = mat[..., gather[valid], :]
+    return out

@@ -46,8 +46,8 @@ from . import cycle_model, isa, pipeline_schedule
 from .dram import DramAllocator
 from .errors import CompileError
 from .hwconfig import VTAConfig, vta_default
-from .layout import (matrix_padding, matrix_splitting, binarize_blocks,
-                     should_pad_height, pad_to_multiple)
+from .layout import (exact_matmul, matrix_padding, matrix_splitting,
+                     binarize_blocks, should_pad_height, pad_to_multiple)
 from .program import OutputMeta, VTAProgram
 
 
@@ -231,18 +231,37 @@ def plan_chunks(cfg: VTAConfig, alpha: int, lam: int, beta: int,
     acc_budget = (cfg.acc_buff_vectors // div) // acc_copies
     out_budget = cfg.out_buff_vectors - (
         cfg.acc_buff_vectors // 2 if double_buffer else 0)
+    uop_budget = cfg.uop_buff_entries - uop_reserve
+
+    def tiles(lam_c: int, rows: int):
+        """``(beta_c, alpha_c)`` for ``lam_c``, β tiles narrow enough
+        that ``rows`` block rows fit the ACC/OUT/UOP budgets."""
+        beta_c = max(1, min(beta, wgt_budget // lam_c,
+                            acc_budget // (row_height * rows),
+                            out_budget // (row_height * rows),
+                            uop_budget // rows))
+        alpha_c = max(1, min(alpha,
+                             inp_budget // (row_height * lam_c),
+                             acc_budget // (row_height * beta_c),
+                             out_budget // (row_height * beta_c),
+                             uop_budget // beta_c))
+        return beta_c, alpha_c
+
     lam_c = max(1, min(lam, wgt_budget, inp_budget // row_height))
     if max_lam_c is not None:
         lam_c = max(1, min(lam_c, max_lam_c))
-    beta_c = max(1, min(beta, wgt_budget // lam_c,
-                        acc_budget // row_height,
-                        out_budget // row_height,
-                        cfg.uop_buff_entries - uop_reserve))
-    alpha_c = max(1, min(alpha,
-                         inp_budget // (row_height * lam_c),
-                         acc_budget // (row_height * beta_c),
-                         out_budget // (row_height * beta_c),
-                         (cfg.uop_buff_entries - uop_reserve) // beta_c))
+    beta_c, alpha_c = tiles(lam_c, 1)
+    # A pair group of ``need`` block rows must fit one chunk.  Where the
+    # greedy tiles are too short for it, narrow β and then λ (INP holds
+    # alpha_c · lam_c block rows per load group) until it does; a group
+    # that fits no tiling is refused by _segment below.
+    need = _widest_group(row_groups)
+    if alpha_c < min(need, alpha):
+        for lc in range(lam_c, 0, -1):
+            b_c, a_c = tiles(lc, need)
+            if a_c >= min(need, alpha):
+                lam_c, beta_c, alpha_c = lc, b_c, a_c
+                break
     if max_alpha_c is not None:
         alpha_c = max(1, min(alpha_c, max_alpha_c))
     plan = ChunkPlan(alpha, lam, beta, alpha_c, lam_c, beta_c, row_height,
@@ -251,6 +270,20 @@ def plan_chunks(cfg: VTAConfig, alpha: int, lam: int, beta: int,
                      acc_copies=acc_copies, double_buffer=double_buffer)
     _validate_plan(cfg, plan)
     return plan
+
+
+def _widest_group(groups: Sequence[Tuple[int, int]]) -> int:
+    """Block rows of the widest run of chained groups: groups that share
+    a block row cannot be cut apart, so they must share a chunk."""
+    if not groups:
+        return 1
+    lo, hi = np.asarray(groups, dtype=np.int64).T
+    order = np.argsort(lo, kind="stable")
+    lo, hi = lo[order], np.maximum.accumulate(hi[order])
+    # a new run starts where a group begins past every earlier end
+    starts = np.flatnonzero(np.r_[True, lo[1:] > hi[:-1]])
+    ends = np.r_[starts[1:], len(lo)] - 1
+    return int((hi[ends] - lo[starts] + 1).max())
 
 
 def _validate_plan(cfg: VTAConfig, p: ChunkPlan) -> None:
@@ -272,15 +305,16 @@ def _ranges(total: int, chunk: int):
         yield start, min(chunk, total - start)
 
 
-def _chunk_local_index(v: int, i0: int, a_c: int, j0: int, b_c: int,
-                       beta: int, row_height: int) -> Optional[int]:
-    """Global result-vector index → index into this chunk's ACC window, or
-    ``None`` when the vector lives in another chunk (block-major, §3.2)."""
-    br, rem = divmod(v, beta * row_height)
-    bc, within = divmod(rem, row_height)
-    if not (i0 <= br < i0 + a_c and j0 <= bc < j0 + b_c):
-        return None
-    return ((br - i0) * b_c + (bc - j0)) * row_height + within
+def _chunk_local_indices(v: np.ndarray, i0: int, a_c: int, j0: int,
+                         b_c: int, beta: int, row_height: int
+                         ) -> Tuple[np.ndarray, np.ndarray]:
+    """Global result-vector indices → ``(inside, local)``: whether each
+    lives in this chunk's ACC window, and its index there (meaningful
+    where ``inside``; block-major, §3.2)."""
+    br, rem = np.divmod(v, beta * row_height)
+    bc, within = np.divmod(rem, row_height)
+    inside = (i0 <= br) & (br < i0 + a_c) & (j0 <= bc) & (bc < j0 + b_c)
+    return inside, ((br - i0) * b_c + (bc - j0)) * row_height + within
 
 
 def _alu_chunk_groups(alu_ops: Sequence, beta: int, row_height: int
@@ -316,10 +350,9 @@ def reference_result(A: np.ndarray, B: np.ndarray, X: Optional[np.ndarray],
     bs = cfg.block_size
     if row_height is None:
         row_height = bs if should_pad_height(A) else 1
-    Ap = matrix_padding(A, bs, pad_height=row_height > 1).astype(np.int32)
-    Bp = matrix_padding(B, bs, pad_height=True).astype(np.int32)
-    acc = Ap @ Bp   # int32 with wraparound handled by numpy int32 ops below
-    acc = acc.astype(np.int64)
+    Ap = matrix_padding(A, bs, pad_height=row_height > 1)
+    Bp = matrix_padding(B, bs, pad_height=True)
+    acc = exact_matmul(Ap, Bp)    # int32 wraparound applied below
     if X is not None:
         Xp = np.zeros(acc.shape, dtype=np.int64)
         Xp[:X.shape[0], :X.shape[1]] = X.astype(np.int64)
@@ -340,8 +373,7 @@ def reference_result(A: np.ndarray, B: np.ndarray, X: Optional[np.ndarray],
         elif isinstance(spec, AluIndexedImmOp):
             vec = _alu_apply(vec, spec.op, spec.imm, np.asarray(spec.indices))
         elif isinstance(spec, AluPairOp):
-            for dst, src in spec.pairs:
-                vec = _alu_pair(vec, spec.op, dst, src)
+            vec = apply_pairs(vec, spec.op, pair_lattice(spec.op, spec.pairs))
         elif isinstance(spec, AluResidualOp):
             if res_vec is None:
                 raise CompileError(
@@ -363,7 +395,9 @@ def reference_result(A: np.ndarray, B: np.ndarray, X: Optional[np.ndarray],
 
 
 def _wrap_int32(x: np.ndarray) -> np.ndarray:
-    return ((x.astype(np.int64) + 2**31) % 2**32 - 2**31).astype(np.int32)
+    """int64 → int32 keeping the low 32 bits, two's complement (the
+    integer downcast does exactly that, as ``layout.truncate_int8``'s)."""
+    return np.asarray(x).astype(np.int64).astype(np.int32)
 
 
 def _alu_apply(vec, op, imm, idx):
@@ -397,20 +431,71 @@ def _alu_residual(vec, op, res64):
     return _wrap_int32(r)
 
 
-def _alu_pair(vec, op, dst, src):
-    vec = vec.copy()
-    a = vec[dst].astype(np.int64)
-    b = vec[src].astype(np.int64)
+@dataclasses.dataclass(frozen=True, eq=False)
+class PairLattice:
+    """An :class:`AluPairOp`'s (dst, src) pairs as index arrays.  Pairs
+    must run in pair order where a destination is also read, or an SHR
+    repeats a destination (``sequential``).  Otherwise no source is ever
+    written, and they vectorise as ``passes``: pass ``k`` merges every
+    destination with its ``k``-th source, so no pass repeats a
+    destination — exact for ADD (mod-2³² congruence) and MIN/MAX, which
+    merge in any order."""
+
+    dst: np.ndarray
+    src: np.ndarray
+    passes: Tuple[Tuple[np.ndarray, np.ndarray], ...]
+    sequential: bool
+
+
+def pair_lattice(op, pairs: Sequence[Tuple[int, int]]) -> PairLattice:
+    idx = np.asarray(pairs, dtype=np.int64).reshape(-1, 2)
+    dst, src = idx[:, 0], idx[:, 1]
+    order = np.argsort(dst, kind="stable")
+    ds, ss = dst[order], src[order]
+    starts = np.flatnonzero(np.r_[True, ds[1:] != ds[:-1]])
+    # each pair's rank among the pairs of its destination
+    rank = np.arange(len(ds)) - np.repeat(starts, np.diff(np.r_[starts,
+                                                                len(ds)]))
+    sequential = (np.intersect1d(dst, src).size > 0
+                  or (op not in (isa.AluOp.ADD, isa.AluOp.MIN,
+                                 isa.AluOp.MAX)
+                      and len(starts) != len(dst)))
+    passes = tuple((ds[rank == k], ss[rank == k])
+                   for k in range(int(rank.max(initial=-1)) + 1))
+    return PairLattice(dst=dst, src=src, passes=passes,
+                       sequential=sequential)
+
+
+def _pair_result(op, a, b):
     if op == isa.AluOp.MIN:
-        r = np.minimum(a, b)
-    elif op == isa.AluOp.MAX:
-        r = np.maximum(a, b)
-    elif op == isa.AluOp.ADD:
-        r = a + b
-    elif op == isa.AluOp.SHR:
-        r = a >> (b & 31)
-    vec[dst] = _wrap_int32(r)
-    return vec
+        return np.minimum(a, b)
+    if op == isa.AluOp.MAX:
+        return np.maximum(a, b)
+    if op == isa.AluOp.ADD:
+        return a + b
+    if op == isa.AluOp.SHR:
+        return a >> (b & 31)
+    raise CompileError(f"unsupported ALU pair op {op!r}",
+                       constraint="alu-pair-op")
+
+
+def apply_pairs(vec: np.ndarray, op, lat: PairLattice) -> np.ndarray:
+    """``vec[..., dst, :] = op(vec[..., dst, :], vec[..., src, :])`` per
+    pair, in pair order, over int32 vectors whose second-to-last axis
+    indexes them (``(n_vec, bs)``, or ``(B, n_vec, bs)`` for a batch);
+    returns a new array.  int32 arithmetic wraps as the ALU's does.  The
+    one implementation of the pair semantics: the compiler's reference
+    and the pallas epilogue both run it."""
+    out = vec.copy()
+    if lat.sequential:
+        for d, s in zip(lat.dst.tolist(), lat.src.tolist()):
+            out[..., d, :] = _wrap_int32(_pair_result(
+                op, out[..., d, :].astype(np.int64),
+                out[..., s, :].astype(np.int64)))
+        return out
+    for d, s in lat.passes:
+        out[..., d, :] = _pair_result(op, out[..., d, :], vec[..., s, :])
+    return out
 
 
 def _matrix_to_vectors(mat: np.ndarray, bs: int, row_height: int) -> np.ndarray:
@@ -590,8 +675,6 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
 
         def _alu_chunk_uops(spec, i0: int, a_c: int, j0: int, b_c: int,
                             acc_off: int) -> List[isa.Uop]:
-            local = lambda v: _chunk_local_index(v, i0, a_c, j0, b_c, beta,
-                                                 row_height)
             out: List[isa.Uop] = []
             if isinstance(spec, AluResidualOp):
                 # The residual window sits right after the chunk's result
@@ -605,24 +688,33 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
                                        wgt_idx=0))
                 out.append(isa.Uop(acc_idx=acc_off, inp_idx=base, wgt_idx=0))
                 return out
-            if isinstance(spec, AluIndexedImmOp):
-                for v in spec.indices:
-                    lv = local(v)
-                    if lv is not None:
-                        out.append(isa.Uop(acc_idx=acc_off + lv,
-                                           inp_idx=acc_off + lv, wgt_idx=0))
+            dst, src = alu_index_arrays[id(spec)]
+            inside, ld = _chunk_local_indices(dst, i0, a_c, j0, b_c, beta,
+                                              row_height)
+            if src is None:                       # indexed immediate
+                ls = ld
             else:
-                for dst, src in spec.pairs:
-                    ld, ls = local(dst), local(src)
-                    if (ld is None) != (ls is None):
-                        raise AssertionError(   # plan alignment guarantees
-                            f"pair ({dst}, {src}) straddles a chunk "
-                            f"boundary")
-                    if ld is not None:
-                        out.append(isa.Uop(acc_idx=acc_off + ld,
-                                           inp_idx=acc_off + ls, wgt_idx=0))
+                inside_s, ls = _chunk_local_indices(src, i0, a_c, j0, b_c,
+                                                    beta, row_height)
+                if (inside != inside_s).any():    # plan alignment guarantees
+                    k = int(np.flatnonzero(inside != inside_s)[0])
+                    raise AssertionError(
+                        f"pair ({dst[k]}, {src[k]}) straddles a chunk "
+                        f"boundary")
+            for d, s_ in zip((ld[inside] + acc_off).tolist(),
+                             (ls[inside] + acc_off).tolist()):
+                out.append(isa.Uop(acc_idx=d, inp_idx=s_, wgt_idx=0))
             return out
 
+        alu_index_arrays = {}
+        for spec in alu_ops:
+            if isinstance(spec, AluIndexedImmOp):
+                alu_index_arrays[id(spec)] = (
+                    np.asarray(spec.indices, dtype=np.int64).reshape(-1),
+                    None)
+            elif isinstance(spec, AluPairOp):
+                pairs = np.asarray(spec.pairs, dtype=np.int64).reshape(-1, 2)
+                alu_index_arrays[id(spec)] = (pairs[:, 0], pairs[:, 1])
         chunk_alu_uops = [
             [None if isinstance(spec, AluImmOp)
              else _alu_chunk_uops(spec, i0, a_c, j0, b_c, sched.acc_base(ci))

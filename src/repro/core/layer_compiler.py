@@ -3,9 +3,10 @@
 A *layer* (paper §4.1) = one dense linear operation (convolution — valid or
 zero-padded "same", stride 1 or 2 (DESIGN.md §Strided-lowering) — or fully
 connected) + subsequent non-linear operations (ReLU on TensorAlu; average
-pooling as an ALU ADD/SHR program; max pooling as an ALU MAX pair program;
-global average pooling as an ALU ADD-pair tree reduction + one SHR; static
-power-of-2 requantisation).  Layers
+pooling as an ALU ADD/SHR program; max pooling — 2×2/s2, or the padded
+3×3/s2 of ResNet's stem over banded GEMM rows — as an ALU MAX pair
+program; global average pooling as an ALU ADD-pair tree reduction + one
+SHR, also after a residual join; static power-of-2 requantisation).  Layers
 whose matrices exceed the SRAM compile to multi-chunk programs — the GEMM
 compiler re-indexes the pool/requant uops against each chunk's local ACC
 window (DESIGN.md §3), so nothing here is limited to single-chunk results.
@@ -32,14 +33,16 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from .conv_lowering import (ConvGeometry, PoolPlan, avgpool2x2_plan,
-                            flatten_tensor, global_avgpool_plan, im2row,
-                            ker2col, mat2tensor, maxpool2x2_plan, tensor2mat)
+                            flatten_tensor, gather_rows, global_avgpool_plan,
+                            im2row, ker2col, mat2tensor, maxpool2x2_plan,
+                            maxpool3x3s2_plan, tensor2mat)
 from .dram import DramAllocator
 from .errors import CompileError
 from .gemm_compiler import (AluImmOp, AluIndexedImmOp, AluPairOp,
                             AluResidualOp, compile_matmul)
 from .hwconfig import VTAConfig, vta_default
-from .layout import pad_to_multiple, should_pad_height, truncate_int8
+from .layout import (exact_matmul, pad_to_multiple, should_pad_height,
+                     truncate_int8)
 from .program import VTAProgram
 from . import isa
 
@@ -60,7 +63,8 @@ class LayerSpec:
     stride: int = 1
     padding: int = 0               # symmetric zero-padding (conv only)
     relu: bool = False
-    pool: Optional[str] = None     # None | "avg2x2" | "max2x2" | "gap"
+    pool: Optional[str] = None     # None | "avg2x2" | "max2x2" |
+    #                                "max3x3s2" | "gap"
     requant_shift: Optional[int] = None   # None = choose statically
     # Residual-add fusion (DESIGN.md §Graph): the layer closes a skip
     # connection — after the GEMM result is requantised (``requant_shift``)
@@ -68,7 +72,8 @@ class LayerSpec:
     # vector-vector ADD (``residual_pre_shift`` equalises its scale), then
     # ``relu`` applies *post-add* and ``residual_shift`` requantises the
     # sum.  ``compile_layer`` must then receive the skip activation via
-    # its ``residual=`` argument.  Pooling cannot fuse with a residual.
+    # its ``residual=`` argument.  Of the pools only "gap" fuses with a
+    # residual: it then sums the requantised join (ResNet's last block).
     residual_add: bool = False
     residual_pre_shift: int = 0
     residual_shift: Optional[int] = None  # None = choose statically
@@ -96,6 +101,10 @@ class CompiledLayer:
     # scale) and the post-add requant shift actually compiled in.
     residual_matrix: Optional[np.ndarray] = None
     residual_shift: Optional[int] = None
+    # Rows of one image's GEMM at its valid shape (the conv's output
+    # positions) where ``input_matrix`` holds more: the padded 3×3/s2 max
+    # pool's bands repeat halo rows and pad each band with zero rows.
+    gemm_rows: Optional[int] = None
 
     @property
     def gemm_loops(self) -> int:
@@ -106,7 +115,7 @@ class CompiledLayer:
         """Multiply-accumulates of one image's GEMM at its valid shape
         (M·K·N, M counted before any pooling)."""
         m, k = self.input_matrix.shape
-        return m * k * self.weight_matrix.shape[1]
+        return (self.gemm_rows or m) * k * self.weight_matrix.shape[1]
 
     @property
     def n_chunks(self) -> int:
@@ -122,11 +131,12 @@ def _vec_index(row: int, col_block: int, beta: int, row_height: int) -> int:
     return (block_row * beta + col_block) * row_height + within
 
 
-def pool_plan_for(spec: LayerSpec,
-                  geo: Optional[ConvGeometry]) -> Optional[PoolPlan]:
+def pool_plan_for(spec: LayerSpec, geo: Optional[ConvGeometry], *,
+                  row_align: int = 16) -> Optional[PoolPlan]:
     """The pooling plan a LayerSpec asks for (None = no pooling).  The
     single place pool kinds are interpreted — unknown kinds raise here for
-    the compiler and the calibration path alike."""
+    the compiler and the calibration path alike.  ``row_align`` is the
+    block row height the padded 3×3/s2 pool's bands are padded to."""
     if spec.pool is None:
         return None
     if geo is None:
@@ -140,12 +150,28 @@ def pool_plan_for(spec: LayerSpec,
                 constraint="pool-even-dims")
         return (avgpool2x2_plan if spec.pool == "avg2x2"
                 else maxpool2x2_plan)(geo.out_h, geo.out_w)
+    if spec.pool == "max3x3s2":
+        check_maxpool3x3_relu(spec.relu, layer=spec.name)
+        return maxpool3x3s2_plan(geo.out_h, geo.out_w, row_align)
     if spec.pool == "gap":
         check_gap_geometry(geo.out_h, geo.out_w, layer=spec.name)
         return global_avgpool_plan(geo.out_h, geo.out_w)
     raise CompileError(f"unsupported pool kind {spec.pool!r} (expected "
-                       f"'avg2x2', 'max2x2' or 'gap')", layer=spec.name,
-                       constraint="pool-kind")
+                       f"'avg2x2', 'max2x2', 'max3x3s2' or 'gap')",
+                       layer=spec.name, constraint="pool-kind")
+
+
+def check_maxpool3x3_relu(relu: bool, *, layer: str = "") -> None:
+    """The padded 3×3/s2 max pool skips the neighbours outside the map.
+    That equals zero padding only over a ReLU's output (no value below
+    0), so the pool must follow a ReLU: otherwise the two common readings
+    of its padding disagree, and the compiler picks neither.  Shared by
+    the layer compiler and the graph linearization."""
+    if not relu:
+        raise CompileError(
+            "the padded 3x3/s2 max pool must follow a ReLU (its padding "
+            "is exact only over values >= 0)", layer=layer,
+            constraint="maxpool3x3-needs-relu")
 
 
 def pool_divisor(pool_plan: Optional[PoolPlan]) -> int:
@@ -167,10 +193,15 @@ def choose_requant_shift(acc: np.ndarray, *, already_shifted: int = 0) -> int:
 def check_stride_tiling(geo: ConvGeometry, *, layer: str = "") -> None:
     """Stride-2 grid-coverage constraint (DESIGN.md §Strided-lowering).
 
-    The strided window grid must reach the last *real* input pixel: the
-    uncovered tail of the padded input is ``(in + 2·pad - k) mod stride``
-    columns/rows wide, and anything beyond the trailing ``pad`` of those
-    is input data the conv would silently ignore — which the compiler
+    The output grid is the floor grid ``(in + 2·pad − k) // stride + 1``
+    that the graph reference and every framework compute, and im2row
+    lowers exactly that grid.  A kernel narrower than its stride (the
+    1×1/s2 projection shortcut) reads only every ``stride``-th position by
+    definition, so what it skips is its own subsampling.  A kernel at
+    least as wide as its stride covers every interior pixel; there, a
+    tail of the padded input that no window reaches, ``(in + 2·pad − k)
+    mod stride`` wide, is refused where it is wider than the trailing
+    ``pad``: real input dropped at the edge alone, which the compiler
     refuses (never silent wrong bytes).  Shared by the layer compiler and
     the graph shape-inference pass so the two front ends cannot drift.
     """
@@ -178,6 +209,8 @@ def check_stride_tiling(geo: ConvGeometry, *, layer: str = "") -> None:
         return
     for axis, extent, k in (("height", geo.in_h, geo.kh),
                             ("width", geo.in_w, geo.kw)):
+        if k < geo.stride:
+            continue
         leftover = (extent + 2 * geo.pad - k) % geo.stride
         if leftover > geo.pad:
             raise CompileError(
@@ -189,25 +222,23 @@ def check_stride_tiling(geo: ConvGeometry, *, layer: str = "") -> None:
 
 
 def check_gap_geometry(out_h: int, out_w: int, *, layer: str = "") -> None:
-    """Global-avg-pool map constraints (DESIGN.md §Strided-lowering): the
-    ÷(H·W) must be one exact SHR, so the map must be square with a
-    power-of-two position count.  Shared by the layer compiler and the
-    graph shape-inference pass so the two front ends cannot drift."""
+    """Global-avg-pool map constraint (DESIGN.md §Strided-lowering): the
+    map must be square.  Any position count lowers — the tree folds every
+    position into row 0, and the ÷(H·W) beyond its power of two is the
+    following requant's scale.  Shared by the layer compiler and the graph
+    shape-inference pass so the two front ends cannot drift."""
     if out_h != out_w:
         raise CompileError(
             f"global avg pool needs a square map, got {out_h}x{out_w}",
             layer=layer, constraint="gap-square")
-    n = out_h * out_w
-    if n & (n - 1):
-        raise CompileError(
-            f"global avg pool needs a power-of-two position count for "
-            f"the exact SHR division, got {out_h}x{out_w}",
-            layer=layer, constraint="gap-pow2")
 
 
-def layer_matrices(spec: LayerSpec, inp: np.ndarray
+def layer_matrices(spec: LayerSpec, inp: np.ndarray, *, row_align: int = 16
                    ) -> Tuple[np.ndarray, np.ndarray, Optional[ConvGeometry]]:
-    """Hardware-agnostic stage: tensors → (A, B) matrices (Def. 3).
+    """Hardware-agnostic stage: tensors → (A, B) matrices (Def. 3).  A
+    padded 3×3/s2 max pool lays A's rows out in its bands
+    (:func:`~repro.core.conv_lowering.maxpool3x3s2_plan`, ``row_align``
+    rows to a block row).
 
     Every unsupported shape/stride raises a typed :class:`CompileError`
     naming the layer and the violated constraint (certification-style
@@ -254,6 +285,9 @@ def layer_matrices(spec: LayerSpec, inp: np.ndarray
                 constraint="conv-kernel-fit")
         check_stride_tiling(geo, layer=spec.name)
         A = im2row(inp, kh, kw, spec.stride, spec.padding)
+        gather = row_gather(spec, geo, row_align)
+        if gather is not None:
+            A = gather_rows(A, gather)
         B = ker2col(spec.weights)
         return A, B, geo
     if spec.kind == "fc":
@@ -277,12 +311,22 @@ def layer_matrices(spec: LayerSpec, inp: np.ndarray
                        f"or 'fc')", layer=spec.name, constraint="layer-kind")
 
 
+def row_gather(spec: LayerSpec, geo: ConvGeometry,
+               row_align: int) -> Optional[np.ndarray]:
+    """The band layout of the layer's GEMM rows, or None where A's rows
+    are the conv's output positions in order (every pool but the padded
+    3×3/s2 max pool).  Compilation and staging both route through it."""
+    if spec.pool != "max3x3s2":
+        return None
+    return pool_plan_for(spec, geo, row_align=row_align).row_gather
+
+
 def reference_layer_acc(A: np.ndarray, B: np.ndarray,
                         bias: Optional[np.ndarray], relu: bool,
                         pool_plan: Optional[PoolPlan]) -> np.ndarray:
     """int64 accumulator right before the final SHR — used for the static
     requant-shift choice and overflow check."""
-    acc = A.astype(np.int64) @ B.astype(np.int64)
+    acc = exact_matmul(A, B)
     if bias is not None:
         acc = acc + bias.astype(np.int64)[None, :]
     if relu:
@@ -291,6 +335,13 @@ def reference_layer_acc(A: np.ndarray, B: np.ndarray,
         if pool_plan.mode == "gap":
             # every spatial position folds into row 0 (÷ in the requant)
             return acc.sum(axis=0, keepdims=True)
+        if pool_plan.row_gather is not None:
+            # banded 3×3/s2 max pool: the rounds in order, each one a
+            # disjoint (dst, src) lattice, on the banded rows
+            for rnd in pool_plan.rounds:
+                dst, src = np.asarray(rnd).T
+                np.maximum.at(acc, dst, acc[src])
+            return acc[list(pool_plan.keep_rows)]
         pooled = np.zeros((len(pool_plan.keep_rows), acc.shape[1]),
                           dtype=np.int64)
         for r, base in enumerate(pool_plan.keep_rows):
@@ -327,12 +378,13 @@ def _compile_residual_layer(spec: LayerSpec, A: np.ndarray, B: np.ndarray,
                             schedule: str = "serialized") -> CompiledLayer:
     """The residual-closing layer (DESIGN.md §Graph): GEMM → SHR(requant)
     → on-VTA vector-vector ADD with the ACC-loaded skip operand →
-    optional ReLU → SHR(post-add requant)."""
-    if spec.pool is not None:
+    optional ReLU → [global-pool ADD tree] → SHR(post-add requant, with
+    the pool's division folded in)."""
+    if spec.pool not in (None, "gap"):
         raise CompileError(
-            "pooling cannot fuse with a residual add (downsample with a "
-            "strided conv instead)", layer=spec.name,
-            constraint="residual-no-pool")
+            f"only the global avg pool fuses with a residual add, not "
+            f"{spec.pool!r} (downsample with a strided conv instead)",
+            layer=spec.name, constraint="residual-no-pool")
     if residual is None:
         raise CompileError(
             "residual_add layer compiled without a residual operand",
@@ -345,7 +397,10 @@ def _compile_residual_layer(spec: LayerSpec, A: np.ndarray, B: np.ndarray,
     M, N = A.shape[0], B.shape[1]
     R = residual_operand_matrix(spec, residual, (M, N))
 
-    acc = A.astype(np.int64) @ B.astype(np.int64)
+    pool_plan = pool_plan_for(spec, geo, row_align=cfg.block_size)
+    pool_div = pool_divisor(pool_plan)
+
+    acc = exact_matmul(A, B)
     if spec.bias is not None:
         acc = acc + spec.bias.astype(np.int64)[None, :]
     s_conv = (spec.requant_shift if spec.requant_shift is not None
@@ -353,9 +408,11 @@ def _compile_residual_layer(spec: LayerSpec, A: np.ndarray, B: np.ndarray,
     t = (acc >> s_conv) + (R.astype(np.int64) >> spec.residual_pre_shift)
     if spec.relu:
         t = np.maximum(t, 0)
+    if pool_plan is not None:
+        t = t.sum(axis=0, keepdims=True)    # the GAP: every row into row 0
     s_add = (spec.residual_shift if spec.residual_shift is not None
-             else choose_requant_shift(t))
-    final = t >> s_add
+             else choose_requant_shift(t, already_shifted=pool_div))
+    final = t >> (pool_div + s_add)
     if np.abs(final).max(initial=0) > 127:
         raise CompileError(
             f"post-add requant shift {s_add} leaves values outside int8 — "
@@ -369,7 +426,10 @@ def _compile_residual_layer(spec: LayerSpec, A: np.ndarray, B: np.ndarray,
                                  pre_shift=spec.residual_pre_shift))
     if spec.relu:
         alu_ops.append(AluImmOp.relu())
-    if s_add > 0:
+    if pool_plan is not None:
+        alu_ops.extend(_pool_alu_ops(pool_plan, A, B, cfg.block_size,
+                                     pool_div + s_add))
+    elif s_add > 0:
         alu_ops.append(AluImmOp.shr(s_add))
 
     prog = compile_matmul(A, B, bias=spec.bias, alu_ops=alu_ops, residual=R,
@@ -377,11 +437,46 @@ def _compile_residual_layer(spec: LayerSpec, A: np.ndarray, B: np.ndarray,
                           schedule=schedule)
     out_h = geo.out_h if geo is not None else None
     out_w = geo.out_w if geo is not None else None
+    if pool_plan is not None:
+        out_h, out_w = pool_plan.out_h, pool_plan.out_w
     return CompiledLayer(spec=spec, program=prog, input_matrix=A,
                          weight_matrix=B, requant_shift=s_conv,
-                         keep_rows=None, out_h=out_h, out_w=out_w,
+                         keep_rows=(pool_plan.keep_rows
+                                    if pool_plan is not None else None),
+                         out_h=out_h, out_w=out_w,
                          ref_output_matrix=truncate_int8(final),
                          residual_matrix=R, residual_shift=s_add)
+
+
+def _pool_alu_ops(pool_plan: PoolPlan, A: np.ndarray, B: np.ndarray,
+                  bs: int, total_shift: int) -> List[object]:
+    """The pool's ALU program over ACC vectors (block-major indices): one
+    pair op per dependency level, then the SHR (the pool's division and
+    the requant) over the surviving rows."""
+    M = A.shape[0]
+    row_height = bs if should_pad_height(A) else M
+    beta = pad_to_multiple(B.shape[1], bs) // bs
+    pool_op = isa.AluOp.MAX if pool_plan.mode == "max" else isa.AluOp.ADD
+    ops: List[object] = []
+    # One AluPairOp per dependency level: 2×2 windows are one flat
+    # independent set; the GAP tree and the 3×3/s2 passes emit one op per
+    # round so every instruction's (dst, src) lattice stays disjoint
+    # (vectorisable) while the read-after-write chain lives *between*
+    # instructions.
+    for round_pairs in pool_plan.rounds or (pool_plan.add_pairs,):
+        pairs = []
+        for dst, src in round_pairs:
+            for j in range(beta):
+                pairs.append((_vec_index(dst, j, beta, row_height),
+                              _vec_index(src, j, beta, row_height)))
+        ops.append(AluPairOp(pool_op, tuple(pairs)))
+    if total_shift > 0:
+        idx = []
+        for r in pool_plan.keep_rows:
+            for j in range(beta):
+                idx.append(_vec_index(r, j, beta, row_height))
+        ops.append(AluIndexedImmOp(isa.AluOp.SHR, total_shift, tuple(idx)))
+    return ops
 
 
 def compile_layer(spec: LayerSpec, inp: np.ndarray, *,
@@ -395,8 +490,7 @@ def compile_layer(spec: LayerSpec, inp: np.ndarray, *,
     — the semantic int8 output of the earlier layer — as ``residual``; it
     becomes the program's second ACC operand, merged on the VTA."""
     cfg = cfg or vta_default()
-    bs = cfg.block_size
-    A, B, geo = layer_matrices(spec, inp)
+    A, B, geo = layer_matrices(spec, inp, row_align=cfg.block_size)
     if spec.residual_add:
         return _compile_residual_layer(spec, A, B, geo, residual, cfg,
                                        allocator, schedule=schedule)
@@ -404,11 +498,8 @@ def compile_layer(spec: LayerSpec, inp: np.ndarray, *,
         raise CompileError(
             "residual operand passed to a layer without residual_add",
             layer=spec.name, constraint="residual-unexpected-operand")
-    M, K = A.shape
-    N = B.shape[1]
-
     # ---- pooling plan (indices in matrix-row space) ----
-    pool_plan = pool_plan_for(spec, geo)
+    pool_plan = pool_plan_for(spec, geo, row_align=cfg.block_size)
 
     # ---- static requant shift (+ overflow check) ----
     acc_pre_shift = reference_layer_acc(A, B, spec.bias, spec.relu, pool_plan)
@@ -423,34 +514,12 @@ def compile_layer(spec: LayerSpec, inp: np.ndarray, *,
             constraint="requant-int8-range")
 
     # ---- ALU program over ACC vectors (block-major indices) ----
-    pad_h = should_pad_height(A)
-    row_height = bs if pad_h else M
-    beta = pad_to_multiple(N, bs) // bs
     alu_ops: List[object] = []
     if spec.relu:
         alu_ops.append(AluImmOp.relu())
     if pool_plan is not None:
-        pool_op = isa.AluOp.MAX if pool_plan.mode == "max" else isa.AluOp.ADD
-        # One AluPairOp per dependency level: 2×2 windows are one flat
-        # independent set; the GAP tree emits one op per round so every
-        # instruction's (dst, src) lattice stays disjoint (vectorisable)
-        # while the read-after-write chain lives *between* instructions.
-        rounds = pool_plan.rounds or (pool_plan.add_pairs,)
-        for round_pairs in rounds:
-            pairs = []
-            for dst, src in round_pairs:
-                for j in range(beta):
-                    pairs.append((_vec_index(dst, j, beta, row_height),
-                                  _vec_index(src, j, beta, row_height)))
-            alu_ops.append(AluPairOp(pool_op, tuple(pairs)))
-        total_shift = pool_div + shift
-        if total_shift > 0:
-            idx = []
-            for r in pool_plan.keep_rows:
-                for j in range(beta):
-                    idx.append(_vec_index(r, j, beta, row_height))
-            alu_ops.append(AluIndexedImmOp(isa.AluOp.SHR, total_shift,
-                                           tuple(idx)))
+        alu_ops.extend(_pool_alu_ops(pool_plan, A, B, cfg.block_size,
+                                     pool_div + shift))
     elif shift > 0:
         alu_ops.append(AluImmOp.shr(shift))
 
@@ -469,7 +538,11 @@ def compile_layer(spec: LayerSpec, inp: np.ndarray, *,
     return CompiledLayer(spec=spec, program=prog, input_matrix=A,
                          weight_matrix=B, requant_shift=shift,
                          keep_rows=keep, out_h=out_h, out_w=out_w,
-                         ref_output_matrix=ref)
+                         ref_output_matrix=ref,
+                         gemm_rows=(geo.n_positions
+                                    if pool_plan is not None
+                                    and pool_plan.row_gather is not None
+                                    else None))
 
 
 def verify_layer(layer: CompiledLayer, *, backend: str = "oracle"):

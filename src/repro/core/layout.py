@@ -27,6 +27,25 @@ def truncate_int8(x: np.ndarray) -> np.ndarray:
     return np.asarray(x).astype(np.uint8).view(np.int8)
 
 
+def exact_matmul(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    """Integer ``a @ b`` as exact int64.  numpy multiplies integer
+    matrices without BLAS; float64 BLAS is exact while every partial sum
+    stays below 2**53, so that path is taken whenever the operands' bound
+    ``max|a| · max|b| · K`` proves it (always, for int8 operands and the
+    K of any compiled layer), and int64 otherwise."""
+    a = np.asarray(a)
+    b = np.asarray(b)
+    bound = _magnitude(a) * _magnitude(b) * a.shape[-1]
+    if bound < 2 ** 53:
+        return (a.astype(np.float64) @ b.astype(np.float64)).astype(np.int64)
+    return a.astype(np.int64) @ b.astype(np.int64)
+
+
+def _magnitude(x: np.ndarray) -> int:
+    """max |x| as a Python int (``np.abs`` of int8 -128 stays -128)."""
+    return max(-int(x.min(initial=0)), int(x.max(initial=0)))
+
+
 def requant_int8(x: np.ndarray, *, saturate: bool = False) -> np.ndarray:
     """Post-SHR ACC→OUT narrowing under the device's semantics: wrap
     (:func:`truncate_int8`) by default, clip with ``saturate=True`` —
@@ -92,20 +111,24 @@ class SplitMatrix:
         return self.blocks[i * self.block_cols + j]
 
 
+def split_shape(h: int, w: int, block_size: int) -> Tuple[int, int, int]:
+    """``(row_height, block_rows, block_cols)`` of §3.2's split of a padded
+    ``h × w`` matrix (the rule :func:`matrix_splitting` applies)."""
+    if w % block_size:
+        raise ValueError(f"width {w} not a multiple of block_size {block_size}")
+    row_height = block_size if h % block_size == 0 else h
+    if h % row_height:
+        raise ValueError(f"height {h} not splittable into rows of {row_height}")
+    return row_height, h // row_height, w // block_size
+
+
 def matrix_splitting(mat: np.ndarray, block_size: int) -> SplitMatrix:
     """§3.2: split a padded matrix into ``block_size``-wide blocks, row-major.
 
     Blocks are square except when the matrix is a single unpadded vector row
     (height < block_size), in which case each "block" is ``h × block_size``.
     """
-    h, w = mat.shape
-    if w % block_size:
-        raise ValueError(f"width {w} not a multiple of block_size {block_size}")
-    row_height = block_size if h % block_size == 0 else h
-    if h % row_height:
-        raise ValueError(f"height {h} not splittable into rows of {row_height}")
-    block_rows = h // row_height
-    block_cols = w // block_size
+    row_height, block_rows, block_cols = split_shape(*mat.shape, block_size)
     blocks = [
         np.ascontiguousarray(mat[i * row_height:(i + 1) * row_height,
                                  j * block_size:(j + 1) * block_size])
@@ -196,13 +219,11 @@ def batch_matrix_to_binary(mats: np.ndarray, block_size: int,
         raise ValueError(f"expected a (B, M, K) stack, got {mats.shape}")
     b, h, w = mats.shape
     # all images share one geometry — derive it through the single-image
-    # helpers (one representative pass) so the rules can never drift
-    split0 = matrix_splitting(
-        matrix_padding(mats[0], block_size,
-                       pad_height=should_pad_height(mats[0])), block_size)
-    new_h, new_w = split0.padded_shape
-    row_height, br, bc = (split0.row_height, split0.block_rows,
-                          split0.block_cols)
+    # rules (the padding of the first image, split_shape) so they can
+    # never drift
+    new_h, new_w = matrix_padding(mats[0], block_size,
+                                  pad_height=should_pad_height(mats[0])).shape
+    row_height, br, bc = split_shape(new_h, new_w, block_size)
     padded = np.zeros((b, new_h, new_w), dtype=mats.dtype)
     padded[:, :h, :w] = mats
     blocks = padded.reshape(b, br, row_height, bc, block_size)

@@ -23,17 +23,18 @@ the traceability check the paper's workflow enables.
 from __future__ import annotations
 
 import dataclasses
+import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .conv_lowering import flatten_tensor, im2row_batch, tensor2mat
+from .conv_lowering import ConvGeometry, gather_rows, im2row_batch
 from .cycle_model import CycleReport, analyze_programs
 from .dram import DramAllocator
 from .errors import CompileError
 from .hwconfig import VTAConfig, vta_default
 from .layer_compiler import (CompiledLayer, LayerSpec, compile_layer,
-                             decode_layer_output, layer_matrices)
+                             decode_layer_output, layer_matrices, row_gather)
 from .layout import (batch_matrix_to_binary, matrix_to_binary,
                      should_pad_height)
 from .simulator import (SimReport, decode_out_region, decode_out_region_batch,
@@ -121,7 +122,8 @@ class NetworkProgram:
                 sem_in = (self.input_tensor if srcs[k] < 0
                           else sems[srcs[k]])
                 A, _, _ = layer_matrices(layer.spec,
-                                         np.asarray(sem_in, dtype=np.int8))
+                                         np.asarray(sem_in, dtype=np.int8),
+                                         row_align=self.config.block_size)
                 if check_chaining:
                     np.testing.assert_array_equal(
                         A, layer.input_matrix,
@@ -226,7 +228,8 @@ class NetworkProgram:
         binarise → written into the layer's INP region of ``dram_row``
         (a view into the batch stack, so writes land in place)."""
         A, _, _ = layer_matrices(layer.spec,
-                                 np.asarray(semantic_input, dtype=np.int8))
+                                 np.asarray(semantic_input, dtype=np.int8),
+                                 row_align=self.config.block_size)
         inp_bin, _ = matrix_to_binary(A, self.config.block_size,
                                       self.config.inp_dtype)
         region = layer.program.regions["inp"]
@@ -249,8 +252,13 @@ class NetworkProgram:
         spec = layer.spec
         arrs = np.stack([np.asarray(s, dtype=np.int8) for s in sems])
         if spec.kind == "conv":
-            _, _, kh, kw = spec.weights.shape
+            _, c, kh, kw = spec.weights.shape
             A = im2row_batch(arrs[:, 0], kh, kw, spec.stride, spec.padding)
+            geo = ConvGeometry(c, arrs.shape[-2], arrs.shape[-1], kh, kw,
+                               spec.stride, spec.padding)
+            gather = row_gather(spec, geo, self.config.block_size)
+            if gather is not None:
+                A = gather_rows(A, gather)
         else:
             A = arrs.reshape(len(sems), 1, -1)       # NCHW flatten / (1, D)
         raw = batch_matrix_to_binary(A, self.config.block_size,
@@ -425,6 +433,25 @@ class NetworkProgram:
             return self._serve_stack(imgs, backend, fault_hook,
                                      count_overflows)
 
+    def _fresh_stack(self, rows: int) -> np.ndarray:
+        """A ``(rows, nbytes)`` stack holding :meth:`dram_image` in every
+        row.  Each thread reuses the largest stack it has served this
+        network with: rewriting memory already mapped is a copy, where a
+        new stack also faults every page in (1.06 GB for ResNet-50's
+        batch of 8).  Only the calling thread ever sees its stack, and
+        :meth:`serve` returns copies, never views of it."""
+        held = self.__dict__.setdefault("_stacks", threading.local())
+        size = self.allocator.image_size()
+        buf = getattr(held, "stack", None)
+        if buf is None or len(buf) < rows:
+            buf = held.stack = np.empty((rows, size), dtype=np.uint8)
+        stack = buf[:rows]
+        stack[0] = 0
+        for layer in self.layers:
+            layer.program.place_segments(stack[0])
+        stack[1:] = stack[0]
+        return stack
+
     def _serve_stack(self, imgs: List[np.ndarray], backend: str, fault_hook,
                      count_overflows: bool):
         """:meth:`serve`'s layer loop over one ``(batch, nbytes)`` stack,
@@ -433,8 +460,7 @@ class NetworkProgram:
         from .fast_simulator import BatchFastSimulator, plan_for
         rows = len(imgs)
         with span("vta.stage"):
-            base = self.dram_image()
-            stack = np.broadcast_to(base, (rows, base.size)).copy()
+            stack = self._fresh_stack(rows)
         reports: List[SimReport] = []
         all_sems: List[List[np.ndarray]] = []   # per layer, per request
         srcs, rsrcs = self._sources(), self._res_sources()

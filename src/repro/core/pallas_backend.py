@@ -41,7 +41,8 @@ import numpy as np
 from . import isa
 from .errors import CompileError
 from .gemm_compiler import (AluImmOp, AluIndexedImmOp, AluPairOp,
-                            AluResidualOp, _wrap_int32)
+                            AluResidualOp, _wrap_int32, apply_pairs,
+                            pair_lattice)
 from .hwconfig import VTAConfig
 from .layout import truncate_int8
 from .simulator import SimReport
@@ -94,6 +95,10 @@ class PallasPlan:
     out: Tuple[int, int]
     acc: Optional[Tuple[int, int]]
     res: Optional[Tuple[int, int]]
+    # the pair ops' lattices, and the result rows (one image's input
+    # positions) the pool reads: the pooling part of the epilogue
+    pair_lattices: Tuple = dataclasses.field(default=(), compare=False)
+    pool_rows: int = 0
 
     @property
     def padded_shape(self) -> Tuple[int, int]:
@@ -141,6 +146,11 @@ def plan_pallas(prog) -> PallasPlan:
         return region.phys_addr - prog.allocator.offset, nbytes
 
     fused = _fused_form(prog.alu_ops)
+    pair_ops = [spec for spec in prog.alu_ops
+                if isinstance(spec, AluPairOp)]
+    pooled = np.unique(np.concatenate(
+        [np.asarray(spec.pairs, np.int64).reshape(-1) for spec in pair_ops]
+        or [np.zeros(0, np.int64)]))
     plan = PallasPlan(
         alpha=alpha, lam=lam, beta=beta, row_height=rh, block_size=bs,
         valid_shape=tuple(prog.output_meta.valid_shape),
@@ -154,7 +164,11 @@ def plan_pallas(prog) -> PallasPlan:
         acc=(_span("acc", alpha * beta * rh * bs * 4)
              if "acc" in prog.regions else None),
         res=(_span("res", alpha * beta * rh * bs * 4)
-             if "res" in prog.regions else None))
+             if "res" in prog.regions else None),
+        pair_lattices=tuple(pair_lattice(spec.op, spec.pairs)
+                            for spec in pair_ops),
+        # a vector index → its result row (block-major, §3.2)
+        pool_rows=len(np.unique(pooled // (beta * rh) * rh + pooled % rh)))
     prog._pallas_plan = plan
     return plan
 
@@ -252,6 +266,16 @@ def _decode_acc32(stack: np.ndarray, p: PallasPlan,
         b, p.alpha * p.row_height, p.beta * p.block_size)
 
 
+def _same_in_every_row(stack: np.ndarray, span: Tuple[int, int]) -> bool:
+    """Whether every row of ``stack`` holds the same bytes in ``span``
+    (compared eight bytes at a time where the size allows)."""
+    start, size = span
+    raw = stack[:, start:start + size]
+    if size % 8 == 0:
+        raw = raw.view(np.uint64)
+    return bool((raw[1:] == raw[:1]).all())
+
+
 def _encode_out(stack: np.ndarray, p: PallasPlan, out: np.ndarray) -> None:
     """(B, α·rh, β·bs) int8 result → OUT bytes, committed in place."""
     start, size = p.out
@@ -293,90 +317,76 @@ def _imm_apply(sel64: np.ndarray, op: isa.AluOp, imm: int) -> np.ndarray:
                        constraint="pallas-alu-op")
 
 
-def _pair_apply(vec: np.ndarray, op: isa.AluOp,
-                pairs: Tuple[Tuple[int, int], ...]) -> np.ndarray:
-    """``vec[:, dst] = op(vec[:, dst], vec[:, src])`` per pair, in pair
-    order.  Disjoint dst/src lattices (every pool/GAP lowering) vectorise
-    with duplicate-merging ufuncs — exact for ADD (mod-2³² congruence) and
-    MIN/MAX (idempotent merges); anything order-dependent falls back to the
-    sequential oracle loop."""
-    dst = np.fromiter((d for d, _ in pairs), dtype=np.int64, count=len(pairs))
-    src = np.fromiter((s for _, s in pairs), dtype=np.int64, count=len(pairs))
-    sequential = (np.intersect1d(dst, src).size > 0
-                  or (op not in (isa.AluOp.ADD, isa.AluOp.MIN, isa.AluOp.MAX)
-                      and len(np.unique(dst)) != len(dst)))
-    if sequential:
-        out = vec.copy()
-        for d, s in pairs:
-            a = out[:, d].astype(np.int64)
-            b = out[:, s].astype(np.int64)
-            if op == isa.AluOp.MIN:
-                r = np.minimum(a, b)
-            elif op == isa.AluOp.MAX:
-                r = np.maximum(a, b)
-            elif op == isa.AluOp.ADD:
-                r = a + b
-            elif op == isa.AluOp.SHR:
-                r = a >> (b & 31)
-            else:
-                raise CompileError(f"unsupported ALU pair op {op!r}",
-                                   constraint="pallas-alu-op")
-            out[:, d] = _wrap_int32(r)
-        return out
-    gathered = vec[:, src].astype(np.int64)
-    acc = vec.astype(np.int64)
-    idx = (slice(None), dst)
+def _add32(a: np.ndarray, b) -> np.ndarray:
+    """int32 addition with the ALU's wraparound, done in uint32 (whose
+    overflow is defined) — equal to the int64 sum wrapped to int32."""
+    return (a.view(np.uint32) + np.asarray(b, np.int32).view(np.uint32)) \
+        .view(np.int32)
+
+
+def _imm32(vec: np.ndarray, op: isa.AluOp, imm: int) -> np.ndarray:
+    """An immediate op over int32 vectors without widening them: MIN/MAX
+    and an arithmetic SHR are exact in int32 (a shift past 31 leaves the
+    sign, as in int64), ADD wraps as the ALU does; anything else takes
+    the int64 path."""
     if op == isa.AluOp.ADD:
-        np.add.at(acc, idx, gathered)
-    elif op == isa.AluOp.MAX:
-        np.maximum.at(acc, idx, gathered)
-    elif op == isa.AluOp.MIN:
-        np.minimum.at(acc, idx, gathered)
-    else:                                       # SHR with unique dst
-        acc[idx] = acc[idx] >> (gathered & 31)
-    out = vec.copy()
-    touched = np.unique(dst)
-    out[:, touched] = _wrap_int32(acc[:, touched])
-    return out
+        return _add32(vec, np.int64(imm).astype(np.int32))
+    if op in (isa.AluOp.MIN, isa.AluOp.MAX) and -2 ** 31 <= imm < 2 ** 31:
+        return (np.minimum if op == isa.AluOp.MIN else np.maximum)(
+            vec, np.int32(imm))
+    if op == isa.AluOp.SHR and imm >= 0:
+        return vec >> np.int32(min(imm, 31))
+    return _wrap_int32(_imm_apply(vec.astype(np.int64), op, imm))
 
 
-def apply_alu_epilogue(vec: np.ndarray, alu_ops,
+def apply_alu_epilogue(vec: np.ndarray, p: PallasPlan,
                        res_vec: Optional[np.ndarray]) -> np.ndarray:
-    """The full TensorAlu program over (B, n_vec, bs) int32 vectors —
-    op-for-op the semantics of ``gemm_compiler.reference_result``."""
+    """``p``'s whole TensorAlu program over (B, n_vec, bs) int32 vectors —
+    op-for-op the semantics of ``gemm_compiler.reference_result``.  The
+    pooling part (the pair ops and what follows them) runs inside a
+    ``vta.pool`` span that counts the input positions it reads."""
+    ops = p.alu_ops
+    lattices = iter(p.pair_lattices)
+    first_pair = next((i for i, spec in enumerate(ops)
+                       if isinstance(spec, AluPairOp)), len(ops))
+    vec = _alu_ops(vec, ops[:first_pair], res_vec, lattices)
+    if first_pair < len(ops):
+        with span("vta.pool", positions=len(vec) * p.pool_rows):
+            vec = _alu_ops(vec, ops[first_pair:], res_vec, lattices)
+    return vec
+
+
+def _alu_ops(vec: np.ndarray, alu_ops, res_vec: Optional[np.ndarray],
+             lattices) -> np.ndarray:
     for spec in alu_ops:
         if isinstance(spec, AluImmOp):
-            vec = _wrap_int32(_imm_apply(vec.astype(np.int64), spec.op,
-                                         spec.imm))
+            vec = _imm32(vec, spec.op, spec.imm)
         elif isinstance(spec, AluIndexedImmOp):
             idx = np.asarray(spec.indices, dtype=np.int64)
             vec = vec.copy()
-            vec[:, idx] = _wrap_int32(
-                _imm_apply(vec[:, idx].astype(np.int64), spec.op, spec.imm))
+            vec[:, idx] = _imm32(vec[:, idx], spec.op, spec.imm)
         elif isinstance(spec, AluPairOp):
-            vec = _pair_apply(vec, spec.op, spec.pairs)
+            vec = apply_pairs(vec, spec.op, next(lattices))
         elif isinstance(spec, AluResidualOp):
             if res_vec is None:
                 raise CompileError(
                     "AluResidualOp requires a staged residual operand",
                     constraint="residual-operand-missing")
-            r = res_vec.astype(np.int64)
+            r = res_vec
             if spec.pre_shift:
-                r = _wrap_int32(r >> spec.pre_shift).astype(np.int64)
-            a = vec.astype(np.int64)
+                r = _imm32(r, isa.AluOp.SHR, spec.pre_shift)
             if spec.op == isa.AluOp.MIN:
-                m = np.minimum(a, r)
+                vec = np.minimum(vec, r)
             elif spec.op == isa.AluOp.MAX:
-                m = np.maximum(a, r)
+                vec = np.maximum(vec, r)
             elif spec.op == isa.AluOp.ADD:
-                m = a + r
+                vec = _add32(vec, r)
             elif spec.op == isa.AluOp.SHR:
-                m = a >> (r & 31)
+                vec = vec >> (r & 31)
             else:
                 raise CompileError(
                     f"unsupported residual ALU op {spec.op!r}",
                     constraint="pallas-alu-op")
-            vec = _wrap_int32(m)
         else:
             raise CompileError(f"unknown ALU spec {type(spec).__name__}",
                                constraint="pallas-alu-op")
@@ -396,12 +406,13 @@ def _kernel_gemm(a: np.ndarray, b: np.ndarray, bias: Optional[np.ndarray],
     semantically identical lowered reference; "auto" picks per platform."""
     from repro.kernels import ops as kernel_ops
     with span("vta.kernel"):
-        with span("vta.kernel.put"):
+        sent = a.nbytes + b.nbytes + (bias.nbytes if bias is not None else 0)
+        with span("vta.kernel.put", bytes=sent):
             operands = jax.device_put((a, b, bias))
         out = kernel_ops.vta_matmul(
             *operands, relu=relu, shift=shift, saturate=saturate,
             out_dtype=out_dtype, backend=gemm_backend)
-        with span("vta.kernel.fetch"):
+        with span("vta.kernel.fetch", bytes=out.size * out.dtype.itemsize):
             return np.asarray(out)      # read-only, as jax buffers are
 
 
@@ -424,10 +435,15 @@ def _execute_stack(prog, stack: np.ndarray, *, saturate: bool,
     m, n = p.valid_shape
     with span("vta.decode"):
         a = _decode_inp(stack, p)                   # (B, Mp, Kp)
-        w = _decode_wgt(stack, p)                   # (B, Kp, Np)
-        x = _decode_acc32(stack, p, p.acc) if p.acc else None
+        # the weights and the preload are the same bytes in every row of
+        # a served stack: decode one row where they are
+        uniform_w = b == 1 or _same_in_every_row(stack, p.wgt)
+        w = _decode_wgt(stack[:1] if uniform_w else stack, p)
+        x = None
+        if p.acc:
+            uniform_x = b == 1 or _same_in_every_row(stack, p.acc)
+            x = _decode_acc32(stack[:1] if uniform_x else stack, p, p.acc)
         res = _decode_acc32(stack, p, p.res) if p.res else None
-        uniform_w = b == 1 or bool((w == w[0]).all())
 
         # A row-broadcast preload (the bias form every compiled layer
         # uses) fuses into the kernel.  The kernel broadcasts the bias to
@@ -447,7 +463,7 @@ def _execute_stack(prog, stack: np.ndarray, *, saturate: bool,
             a_pad_zero = bool((a[:, m:] == 0).all())
             if rows_equal and x_pad_zero and a_pad_zero:
                 bias, fuse_bias = x[:, 0], True
-        uniform_bias = bias is None or b == 1 or bool((bias == bias[0]).all())
+        uniform_bias = bias is None or bool((bias == bias[0]).all())
 
     if p.fused and fuse_bias:
         # -- whole program inside the kernel --------------------------------
@@ -459,6 +475,9 @@ def _execute_stack(prog, stack: np.ndarray, *, saturate: bool,
                 out_dtype=jnp.int8, gemm_backend=gemm_backend)
             out = out.reshape(b, mp, np_)
         else:
+            w = np.broadcast_to(w, (b,) + w.shape[1:])
+            if bias is not None:
+                bias = np.broadcast_to(bias, (b,) + bias.shape[1:])
             out = np.stack([
                 _kernel_gemm(a[i], w[i],
                              bias[i] if bias is not None else None,
@@ -483,11 +502,10 @@ def _execute_stack(prog, stack: np.ndarray, *, saturate: bool,
                 for i in range(b)])
         with span("vta.epilogue"):
             if x is not None:                       # ACC preload (C = A·B+X)
-                acc = _wrap_int32(acc.astype(np.int64)
-                                  + x.astype(np.int64))
+                acc = _add32(acc, x)
             vec = _to_vectors(acc, p)
             res_vec = _to_vectors(res, p) if res is not None else None
-            vec = apply_alu_epilogue(vec, p.alu_ops, res_vec)
+            vec = apply_alu_epilogue(vec, p, res_vec)
             out = _commit_int8(_to_matrix(vec, p), saturate)
 
     with span("vta.encode"):

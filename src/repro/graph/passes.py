@@ -29,11 +29,13 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from repro.core.conv_lowering import (ConvGeometry, im2row, ker2col,
-                                      mat2tensor)
+                                      mat2tensor, pooled_extent)
 from repro.core.errors import CompileError
 from repro.core.layer_compiler import (check_gap_geometry,
+                                       check_maxpool3x3_relu,
                                        check_stride_tiling,
                                        choose_requant_shift)
+from repro.core.layout import exact_matmul
 
 from .ir import Graph, Node
 
@@ -101,6 +103,8 @@ def _node_shape(node: Node, ins: List[Tuple[int, ...]]) -> Tuple[int, ...]:
         if len(s) != 4:
             raise CompileError(f"pool input must be 4-D, got {s}",
                                layer=node.name, constraint="pool-input-rank")
+        if node.mode == "max3x3s2":
+            return (s[0], s[1], pooled_extent(s[2]), pooled_extent(s[3]))
         if s[2] % 2 or s[3] % 2:
             raise CompileError(
                 f"2x2 pooling needs even spatial dims, got {s[2]}x{s[3]}",
@@ -180,8 +184,8 @@ def _eval_node(node: Node, ins: List[np.ndarray], refs: Tuple[str, ...],
         _check_int8(node, refs[0], ins[0], "conv input")
         x = ins[0].astype(np.int8)
         f, c, kh, kw = node.weights.shape
-        A = im2row(x, kh, kw, node.stride, node.padding).astype(np.int64)
-        acc = A @ ker2col(node.weights).astype(np.int64)
+        acc = exact_matmul(im2row(x, kh, kw, node.stride, node.padding),
+                           ker2col(node.weights))
         if node.bias is not None:
             acc = acc + node.bias.astype(np.int64)[None, :]
         _, _, h, w = ins[0].shape
@@ -189,7 +193,7 @@ def _eval_node(node: Node, ins: List[np.ndarray], refs: Tuple[str, ...],
         return mat2tensor(acc, geo.out_h, geo.out_w)
     if node.kind == "fc":
         _check_int8(node, refs[0], ins[0], "fc input")
-        acc = ins[0] @ node.weights.astype(np.int64)
+        acc = exact_matmul(ins[0], node.weights)
         if node.bias is not None:
             acc = acc + node.bias.astype(np.int64)[None, :]
         return acc
@@ -197,6 +201,8 @@ def _eval_node(node: Node, ins: List[np.ndarray], refs: Tuple[str, ...],
         return np.maximum(ins[0], 0)
     if node.kind == "pool":
         t = ins[0]
+        if node.mode == "max3x3s2":
+            return _max3x3s2(t)
         q = (t[:, :, 0::2, 0::2], t[:, :, 0::2, 1::2],
              t[:, :, 1::2, 0::2], t[:, :, 1::2, 1::2])
         if node.mode == "max2x2":
@@ -223,6 +229,18 @@ def _eval_node(node: Node, ins: List[np.ndarray], refs: Tuple[str, ...],
         return ins[0].reshape(1, -1)
     raise CompileError(f"unknown node kind {node.kind!r}", layer=node.name,
                        constraint="node-kind")
+
+
+def _max3x3s2(t: np.ndarray) -> np.ndarray:
+    """3×3/stride-2 max with padding 1: each window's maximum over its
+    positions inside the map (the padding never wins)."""
+    n, c, h, w = t.shape
+    low = np.iinfo(np.int64).min
+    padded = np.full((n, c, h + 2, w + 2), low, dtype=np.int64)
+    padded[:, :, 1:-1, 1:-1] = t
+    oh, ow = pooled_extent(h), pooled_extent(w)
+    return np.max([padded[:, :, i:i + 2 * oh:2, j:j + 2 * ow:2]
+                   for i in range(3) for j in range(3)], axis=0)
 
 
 # ---------------------------------------------------------------------------
@@ -376,7 +394,7 @@ class Step:
     stride: int
     padding: int
     relu: bool
-    pool: Optional[str]              # max2x2 | avg2x2 | None
+    pool: Optional[str]              # max2x2 | avg2x2 | max3x3s2 | gap | None
     requant_shift: int               # LayerSpec shift (pool ÷4 excluded)
     residual_source: Optional[str] = None
     residual_pre_shift: int = 0
@@ -390,7 +408,8 @@ def linearize(graph: Graph) -> List[Step]:
 
         conv → [relu] → [pool|global_avg_pool] → requant       (linear)
         fc   → [relu] → requant                                (linear)
-        conv|fc → requant → add(·, skip) → [relu] → requant    (residual)
+        conv|fc → requant → add(·, skip) → [relu]
+                → [global_avg_pool] → requant                  (residual)
 
     plus ``flatten`` folded into the fc that consumes it.  Anything else
     raises :class:`CompileError`.  Requant shifts must be planned first.
@@ -458,6 +477,8 @@ def linearize(graph: Graph) -> List[Step]:
             else:
                 pool = nxt.mode
                 pool_div = AVG_POOL_DIV if pool == "avg2x2" else 0
+                if pool == "max3x3s2":
+                    check_maxpool3x3_relu(relu, layer=nxt.name)
             chain.append(nxt.name)
             cur = nxt.name
             nxt = graph.node(single(cur, "pool result must fuse"))
@@ -485,9 +506,9 @@ def linearize(graph: Graph) -> List[Step]:
             if maybe_add.kind == "add":
                 other = [r for r in maybe_add.inputs if r != q.name]
                 if len(other) == 1 and other[0] in materialized:
-                    step = _residual_step(graph, cons, node, chain, in_value,
-                                          q_shift, maybe_add, other[0],
-                                          single, shift_of)
+                    step = _residual_step(graph, cons, shapes, node, chain,
+                                          in_value, q_shift, maybe_add,
+                                          other[0], single, shift_of)
         if step is None:
             step = Step(name=name, kind=node.kind,
                         node_names=tuple(chain), input_value=in_value,
@@ -514,10 +535,11 @@ def linearize(graph: Graph) -> List[Step]:
     return steps
 
 
-def _residual_step(graph: Graph, cons, linear: Node, chain: List[str],
-                   in_value: str, q_shift: int, add: Node, skip: str,
-                   single, shift_of) -> Step:
-    """Fuse ``linear → requant → add(·, skip) → [relu] → requant``."""
+def _residual_step(graph: Graph, cons, shapes, linear: Node,
+                   chain: List[str], in_value: str, q_shift: int, add: Node,
+                   skip: str, single, shift_of) -> Step:
+    """Fuse ``linear → requant → add(·, skip) → [relu] →
+    [global_avg_pool] → requant``."""
     if add.pre_shifts is None:
         raise CompileError("add pre-shifts unplanned — run plan_requant",
                            layer=add.name, constraint="requant-planned")
@@ -533,19 +555,31 @@ def _residual_step(graph: Graph, cons, linear: Node, chain: List[str],
         chain.append(nxt.name)
         cur = nxt.name
         nxt = graph.node(single(cur, "relu result must fuse"))
+    pool, pool_div = None, 0
+    if nxt.kind == "global_avg_pool":
+        pool, pool_div = "gap", _gap_div(shapes[nxt.inputs[0]])
+        chain.append(nxt.name)
+        cur = nxt.name
+        nxt = graph.node(single(cur, "pool result must fuse"))
     if nxt.kind != "requant":
         raise CompileError(
             f"residual add must be requantised before any other consumer "
             f"(found {nxt.kind} {nxt.name!r})", layer=add.name,
             constraint="requant-required")
     chain.append(nxt.name)
+    post_shift = shift_of(nxt.name)
+    if post_shift < pool_div:
+        raise CompileError(
+            f"requant after a pooled reduction must shift by >= "
+            f"{pool_div} (the fused division), got {post_shift}",
+            layer=nxt.name, constraint="gap-min-shift")
     return Step(name=linear.name, kind=linear.kind, node_names=tuple(chain),
                 input_value=in_value, output_value=nxt.name,
                 weights=linear.weights, bias=linear.bias,
                 stride=linear.stride, padding=linear.padding, relu=relu,
-                pool=None,
+                pool=pool,
                 # the branch operand's scale-equalising shift folds into
                 # the pre-add requant: (x >> q) >> pre == x >> (q + pre)
                 requant_shift=q_shift + branch_pre,
                 residual_source=skip, residual_pre_shift=skip_pre,
-                residual_shift=shift_of(nxt.name))
+                residual_shift=post_shift - pool_div)

@@ -98,6 +98,35 @@ def test_lenet_serve_reuses_cached_plans(lenet):
                for l, p in zip(net.layers, plans_first))
 
 
+@pytest.mark.parametrize("backend", ["batched", "pallas"])
+def test_lenet_serve_reuses_its_stack_without_carrying_rows_over(lenet,
+                                                                 backend):
+    """A thread serves into the largest stack it has served with, rewritten
+    from the DRAM image each time: a smaller batch after a larger one,
+    and a batch on another thread, answer as on a fresh network, and the
+    answers handed back are not views of the stack."""
+    import threading
+    weights, _ = lenet
+    net = compile_network(lenet5_specs(weights), synthetic_digit(0))
+    fresh = compile_network(lenet5_specs(weights), synthetic_digit(0))
+    big, small = _digits(4, seed=5), _digits(3, seed=6)
+    first, _ = net.serve(big, backend=backend)
+    stack = net._stacks.stack
+    second, _ = net.serve(small, backend=backend)
+    assert net._stacks.stack is stack and len(stack) == len(big)
+    np.testing.assert_array_equal(second, fresh.serve(small)[0])
+    assert not np.shares_memory(first, stack)
+    np.testing.assert_array_equal(first, fresh.serve(big)[0])
+    other = {}
+    worker = threading.Thread(target=lambda: other.update(
+        out=net.serve(small, backend=backend)[0],
+        stack=net._stacks.stack))
+    worker.start()
+    worker.join()
+    assert other["stack"] is not stack
+    np.testing.assert_array_equal(other["out"], second)
+
+
 def test_lenet_serve_report_totals(lenet):
     """Batched reports carry batch totals: loop counts are batch × the
     single-image program counts."""

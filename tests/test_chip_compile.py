@@ -11,14 +11,23 @@ shapes come from ``plan_pallas`` → ``kernel_call``;
 ``test_rehearsal_calls_are_what_serving_runs`` pins that serving makes
 exactly those calls.  The topology is described inside a
 fixture, never at import, so every test worker collects the same tests.
+
+ResNet-50's calls at the benchmark's rungs 1 and 8 are compiled too, as
+the program serving runs; they are derived from its layer shapes and the
+configuration's shifts (:func:`_resnet50_calls`), so that the whole net
+is never compiled on the CPU, and the derivation is checked against the
+calls a small compiled ResNet-50 makes.
 """
 
+import json
 import re
+from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import pytest
 
-from repro.core.pallas_backend import kernel_call, plan_pallas
+from repro.core.pallas_backend import KernelCall, kernel_call, plan_pallas
 from repro.kernels import ops
 from repro.kernels.vta_gemm import vta_gemm
 
@@ -148,3 +157,62 @@ def test_rehearsal_calls_are_what_serving_runs(net_name, nets, monkeypatch):
     for rung in RUNGS:
         net.serve(images[:rung], backend="pallas")
     assert seen == set().union(*(_kernel_calls(net, r) for r in RUNGS))
+
+
+def _round_up(x, m):
+    return -(-x // m) * m
+
+
+def _resnet50_calls(layers, shifts, rung, bs=16):
+    """The kernel calls a pallas serve of ``rung`` images runs for a
+    ResNet-50 of ``layers`` (``models/resnet50.py linear_layers``) with
+    the planned ``shifts``, from the shapes alone: the stem's rows are the
+    padded 3×3/s2 max pool's bands, its pool and the joins (``c``) finish
+    in the epilogue on an int32 GEMM, every other layer runs whole in the
+    kernel (bias, ReLU on ``a``/``b``, its requant shift)."""
+    calls = set()
+    for layer in layers:
+        name = layer["name"]
+        if layer["kind"] == "fc":
+            m, k, n = 1, layer["in"], layer["out"]
+        else:
+            kk, s, p = layer["kernel"], layer["stride"], layer["padding"]
+            out = (layer["in_size"] + 2 * p - kk) // s + 1
+            m, k, n = out * out, layer["in"] * kk * kk, layer["out"]
+        if name == "stem":
+            m = ((out - 1) // 2 + 1) * _round_up(3 * out, bs)
+        shape = dict(m=rung * (_round_up(m, bs) if m > 1 else 1),
+                     k=_round_up(k, bs), n=_round_up(n, bs))
+        if name == "stem" or (layer["kind"] == "conv"
+                              and name.endswith("c")):
+            calls.add(KernelCall(**shape, bias=False, relu=False, shift=0,
+                                 out_dtype=jnp.int32))
+        else:
+            calls.add(KernelCall(**shape, bias=True,
+                                 relu=name.endswith(("a", "b")),
+                                 shift=shifts[f"{name}_q"],
+                                 out_dtype=jnp.int8))
+    return calls
+
+
+@pytest.mark.parametrize("rung", (1, 8))
+def test_resnet50_calls_from_shapes_are_what_the_program_compiles(rung):
+    from repro.models import resnet50 as r50
+    geometry = dict(base_width=16, blocks=(1, 2, 1, 1), image_size=64)
+    net, graph = r50.compile_resnet50(**geometry)
+    assert _resnet50_calls(r50.linear_layers(**geometry),
+                           r50.graph_shifts(graph), rung) == {
+        kernel_call(plan_pallas(layer.program), rung)
+        for layer in net.layers}
+
+
+@pytest.mark.parametrize("rung", (1, 8))
+def test_resnet50_padded_vta_gemm_compiles_for_v5e(rung, one_chip):
+    cfg = json.loads((Path(__file__).resolve().parents[1] / "bench" /
+                      "configs" / "resnet50.json").read_text())
+    calls = _resnet50_calls(cfg["layers"], cfg["requant_shifts"], rung)
+    for call in calls:
+        args, statics = call.matmul_args(sharding=one_chip)
+        compiled = ops._padded_vta_gemm.lower(*args, interpret=False,
+                                              **statics).compile()
+        assert "tpu_custom_call" in compiled.as_text(), call

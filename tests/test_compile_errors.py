@@ -108,15 +108,22 @@ def test_strided_geometry_diagnostics():
 
 
 def test_gap_geometry_diagnostics():
-    """Global average pooling needs a square power-of-two map (the ÷H·W
-    must be one exact SHR) and never compiles a straddling tree."""
+    """Global average pooling needs a square map, takes any position
+    count (the ÷H·W beyond its power of two is the requant's scale) and
+    never compiles a straddling tree."""
     rng = np.random.default_rng(4)
     w = rng.integers(-4, 5, (4, 2, 1, 1)).astype(np.int8)
     gap = lambda: LayerSpec("g", "conv", w, relu=True, pool="gap")
     _raises("gap-square", compile_layer, gap(),
             rng.integers(-16, 17, (1, 2, 8, 4)).astype(np.int8))
-    _raises("gap-pow2", compile_layer, gap(),
-            rng.integers(-16, 17, (1, 2, 6, 6)).astype(np.int8))
+    # a 6×6 map (36 positions) sums every position, then SHR by 5
+    t6 = rng.integers(-16, 17, (1, 2, 6, 6)).astype(np.int8)
+    layer = compile_layer(gap(), t6)
+    acc = np.maximum(np.einsum("chw,fc->fhw", t6[0].astype(np.int64),
+                               w[:, :, 0, 0].astype(np.int64)), 0).sum((1, 2))
+    np.testing.assert_array_equal(
+        layer.ref_output_matrix[0],
+        (acc >> (5 + layer.requant_shift)).astype(np.int8))
     # a GAP result too large for one ACC residency refuses to compile
     # (the tree's pair groups may not straddle chunks)
     from repro.core.hwconfig import VTAConfig
@@ -145,11 +152,11 @@ def test_graph_builder_rejects_strided_geometry_early():
     _raises("conv-stride-tiling", infer_shapes, bld.build())
 
     bld2 = GraphBuilder("bad_gap")
-    x = bld2.input("x", shape=(1, 2, 6, 6))
+    x = bld2.input("x", shape=(1, 2, 6, 4))
     v = bld2.conv("c", x, rng.integers(-4, 5, (4, 2, 1, 1)).astype(np.int8))
     v = bld2.global_avg_pool("g", v)
     bld2.output(v)
-    _raises("gap-pow2", infer_shapes, bld2.build())
+    _raises("gap-square", infer_shapes, bld2.build())
 
 
 def test_requant_overflow_diagnostic():

@@ -173,7 +173,22 @@ def test_global_avgpool_tree_sums_every_row(log_hw, cols, seed):
 
 
 def test_global_avgpool_plan_rejects_bad_maps():
+    """A map that is not square is refused; one whose position count is
+    not a power of two (ResNet-50's 7×7) folds every row into row 0 all
+    the same, in ceil(log2(H·W)) disjoint rounds, and divides by the
+    power of two below H·W."""
     with pytest.raises(ValueError, match="square"):
         global_avgpool_plan(4, 8)
-    with pytest.raises(ValueError, match="power-of-two"):
-        global_avgpool_plan(6, 6)
+    for hw in (3, 6, 7):
+        plan = global_avgpool_plan(hw, hw)
+        n = hw * hw
+        assert len(plan.rounds) == (n - 1).bit_length()
+        assert plan.div_shift == n.bit_length() - 1
+        for rnd in plan.rounds:
+            dsts = {d for d, _ in rnd}
+            assert len(dsts) == len(rnd) and not dsts & {s for _, s in rnd}
+        mat = np.arange(n * 3, dtype=np.int64).reshape(n, 3) ** 2
+        work = mat.copy()
+        for dst, src in plan.add_pairs:
+            work[dst] += work[src]
+        np.testing.assert_array_equal(work[0], mat.sum(axis=0))

@@ -6,11 +6,14 @@ and one engine batch of 3 requests (padded to a rung of 4) record every
 span of the serving path, nested on one thread as the layers call each
 other, with the counts the benchmark's readers sum: ``issued_macs`` is
 the padded ``vta_gemm`` call's ``gemm_blocks`` product, ``useful_macs``
-the layer's valid M·K·N per row, and ``rows``/``real``/``first_rid``/
-``worker`` of ``engine.execute`` those of the batch's ``RequestRecord``s.
+the layer's valid M·K·N per row, ``bytes`` what each kernel call sends
+and fetches, ``positions`` what each pool reads, and ``rows``/``real``/
+``first_rid``/``worker`` of ``engine.execute`` those of the batch's
+``RequestRecord``s.
 """
 
 import jax
+import jax.numpy as jnp
 import pytest
 from jax.profiler import ProfileData
 
@@ -31,6 +34,7 @@ NESTING = {
                   "vta.encode", "vta.readout"},
     "vta.kernel": {"vta.kernel.put", "vta.kernel.dispatch",
                    "vta.kernel.fetch"},
+    "vta.epilogue": {"vta.pool"},
 }
 SPANS = {"engine.batch_form"} | set(NESTING) | set().union(*NESTING.values())
 
@@ -151,6 +155,35 @@ def test_counts_are_the_kernel_calls_and_the_layers_work(traced, lenet):
             dispatch, = [n for n in layer_span.walk()
                          if n.name == "vta.kernel.dispatch"]
             assert dispatch.stats == {"issued_macs": g.m * g.k * g.n}
+
+
+def test_pool_positions_and_kernel_bytes(traced, lenet):
+    """``vta.pool`` counts the input positions each pooled layer's
+    epilogue reads (rows × the layer's pooled result rows); the kernel
+    call's put counts the operands it sends, its fetch the result."""
+    threads, _ = traced
+    serves = [n for roots in threads for r in roots for n in r.walk()
+              if n.name == "vta.serve"]
+    for serve in serves:
+        rows = serve.stats["rows"]
+        for layer_span, layer in zip(serve.children[1:], lenet.layers):
+            p = plan_pallas(layer.program)
+            call = kernel_call(p, rows)
+            put, = [n for n in layer_span.walk()
+                    if n.name == "vta.kernel.put"]
+            fetch, = [n for n in layer_span.walk()
+                      if n.name == "vta.kernel.fetch"]
+            assert put.stats == {"bytes": call.m * call.k + call.k * call.n
+                                 + 4 * call.n * call.bias}
+            assert fetch.stats == {"bytes": call.m * call.n * (
+                1 if call.out_dtype == jnp.int8 else 4)}
+            pools = [n for n in layer_span.walk() if n.name == "vta.pool"]
+            if layer.spec.pool is None:
+                assert not pools and p.pool_rows == 0
+            else:
+                pool, = pools
+                assert p.pool_rows == layer.input_matrix.shape[0] > 0
+                assert pool.stats == {"positions": rows * p.pool_rows}
 
 
 def test_engine_spans_match_the_request_records(traced):
