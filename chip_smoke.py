@@ -13,9 +13,9 @@ widths with seeded weights, it
 2. serves 16 requests through ``VTAServingEngine`` with two pallas workers,
    in bursts that use several rungs of the batch ladder, and checks every
    answer against a direct serve and the metrics audit;
-3. lowers one layer's kernel call as serving makes it (one program: the
-   pads, ``vta_gemm``, the slice) and checks that it holds a compiled TPU
-   kernel (``tpu_custom_call``).
+3. lowers one layer's kernel call as serving makes it (one program: A
+   staged from the activations, the pads, ``vta_gemm``, the slice) and
+   checks that it holds a compiled TPU kernel (``tpu_custom_call``).
 
 JAX's persistent compilation cache lives where ``JAX_COMPILATION_CACHE_DIR``
 says, or else in ``.jax_cache/`` of the checkout; the compile lines report
@@ -42,7 +42,7 @@ BATCH = 8
 BURSTS = (8, 3, 4, 1)
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-KERNEL_FUN = "jit(_padded_vta_gemm)"
+KERNEL_FUN = "jit(_staged_vta_gemm)"
 
 
 class SmokeFailure(RuntimeError):
@@ -174,19 +174,20 @@ def engine_phase(name, net, images, log) -> None:
 
 def kernel_phase(name, net) -> None:
     """Lower the first layer's kernel call at batch 8 as serving makes it:
-    one program holding the pads, ``vta_gemm`` and the slice."""
-    from repro.core.pallas_backend import kernel_call, plan_pallas
-    from repro.kernels.ops import _padded_vta_gemm, pallas_interpret
+    one program holding A's staging, the pads, ``vta_gemm`` and the
+    slice."""
+    from repro.core.pallas_backend import kernel_call
+    from repro.kernels.ops import _staged_vta_gemm, pallas_interpret
 
-    args, statics = kernel_call(plan_pallas(net.layers[0].program),
-                                BATCH).matmul_args()
-    text = _padded_vta_gemm.lower(*args, interpret=pallas_interpret(),
+    args, statics = kernel_call(net.layers[0], BATCH).matmul_args()
+    text = _staged_vta_gemm.lower(*args, interpret=pallas_interpret(),
                                   **statics).as_text()
     require("tpu_custom_call" in text,
             f"{name}: the lowered kernel call holds no tpu_custom_call")
-    print(f"{name}: layer 0 kernel call {args[0].shape} @ {args[1].shape} "
-          f"(pads, vta_gemm, slice) lowers to a tpu_custom_call (compiled "
-          f"Mosaic kernel, not interpreted)", flush=True)
+    print(f"{name}: layer 0 kernel call, activations {args[0].shape} "
+          f"staged to A, A @ W {args[2].shape} (staging, pads, vta_gemm, "
+          f"slice) lowers to a tpu_custom_call (compiled Mosaic kernel, not "
+          f"interpreted)", flush=True)
 
 
 def main() -> None:

@@ -34,7 +34,7 @@ import numpy as np
 
 from .conv_lowering import (ConvGeometry, PoolPlan, avgpool2x2_plan,
                             flatten_tensor, gather_rows, global_avgpool_plan,
-                            im2row, ker2col, mat2tensor, maxpool2x2_plan,
+                            im2row, ker2col, maxpool2x2_plan,
                             maxpool3x3s2_plan, tensor2mat)
 from .dram import DramAllocator
 from .errors import CompileError
@@ -105,6 +105,9 @@ class CompiledLayer:
     # positions) where ``input_matrix`` holds more: the padded 3×3/s2 max
     # pool's bands repeat halo rows and pad each band with zero rows.
     gemm_rows: Optional[int] = None
+    # The convolution's geometry (its input tensor's shape), None for fc:
+    # serving stages A from the activations with it (``pallas_backend``).
+    geometry: Optional[ConvGeometry] = None
 
     @property
     def gemm_loops(self) -> int:
@@ -445,7 +448,8 @@ def _compile_residual_layer(spec: LayerSpec, A: np.ndarray, B: np.ndarray,
                                     if pool_plan is not None else None),
                          out_h=out_h, out_w=out_w,
                          ref_output_matrix=truncate_int8(final),
-                         residual_matrix=R, residual_shift=s_add)
+                         residual_matrix=R, residual_shift=s_add,
+                         geometry=geo)
 
 
 def _pool_alu_ops(pool_plan: PoolPlan, A: np.ndarray, B: np.ndarray,
@@ -542,7 +546,8 @@ def compile_layer(spec: LayerSpec, inp: np.ndarray, *,
                          gemm_rows=(geo.n_positions
                                     if pool_plan is not None
                                     and pool_plan.row_gather is not None
-                                    else None))
+                                    else None),
+                         geometry=geo)
 
 
 def verify_layer(layer: CompiledLayer, *, backend: str = "oracle"):
@@ -561,8 +566,18 @@ def decode_layer_output(layer: CompiledLayer, out_matrix: np.ndarray
     conv → ``(1, F, H', W')`` tensor (pooled rows extracted first);
     fc   → ``(rows, F)`` matrix.
     """
+    return decode_layer_output_batch(layer, out_matrix[None])[0]
+
+
+def decode_layer_output_batch(layer: CompiledLayer, out_matrices: np.ndarray
+                              ) -> np.ndarray:
+    """:func:`decode_layer_output` over a ``(B, M, N)`` stack of output
+    matrices: ``(B, 1, F, H', W')`` for conv, ``(B, rows, F)`` for fc —
+    row ``b`` is ``decode_layer_output(layer, out_matrices[b])``."""
     if layer.keep_rows is not None:
-        out_matrix = out_matrix[list(layer.keep_rows)]
+        out_matrices = out_matrices[:, list(layer.keep_rows)]
     if layer.spec.kind == "conv":
-        return mat2tensor(out_matrix, layer.out_h, layer.out_w)
-    return out_matrix
+        b, _, f = out_matrices.shape
+        return np.ascontiguousarray(out_matrices.reshape(
+            b, layer.out_h, layer.out_w, f).transpose(0, 3, 1, 2)[:, None])
+    return out_matrices

@@ -34,7 +34,8 @@ from .dram import DramAllocator
 from .errors import CompileError
 from .hwconfig import VTAConfig, vta_default
 from .layer_compiler import (CompiledLayer, LayerSpec, compile_layer,
-                             decode_layer_output, layer_matrices, row_gather)
+                             decode_layer_output, decode_layer_output_batch,
+                             layer_matrices, row_gather)
 from .layout import (batch_matrix_to_binary, matrix_to_binary,
                      should_pad_height)
 from .simulator import (SimReport, decode_out_region, decode_out_region_batch,
@@ -399,9 +400,11 @@ class NetworkProgram:
 
         ``backend="batched"`` (default) runs the vectorised instruction
         interpreter; ``backend="pallas"`` executes each layer as a fused
-        MXU kernel call over the whole stack
-        (:mod:`repro.core.pallas_backend`; interpreted only on the CPU) —
-        bit-identical to the simulators on its truncation path.
+        MXU kernel call over the whole batch, sending the layer's
+        activations and staging A on the device, with no DRAM stack
+        (:func:`repro.core.pallas_backend.serve_layer`; interpreted only
+        on the CPU) — bit-identical to the simulators on its truncation
+        path.
 
         Returns ``(stacked outputs, per-layer batch-total reports)``: the
         leading output axis is the request index.
@@ -454,49 +457,86 @@ class NetworkProgram:
 
     def _serve_stack(self, imgs: List[np.ndarray], backend: str, fault_hook,
                      count_overflows: bool):
-        """:meth:`serve`'s layer loop over one ``(batch, nbytes)`` stack,
-        with a span at each boundary (``vta.layer`` > ``vta.stage``, the
-        backend's own spans, ``vta.readout``)."""
-        from .fast_simulator import BatchFastSimulator, plan_for
+        """:meth:`serve`'s layer loop, with a span at each boundary
+        (``vta.layer`` > ``vta.stage``, the backend's own spans,
+        ``vta.readout``).  ``batched`` runs each layer over one ``(batch,
+        nbytes)`` DRAM stack; ``pallas`` sends each layer's activations to
+        the kernel, which stages A on the device, and keeps no DRAM image
+        (:func:`~repro.core.pallas_backend.serve_layer`)."""
         rows = len(imgs)
-        with span("vta.stage"):
-            stack = self._fresh_stack(rows)
+        stack = None
+        if backend == "batched":
+            with span("vta.stage"):
+                stack = self._fresh_stack(rows)
+        elif fault_hook is not None:
+            raise ValueError(
+                "fault_hook requires per-instruction execution; the pallas "
+                "backend has no instruction stream to hook (use "
+                "backend='oracle'/'fast'/'batched' for injection)")
         reports: List[SimReport] = []
-        all_sems: List[List[np.ndarray]] = []   # per layer, per request
+        all_sems: List[np.ndarray] = []         # per layer: (B, ...) stack
         srcs, rsrcs = self._sources(), self._res_sources()
         for k, layer in enumerate(self.layers):
             with span("vta.layer", layer=k, useful_macs=rows * layer.macs):
-                with span("vta.stage"):
-                    src_sems = (imgs if k == 0 or srcs[k] < 0
-                                else all_sems[srcs[k]])
-                    self._stage_layer_input_batch(stack, layer, src_sems)
-                    if rsrcs[k] is not None:
-                        res_sems = (imgs if rsrcs[k] < 0
-                                    else all_sems[rsrcs[k]])
-                        self._stage_residual_batch(stack, layer, res_sems)
-                # the loop owns ``stack`` and re-reads it from ``sim.dram``,
-                # so the engine's defensive copy is skipped
+                src_sems = (imgs if k == 0 or srcs[k] < 0
+                            else all_sems[srcs[k]])
+                res_sems = None
+                if rsrcs[k] is not None:
+                    res_sems = (imgs if rsrcs[k] < 0
+                                else all_sems[rsrcs[k]])
                 if backend == "pallas":
-                    from .pallas_backend import BatchPallasSimulator
-                    sim = BatchPallasSimulator(self.config, stack,
-                                               copy_dram=False)
-                    reports.append(sim.run_program(
-                        layer.program,
-                        fault_hook=self._layer_hook(fault_hook, k)))
+                    out_mats, report = self._pallas_layer(layer, src_sems,
+                                                          res_sems)
                 else:
-                    sim = BatchFastSimulator(self.config, stack,
-                                             copy_dram=False,
-                                             count_overflows=count_overflows)
-                    reports.append(sim.run(layer.program.instructions,
-                                           plan=plan_for(layer.program),
-                                           fault_hook=self._layer_hook(
-                                               fault_hook, k)))
-                stack = sim.dram
+                    stack, report = self._batched_layer(
+                        stack, layer, src_sems, res_sems,
+                        self._layer_hook(fault_hook, k), count_overflows)
+                reports.append(report)
                 with span("vta.readout"):
-                    out_mats = decode_out_region_batch(layer.program, stack)
-                    all_sems.append([decode_layer_output(layer, m)
-                                     for m in out_mats])
-        return np.stack(all_sems[-1]), reports
+                    if stack is not None:
+                        out_mats = decode_out_region_batch(layer.program,
+                                                           stack)
+                    all_sems.append(decode_layer_output_batch(layer,
+                                                              out_mats))
+        return np.array(all_sems[-1]), reports
+
+    def _batched_layer(self, stack: np.ndarray, layer: CompiledLayer,
+                       src_sems, res_sems, hook, count_overflows: bool):
+        """One layer on the batched interpreter: stage the layer's INP (and
+        RES) bytes into ``stack``, run its cached plan over every row."""
+        from .fast_simulator import BatchFastSimulator, plan_for
+        with span("vta.stage"):
+            self._stage_layer_input_batch(stack, layer, src_sems)
+            if res_sems is not None:
+                self._stage_residual_batch(stack, layer, res_sems)
+        # the loop owns ``stack`` and re-reads it from ``sim.dram``, so the
+        # engine's defensive copy is skipped
+        sim = BatchFastSimulator(self.config, stack, copy_dram=False,
+                                 count_overflows=count_overflows)
+        report = sim.run(layer.program.instructions,
+                         plan=plan_for(layer.program), fault_hook=hook)
+        return sim.dram, report
+
+    def _pallas_layer(self, layer: CompiledLayer, src_sems, res_sems):
+        """One layer on the pallas kernel: stack the layer's semantic
+        inputs (and its residual operand, zero-padded to the result's
+        block grid) and run it; the result comes back as ``(B, M, N)``."""
+        from .layer_compiler import residual_operand_matrix
+        from .pallas_backend import plan_pallas, serve_layer
+        with span("vta.stage"):
+            x = np.asarray(src_sems, dtype=np.int8)
+            x = x[:, 0] if layer.spec.kind == "conv" else \
+                x.reshape(len(x), -1)
+            res = None
+            if res_sems is not None:
+                res = np.zeros((len(res_sems),)
+                               + plan_pallas(layer.program).padded_shape,
+                               np.int32)
+                m, n = layer.residual_matrix.shape
+                for r, sem in zip(res, res_sems):
+                    r[:m, :n] = residual_operand_matrix(layer.spec, sem,
+                                                        (m, n))
+        return serve_layer(layer, x, res)
 
 
 def calibrate_network(specs: Sequence[LayerSpec],

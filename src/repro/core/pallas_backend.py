@@ -29,11 +29,18 @@ When the program's ALU epilogue is exactly the fused-kernel form
 ADD) run the GEMM on the kernel and the remaining TensorAlu ops as the
 vectorised int32 epilogue below, which mirrors ``gemm_compiler``'s
 reference semantics op for op (wraparound included).
+
+``NetworkProgram.serve(backend="pallas")`` takes the direct path,
+:func:`serve_layer`: no DRAM image, the program's constants on the device
+once (:func:`serving_constants`), and each layer's activations staged
+into A inside the kernel's own jitted call (``ops.staged_vta_matmul``;
+DESIGN.md §2, "Two pallas paths").
 """
 
 from __future__ import annotations
 
 import dataclasses
+import threading
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -56,6 +63,11 @@ try:  # jax + the kernels layer are optional at import time (clean skips)
 except Exception as exc:  # pragma: no cover - exercised only without jax
     HAS_PALLAS = False
     _IMPORT_ERROR = exc
+
+
+# plans and what serving holds for them are made once, whichever serving
+# thread asks first
+_LOCK = threading.RLock()
 
 
 def _require_pallas() -> None:
@@ -99,6 +111,10 @@ class PallasPlan:
     # positions) the pool reads: the pooling part of the epilogue
     pair_lattices: Tuple = dataclasses.field(default=(), compare=False)
     pool_rows: int = 0
+    # what serving keeps for this program: :func:`serving_lowering` and
+    # its device copy, :func:`serving_constants`
+    held: dict = dataclasses.field(default_factory=dict, compare=False,
+                                   repr=False)
 
     @property
     def padded_shape(self) -> Tuple[int, int]:
@@ -127,8 +143,15 @@ def plan_pallas(prog) -> PallasPlan:
     """Lower ``prog`` for the pallas backend; cached on the program (the
     compile-once/serve-many contract shared with ``plan_for``)."""
     plan = getattr(prog, "_pallas_plan", None)
-    if plan is not None:
-        return plan
+    if plan is None:
+        with _LOCK:                 # serving threads share programs
+            plan = getattr(prog, "_pallas_plan", None)
+            if plan is None:
+                plan = prog._pallas_plan = _lower(prog)
+    return plan
+
+
+def _lower(prog) -> PallasPlan:
     if prog.chunk_plan is None or prog.output_meta is None \
             or prog.alu_ops is None:
         raise CompileError(
@@ -151,7 +174,7 @@ def plan_pallas(prog) -> PallasPlan:
     pooled = np.unique(np.concatenate(
         [np.asarray(spec.pairs, np.int64).reshape(-1) for spec in pair_ops]
         or [np.zeros(0, np.int64)]))
-    plan = PallasPlan(
+    return PallasPlan(
         alpha=alpha, lam=lam, beta=beta, row_height=rh, block_size=bs,
         valid_shape=tuple(prog.output_meta.valid_shape),
         alu_ops=tuple(prog.alu_ops),
@@ -169,15 +192,18 @@ def plan_pallas(prog) -> PallasPlan:
                             for spec in pair_ops),
         # a vector index → its result row (block-major, §3.2)
         pool_rows=len(np.unique(pooled // (beta * rh) * rh + pooled % rh)))
-    prog._pallas_plan = plan
-    return plan
 
 
 @dataclasses.dataclass(frozen=True)
 class KernelCall:
-    """The unpadded operands and epilogue of one ``ops.vta_matmul`` call."""
+    """One served layer's kernel call over a batch: the activation stack
+    it sends, how the device stages A from it (``ops.stage_rows``), and
+    the padded GEMM ``(m, k) @ (k, n)`` with its epilogue."""
 
-    m: int
+    x: Tuple[int, ...]            # (B, C, H, W) conv input, (B, D) fc
+    conv: Optional[Tuple[int, int, int, int]]   # (kh, kw, stride, pad)
+    gather: int                   # band rows (max3x3s2), 0 for none
+    rows: int                     # A's rows per image, padded
     k: int
     n: int
     bias: bool
@@ -185,17 +211,28 @@ class KernelCall:
     shift: int
     out_dtype: object
 
+    @property
+    def m(self) -> int:
+        return self.x[0] * self.rows
+
+    def statics(self) -> dict:
+        """The static kwargs of ``ops.staged_vta_matmul`` for this call
+        (serving's truncating commit, the default blocks)."""
+        return dict(conv=self.conv, rows=self.rows, cols=self.k,
+                    relu=self.relu, shift=self.shift, saturate=False,
+                    out_dtype=self.out_dtype)
+
     def matmul_args(self, sharding=None):
         """``(operand shapes, static kwargs)`` of the one jitted call
-        (``ops._padded_vta_gemm``: pads, ``vta_gemm``, slice) that
-        ``ops.vta_matmul`` makes for this call (serving's truncating commit,
-        ``vta_matmul``'s default blocks, ``interpret`` left to the caller)
-        — what its ``lower`` takes."""
-        shapes = [((self.m, self.k), jnp.int8), ((self.k, self.n), jnp.int8),
+        serving makes (``ops._staged_vta_gemm``: staging, pads,
+        ``vta_gemm``, slice; ``interpret`` left to the caller) — what its
+        ``lower`` takes."""
+        shapes = [(self.x, jnp.int8),
+                  ((self.gather,), jnp.int32) if self.gather else None,
+                  ((self.k, self.n), jnp.int8),
                   ((self.n,), jnp.int32) if self.bias else None]
         return _shape_structs(shapes, sharding), dict(
-            relu=self.relu, shift=self.shift, saturate=False,
-            out_dtype=self.out_dtype, block_m=256, block_n=256, block_k=256)
+            self.statics(), block_m=256, block_n=256, block_k=256)
 
     def vta_gemm_args(self, sharding=None):
         """``(operand shapes, static kwargs)`` of the padded ``vta_gemm``
@@ -205,10 +242,10 @@ class KernelCall:
         g = gemm_blocks(self.m, self.k, self.n)
         shapes = [((g.m, g.k), jnp.int8), ((g.k, g.n), jnp.int8),
                   ((g.n,), jnp.int32) if self.bias else None]
-        _, statics = self.matmul_args()
-        statics.update(block_m=g.block_m, block_n=g.block_n,
-                       block_k=g.block_k)
-        return _shape_structs(shapes, sharding), statics
+        return _shape_structs(shapes, sharding), dict(
+            relu=self.relu, shift=self.shift, saturate=False,
+            out_dtype=self.out_dtype, block_m=g.block_m, block_n=g.block_n,
+            block_k=g.block_k)
 
 
 def _shape_structs(shapes, sharding):
@@ -216,19 +253,105 @@ def _shape_structs(shapes, sharding):
             for s in shapes]
 
 
-def kernel_call(p: PallasPlan, batch: int) -> KernelCall:
-    """The kernel call serving makes for ``p`` over a stack of ``batch``
-    compiled images (shared weights, broadcast bias, zero pad rows): the
-    whole program inside the kernel when its epilogue fuses, else the bare
-    int32 GEMM that :func:`apply_alu_epilogue` finishes.  The chip-compile
-    rehearsal compiles exactly these calls."""
+def _layer_gather(layer) -> Optional[np.ndarray]:
+    """The layer's band layout of A's rows (``row_gather``), or None."""
+    from .layer_compiler import row_gather
+    geo = layer.geometry
+    if geo is None:
+        return None
+    return row_gather(layer.spec, geo, layer.program.config.block_size)
+
+
+def kernel_call(layer, batch: int) -> KernelCall:
+    """The kernel call ``serve(backend="pallas")`` makes for the compiled
+    ``layer`` over ``batch`` images: A staged on the device from the
+    activations, the whole program inside the kernel when its epilogue
+    fuses, else the bare int32 GEMM that :func:`apply_alu_epilogue`
+    finishes.  The chip-compile rehearsal compiles exactly these calls."""
+    p = plan_pallas(layer.program)
+    geo = layer.geometry
+    gather = _layer_gather(layer)
     mp, np_ = p.padded_shape
-    shape = dict(m=batch * mp, k=p.lam * p.block_size, n=np_)
-    if p.fused:
+    shape = dict(
+        x=((batch, layer.input_matrix.shape[1]) if geo is None else
+           (batch, geo.in_channels, geo.in_h, geo.in_w)),
+        conv=None if geo is None else (geo.kh, geo.kw, geo.stride, geo.pad),
+        gather=0 if gather is None else len(gather),
+        rows=mp, k=p.lam * p.block_size, n=np_)
+    if serving_lowering(layer).fused:
         return KernelCall(**shape, bias=p.acc is not None, relu=p.relu,
                           shift=p.shift, out_dtype=jnp.int8)
     return KernelCall(**shape, bias=False, relu=False, shift=0,
                       out_dtype=jnp.int32)
+
+
+@dataclasses.dataclass(frozen=True)
+class ServingLowering:
+    """What every ``serve(backend="pallas")`` of one compiled layer uses
+    besides its activations, decoded once from the program's own WGT and
+    ACC segments: ``w`` ``(λ·bs, β·bs)`` int8, the fused
+    row-broadcast ``bias`` ``(β·bs,)`` int32 or None, the int32 X
+    ``preload`` ``(1, α·rh, β·bs)`` the host epilogue adds where the
+    layer does not run whole in the kernel, and the band ``gather``."""
+
+    w: np.ndarray
+    bias: Optional[np.ndarray]
+    preload: Optional[np.ndarray]
+    gather: Optional[np.ndarray]
+    fused: bool
+
+
+def _held(p: PallasPlan, key: str, make):
+    """``p.held[key]``, made once under a lock (serving threads share
+    programs)."""
+    found = p.held.get(key)
+    if found is None:
+        with _LOCK:
+            found = p.held.get(key)
+            if found is None:
+                found = p.held[key] = make()
+    return found
+
+
+def serving_lowering(layer) -> ServingLowering:
+    """The layer's :class:`ServingLowering`, cached on its plan.
+
+    A compiled image's X preload is the bias broadcast to every valid row
+    and zero in the pad rows, and the activations staged on the device
+    give A zero pad rows, so such a preload fuses into the kernel (its
+    pad rows read 0·B + 0 as the oracle's do, and are sliced off).  A
+    preload of any other form stays with the host epilogue."""
+    p = plan_pallas(layer.program)
+
+    def make():
+        row = layer.program.dram_image()[None]
+        x = _decode_acc32(row, p, p.acc) if p.acc else None
+        bias, fused = None, p.fused
+        if x is not None and fused:
+            m = p.valid_shape[0]
+            if (x[:, :m] == x[:, :1]).all() and (x[:, m:] == 0).all():
+                bias, x = x[0, 0], None
+            else:
+                fused = False
+        gather = _layer_gather(layer)
+        return ServingLowering(
+            w=_decode_wgt(row, p)[0], bias=bias, preload=x,
+            gather=None if gather is None else gather.astype(np.int32),
+            fused=fused)
+    return _held(p, "lowering", make)
+
+
+def serving_constants(layer) -> Tuple:
+    """``(w, bias, gather)`` of :func:`serving_lowering` on the device:
+    put once per program, inside a ``vta.const.put`` span."""
+    low = serving_lowering(layer)
+
+    def make():
+        consts = (low.w, low.bias, low.gather)
+        sent = sum(c.nbytes for c in consts if c is not None)
+        with span("vta.const.put", bytes=sent):
+            return tuple(jax.device_put(consts))
+    return _held(plan_pallas(layer.program), "device", make)
 
 
 # ---------------------------------------------------------------------------
@@ -500,20 +623,65 @@ def _execute_stack(prog, stack: np.ndarray, *, saturate: bool,
                              saturate=False, out_dtype=jnp.int32,
                              gemm_backend=gemm_backend)
                 for i in range(b)])
-        with span("vta.epilogue"):
-            if x is not None:                       # ACC preload (C = A·B+X)
-                acc = _add32(acc, x)
-            vec = _to_vectors(acc, p)
-            res_vec = _to_vectors(res, p) if res is not None else None
-            vec = apply_alu_epilogue(vec, p, res_vec)
-            out = _commit_int8(_to_matrix(vec, p), saturate)
+        out = _epilogue(acc, p, x, res, saturate)
 
     with span("vta.encode"):
         _encode_out(stack, p, out)
+    return _report(prog, b)
+
+
+def _epilogue(acc: np.ndarray, p: PallasPlan, x: Optional[np.ndarray],
+              res: Optional[np.ndarray], saturate: bool) -> np.ndarray:
+    """The int32 GEMM result ``(B, α·rh, β·bs)`` through the X preload and
+    the TensorAlu program to the committed int8 result."""
+    with span("vta.epilogue"):
+        if x is not None:                           # ACC preload (C = A·B+X)
+            acc = _add32(acc, x)
+        vec = _to_vectors(acc, p)
+        res_vec = _to_vectors(res, p) if res is not None else None
+        vec = apply_alu_epilogue(vec, p, res_vec)
+        return _commit_int8(_to_matrix(vec, p), saturate)
+
+
+def _report(prog, b: int) -> SimReport:
     report = SimReport()
     report.gemm_loops = b * prog.gemm_loops()
     report.alu_loops = b * prog.alu_loops()
     return report
+
+
+def serve_layer(layer, x: np.ndarray, res: Optional[np.ndarray]
+                ) -> Tuple[np.ndarray, SimReport]:
+    """One layer of ``serve(backend="pallas")``, no DRAM image: ``x`` is
+    the stacked int8 activations (``(B, C, H, W)``, or ``(B, D)`` for an fc
+    layer), ``res`` the int32 residual operand ``(B, α·rh, β·bs)`` or None.
+    The device stages A from ``x`` inside the kernel's jitted call; W, the
+    bias and the band gather are already there (:func:`serving_constants`).
+    Returns the committed ``(B, M, N)`` int8 result at its valid shape."""
+    _require_pallas()
+    from repro.kernels import ops as kernel_ops
+    p = plan_pallas(layer.program)
+    b = x.shape[0]
+    call = kernel_call(layer, b)
+    if x.shape != call.x:
+        raise ValueError(
+            f"layer {layer.spec.name!r}: activations of shape {x.shape[1:]}, "
+            f"compiled for {call.x[1:]} — request shape does not match the "
+            f"compiled geometry")
+    w, bias, gather = serving_constants(layer)
+    with span("vta.kernel"):
+        with span("vta.kernel.put", bytes=x.nbytes):
+            x = jax.device_put(x)
+        out = kernel_ops.staged_vta_matmul(x, gather, w, bias,
+                                           **call.statics())
+        with span("vta.kernel.fetch", bytes=out.size * out.dtype.itemsize):
+            out = np.asarray(out)       # read-only, as jax buffers are
+    out = out.reshape(b, call.rows, call.n)
+    low = serving_lowering(layer)
+    if not low.fused:
+        out = _epilogue(out, p, low.preload, res, saturate=False)
+    m, n = p.valid_shape
+    return out[:, :m, :n], _report(layer.program, b)
 
 
 # ---------------------------------------------------------------------------

@@ -1,11 +1,12 @@
 """Jitted public wrappers around the Pallas kernels.
 
 Handles shape padding to block multiples (:func:`gemm_blocks`, inside the
-kernel's own jitted call), the one decision of whether a kernel runs
-compiled or interpreted (:func:`pallas_interpret`: compiled on the TPU,
-interpreted only on the CPU the tests run on), and the pure-JAX fallbacks
-used by the dry-run path (XLA lowers those for the roofline analysis; see
-DESIGN.md §2).
+kernel's own jitted call), the staging of a served layer's GEMM input from
+its activations in that same call (:func:`stage_rows`), the one decision
+of whether a kernel runs compiled or interpreted (:func:`pallas_interpret`:
+compiled on the TPU, interpreted only on the CPU the tests run on), and
+the pure-JAX fallbacks used by the dry-run path (XLA lowers those for the
+roofline analysis; see DESIGN.md §2).
 """
 
 from __future__ import annotations
@@ -134,6 +135,14 @@ def _padded_vta_gemm(a, b, bias, *, relu, shift, saturate, out_dtype,
     into the runtime per call: each op run on its own would be another.
     Its module (``jit__padded_vta_gemm``) names ``vta_gemm``, which is how
     a device trace finds the kernel's time."""
+    return _pad_gemm_slice(a, b, bias, relu=relu, shift=shift,
+                           saturate=saturate, out_dtype=out_dtype,
+                           block_m=block_m, block_n=block_n, block_k=block_k,
+                           interpret=interpret)
+
+
+def _pad_gemm_slice(a, b, bias, *, relu, shift, saturate, out_dtype,
+                    block_m, block_n, block_k, interpret):
     m, k = a.shape
     n = b.shape[1]
     g = gemm_blocks(m, k, n, block_m=block_m, block_n=block_n,
@@ -146,6 +155,92 @@ def _padded_vta_gemm(a, b, bias, *, relu, shift, saturate, out_dtype,
                     block_m=g.block_m, block_n=g.block_n, block_k=g.block_k,
                     interpret=interpret)
     return out[:m, :n]
+
+
+def stage_rows(x, gather, *, conv, rows, cols):
+    """A layer's padded GEMM input built from its activations, on the
+    device: ``x`` is ``(B, C, H, W)`` int8 for a conv, ``(B, D)`` for an fc
+    layer; the result is ``(B·rows, cols)``.
+
+    ``conv`` is ``(kh, kw, stride, pad)``: zero padding, the map split
+    into its ``stride × stride`` phases (so that every kernel tap is a
+    unit-stride slice of one phase), one slice per tap, patches flattened
+    channel-major (``c·kh·kw + i·kw + j``, ``conv_lowering.im2row_batch``'s
+    order) — data movement only.  ``gather`` (int32, or None) lays the
+    rows out in the bands of a padded 3×3/s2 max pool, -1 giving a zero
+    row (``conv_lowering.gather_rows``).  Rows pad with zeros to ``rows``
+    per image, columns to ``cols``."""
+    b = x.shape[0]
+    if conv is None:
+        a = x.reshape(b, 1, -1)
+    else:
+        kh, kw, s, pad = conv
+        _, c, h, w = x.shape
+        oh = (h + 2 * pad - kh) // s + 1
+        ow = (w + 2 * pad - kw) // s + 1
+        xp = jnp.pad(x, ((0, 0), (0, 0), (pad, pad + (-(h + 2 * pad)) % s),
+                         (pad, pad + (-(w + 2 * pad)) % s)))
+        hs, ws = xp.shape[2] // s, xp.shape[3] // s
+        phases = xp.reshape(b, c, hs, s, ws, s).transpose(0, 3, 5, 1, 2, 4)
+        taps = [phases[:, i % s, j % s, :, i // s:i // s + oh,
+                       j // s:j // s + ow]
+                for i in range(kh) for j in range(kw)]
+        a = jnp.stack(taps, axis=2).reshape(b, c * kh * kw, oh * ow)
+        a = a.transpose(0, 2, 1)
+    if gather is not None:
+        m = a.shape[1]
+        a = jnp.pad(a, ((0, 0), (0, 1), (0, 0)))       # row m is zeros
+        a = jnp.take(a, jnp.where(gather < 0, m, gather), axis=1,
+                     mode="clip")
+    a = jnp.pad(a, ((0, 0), (0, rows - a.shape[1]), (0, cols - a.shape[2])))
+    return a.reshape(b * rows, cols)
+
+
+def staged_vta_matmul(x: jax.Array, gather: Optional[jax.Array],
+                      b: jax.Array, bias: Optional[jax.Array] = None, *,
+                      conv, rows: int, cols: int,
+                      relu: bool = False, shift: int = 0,
+                      saturate: bool = True, out_dtype=jnp.int8,
+                      block_m: int = 256, block_n: int = 256,
+                      block_k: int = 256) -> jax.Array:
+    """The fused GEMM of one served layer, its input staged on the device
+    (:func:`stage_rows`) in the kernel's own jitted call: ``x``'s
+    activations in, ``(B·rows, n)`` out.  The pallas leg only, compiled on
+    the TPU and interpreted on the CPU (:func:`pallas_interpret`)."""
+    m, n = x.shape[0] * rows, b.shape[1]
+    if b.shape[0] != cols:
+        raise CompileError(
+            f"staged GEMM input of {cols} columns @ {tuple(b.shape)}",
+            constraint="kernel-gemm-shape")
+    g = gemm_blocks(m, cols, n, block_m=block_m, block_n=block_n,
+                    block_k=block_k)
+    with span("vta.kernel.dispatch", issued_macs=g.m * g.k * g.n,
+              a_bytes=m * cols):
+        return _staged_vta_gemm(x, gather, b, bias, conv=conv, rows=rows,
+                                cols=cols, relu=relu, shift=shift,
+                                saturate=saturate, out_dtype=out_dtype,
+                                block_m=block_m, block_n=block_n,
+                                block_k=block_k,
+                                interpret=pallas_interpret())
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("conv", "rows", "cols", "relu", "shift", "saturate",
+                     "out_dtype", "block_m", "block_n", "block_k",
+                     "interpret"))
+def _staged_vta_gemm(x, gather, b, bias, *, conv, rows, cols, relu, shift,
+                     saturate, out_dtype, block_m, block_n, block_k,
+                     interpret):
+    """:func:`staged_vta_matmul` as one program: stage A, pad it to
+    :func:`gemm_blocks`, run ``vta_gemm``, slice.  Its module
+    (``jit__staged_vta_gemm``) names ``vta_gemm``, which is how a device
+    trace finds the kernel's time (the staging included)."""
+    a = stage_rows(x, gather, conv=conv, rows=rows, cols=cols)
+    return _pad_gemm_slice(a, b, bias, relu=relu, shift=shift,
+                           saturate=saturate, out_dtype=out_dtype,
+                           block_m=block_m, block_n=block_n, block_k=block_k,
+                           interpret=interpret)
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

@@ -1,12 +1,16 @@
-"""The kernel-call leg serving runs: ``ops.vta_matmul``'s pallas leg.
+"""The kernel-call legs: ``ops.vta_matmul``'s pallas leg, which the
+simulator-shaped engines run, and ``ops.staged_vta_matmul``, which
+``NetworkProgram.serve(backend="pallas")`` runs.
 
-Its pads, ``vta_gemm`` and slice run as one jitted program
+``vta_matmul``'s pads, ``vta_gemm`` and slice run as one jitted program
 (``ops._padded_vta_gemm``), so a call crosses into the runtime once.  Here,
 on the CPU (interpret mode), that leg is checked bit for bit against the
-``xla`` reference at every kernel call ``NetworkProgram.serve`` makes for
-lenet5 and resnet8 at rungs 1, 4 and 8, in the fused int8 form (bias, ReLU,
-shift, truncating commit) and the bare int32 form, and its jaxpr is
-checked to be one jitted call holding one ``pallas_call``.
+``xla`` reference at the GEMM shape of every kernel call
+``NetworkProgram.serve`` makes for lenet5 and resnet8 at rungs 1, 4 and 8,
+in the fused int8 form (bias, ReLU, shift, truncating commit) and the bare
+int32 form, and its jaxpr is checked to be one jitted call holding one
+``pallas_call``.  The staged call must be one such program too, A's
+staging inside it.
 """
 
 import jax
@@ -15,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.pallas_backend import kernel_call, plan_pallas
+from repro.core.pallas_backend import kernel_call
 from repro.kernels import ops
 
 NETS = ("lenet5", "resnet8")
@@ -43,7 +47,7 @@ def _operands(rng, call, with_bias):
 @pytest.mark.parametrize("rung", RUNGS)
 @pytest.mark.parametrize("net_name", NETS)
 def test_pallas_leg_is_bit_exact_against_xla(net_name, rung, form, nets):
-    calls = {kernel_call(plan_pallas(layer.program), rung)
+    calls = {kernel_call(layer, rung)
              for layer in nets[net_name].layers}
     rng = np.random.default_rng(rung)
     for call in sorted(calls, key=repr):
@@ -90,3 +94,22 @@ def test_pallas_leg_is_one_jitted_call_with_one_pallas_call(with_bias):
     assert "vta_gemm" in eqn.params["name"], eqn.params["name"]
     assert _count(closed.jaxpr, "pallas_call") == 1
     assert eqn.outvars[0].aval.shape == (40, 6)
+
+
+@pytest.mark.parametrize("conv", ((3, 3, 2, 1), None))
+def test_staged_call_is_one_jitted_call_with_one_pallas_call(conv):
+    """A staged from the activations inside the kernel's program, and a
+    module name that still holds ``vta_gemm`` for the trace."""
+    x = jnp.zeros((2, 4, 9, 9) if conv else (2, 40), jnp.int8)
+    rows, cols = (32, 48) if conv else (1, 48)
+    w = jnp.zeros((cols, 6), jnp.int8)
+    bias = jnp.zeros((6,), jnp.int32)
+    closed = jax.make_jaxpr(
+        lambda x, w, bias: ops.staged_vta_matmul(
+            x, None, w, bias, conv=conv, rows=rows, cols=cols, relu=True,
+            shift=3))(x, w, bias)
+    eqn, = closed.jaxpr.eqns
+    assert eqn.primitive.name in ("jit", "pjit"), eqn.primitive
+    assert "vta_gemm" in eqn.params["name"], eqn.params["name"]
+    assert _count(closed.jaxpr, "pallas_call") == 1
+    assert eqn.outvars[0].aval.shape == (2 * rows, 6)
