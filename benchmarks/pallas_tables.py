@@ -1,8 +1,8 @@
 """Pallas-backend serving benchmarks (``pallas/*`` rows, BENCH_pallas.json).
 
 Kernel-vs-fast serving throughput for the wired backend (DESIGN.md §2):
-LeNet-5 batched serving and resnet8 per-image serving, each executed on
-both the fast simulator and the pallas backend, with a bit-identity check
+LeNet-5 batched serving and resnet8 serving of one image, each executed
+on both the fast simulator and the pallas backend, with a bit-identity check
 riding along (a perf row from a diverging backend would be meaningless).
 Off-TPU the kernel runs in interpret mode — reported in the row names, as
 with ``kernel/*`` — so these are correctness-trajectory numbers on CPU
@@ -55,9 +55,9 @@ def _resnet8_section() -> Dict:
     net, _ = compile_resnet8()
     img = synthetic_image(0)
     out_fast = net.serve_one(img, backend="fast")
-    out_pal = net.serve_one(img, backend="pallas")
+    out_pal = net.serve([img], backend="pallas")[0][0]
     dt_fast = _time_serve(lambda: net.serve_one(img, backend="fast"))
-    dt_pal = _time_serve(lambda: net.serve_one(img, backend="pallas"))
+    dt_pal = _time_serve(lambda: net.serve([img], backend="pallas"))
     return {"fast_img_per_s": 1.0 / dt_fast,
             "kernel_img_per_s": 1.0 / dt_pal,
             "bit_identical": bool(np.array_equal(out_fast, out_pal))}

@@ -85,7 +85,8 @@ def main():
               for _ in range(args.requests)]
     logits_all = []
     serve_s = 0.0
-    if args.batch > 1:
+    # the pallas kernel serves batches only: at --batch 1, of one request
+    if args.batch > 1 or args.backend == "pallas":
         batch_backend = "pallas" if args.backend == "pallas" else "batched"
         mode = f"batched (batch {args.batch}, {batch_backend})"
         for lo in range(0, len(images), args.batch):

@@ -62,7 +62,8 @@ def main():
                          "per-image (default: 1)")
     ap.add_argument("--backend", choices=("fast", "oracle", "pallas"),
                     default="fast",
-                    help="backend for the per-image serving loop")
+                    help="serving backend: fast/oracle per image, or the "
+                         "vta_gemm Pallas kernel (through serve)")
     ap.add_argument("--skip-oracle", action="store_true",
                     help="skip the oracle cross-check (CI smoke mode)")
     args = ap.parse_args()
@@ -100,7 +101,8 @@ def main():
     images = [synthetic_image(100 + r) for r in range(args.requests)]
     serve_s = 0.0
     logits_all = []
-    if args.batch > 1:
+    # the pallas kernel serves batches only: at --batch 1, of one request
+    if args.batch > 1 or args.backend == "pallas":
         batch_backend = "pallas" if args.backend == "pallas" else "batched"
         mode = f"batched (batch {args.batch}, {batch_backend})"
         for lo in range(0, len(images), args.batch):

@@ -44,10 +44,10 @@ from repro.trace import span
 
 # The real backend sets, enumerated once so refusal messages, the serving
 # engine (repro.serving.vta) and the tests never drift out of sync again:
-# ``serve`` executes a (batch, nbytes) DRAM stack — only the two batch
-# engines can; ``serve_one`` runs the per-image simulators/kernel.
+# ``serve`` runs a batch — on the batched interpreter or the pallas kernel;
+# ``serve_one`` runs the per-image simulators.
 SERVE_BACKENDS = ("batched", "pallas")
-SERVE_ONE_BACKENDS = ("oracle", "fast", "pallas")
+SERVE_ONE_BACKENDS = ("oracle", "fast")
 
 
 @dataclasses.dataclass
@@ -112,7 +112,9 @@ class NetworkProgram:
         matrix) and the per-execution simulator reports.  ``backend="fast"``
         runs each layer on the vectorised interpreter; per-layer instruction
         plans are compiled once and cached on the layer programs, so
-        repeated runs (batch serving) pay only the array work.
+        repeated runs (batch serving) pay only the array work.  ``backend``
+        is a simulator's (:func:`~repro.core.simulator.make_simulator`,
+        which refuses ``"pallas"``).
         """
         image = self.dram_image()
         reports: List[SimReport] = []
@@ -344,10 +346,10 @@ class NetworkProgram:
         plan compilation.
 
         ``backend`` is one of :data:`SERVE_ONE_BACKENDS` — ``"fast"``
-        (default, the vectorised plan-compiling interpreter), ``"oracle"``
-        (the per-struct reference interpreter) or ``"pallas"`` (fused MXU
-        kernel calls, :mod:`repro.core.pallas_backend`); the batch engine
-        is :meth:`serve`'s, not this path's.  All are bit-identical.
+        (default, the vectorised plan-compiling interpreter) or
+        ``"oracle"`` (the per-struct reference interpreter); the batch
+        engines, the pallas kernel among them, are :meth:`serve`'s, not
+        this path's.  Both are bit-identical.
 
         ``guard`` (a :class:`repro.harden.GuardPolicy`) routes the request
         through the integrity-guarded path — CRC verification, instruction
@@ -358,8 +360,9 @@ class NetworkProgram:
         if backend not in SERVE_ONE_BACKENDS:
             raise CompileError(
                 f"serve_one supports backend in {SERVE_ONE_BACKENDS}, got "
-                f"{backend!r} (the batch engines 'batched'/'pallas' are "
-                f"serve()'s)", constraint="serve-one-backend")
+                f"{backend!r} (the batch engines run through "
+                f'serve(backend="batched") and serve(backend="pallas"))',
+                constraint="serve-one-backend")
         if guard is not None:
             from repro.harden import guards as _guards
             return _guards.guarded_serve_one(
@@ -403,8 +406,7 @@ class NetworkProgram:
         MXU kernel call over the whole batch, sending the layer's
         activations and staging A on the device, with no DRAM stack
         (:func:`repro.core.pallas_backend.serve_layer`; interpreted only
-        on the CPU) — bit-identical to the simulators on its truncation
-        path.
+        on the CPU) — bit-identical to the simulators.
 
         Returns ``(stacked outputs, per-layer batch-total reports)``: the
         leading output axis is the request index.

@@ -1,47 +1,34 @@
-"""The Pallas backend: compiled VTA programs on the fused TPU kernel.
+"""The Pallas backend: compiled VTA layers served on the fused TPU kernel.
 
-The fourth backend (DESIGN.md §2): where ``oracle``/``fast``/``batched``
-*interpret* the instruction stream, this backend executes the *semantics* a
-compiled :class:`~repro.core.program.VTAProgram` encodes — one
-``kernels.vta_gemm`` MXU call per program (compiled on the TPU; interpreted
-on the CPU the tests run on, so they run the same kernel body) plus a
-bit-exact TensorAlu epilogue —
-and commits the result to the same DRAM OUT region the simulators write.
-Because it reads the INP/WGT/ACC/RES segments and writes OUT bytes through
-the §3.2 layout (block-major vectors), it is a drop-in
-``make_simulator(backend="pallas")`` engine: ``run_program``,
-``NetworkProgram.run_functional/serve_one/serve`` and the differential
-conformance suite drive it unchanged, and multi-chunk / LOAD_UOP-wave /
-pipelined programs come along for free (chunking is an SRAM-residency
-concern; the DRAM-level semantics this backend reproduces are identical).
+Where ``oracle``/``fast``/``batched`` *interpret* a compiled program's
+instruction stream, this backend runs the *semantics* the compiler
+recorded beside it (DESIGN.md §2): ``NetworkProgram.serve(backend=
+"pallas")`` calls :func:`serve_layer` for each layer.  It sends the
+layer's int8 activations, and the kernel's one jitted call
+(``ops.staged_vta_matmul``) stages A from them on the device and runs
+``kernels.vta_gemm`` — compiled on the TPU, interpreted on the CPU the
+tests run on, so they run the same kernel body.  W, the fused bias and the
+band gather are read once per program from its own segments
+(:func:`serving_lowering`) and put on the device once
+(:func:`serving_constants`).  No DRAM image is built or decoded per
+request.
 
-Semantics contract (pinned by ``tests/test_pallas_backend.py``):
-
-* ``saturate=False`` (default) — faithful §2.1 truncation; OUT bytes are
-  **bit-identical** to the oracle for every compiled program (fuzzed in
-  ``tests/test_batched_conformance.py``).
-* ``saturate=True`` — the kernel's deliberate int8-saturation upgrade; OUT
-  equals ``clip(acc, -128, 127)`` of the oracle's pre-truncation ACC.
-
-When the program's ALU epilogue is exactly the fused-kernel form
+When the layer's ALU program is exactly the fused-kernel form
 (``[relu?][shr?]`` with a row-broadcast bias) the whole layer runs inside
 ``vta_gemm``; richer programs (pool pair lattices, indexed SHR, residual
 ADD) run the GEMM on the kernel and the remaining TensorAlu ops as the
-vectorised int32 epilogue below, which mirrors ``gemm_compiler``'s
-reference semantics op for op (wraparound included).
-
-``NetworkProgram.serve(backend="pallas")`` takes the direct path,
-:func:`serve_layer`: no DRAM image, the program's constants on the device
-once (:func:`serving_constants`), and each layer's activations staged
-into A inside the kernel's own jitted call (``ops.staged_vta_matmul``;
-DESIGN.md §2, "Two pallas paths").
+vectorised int32 epilogue below (:func:`apply_alu_epilogue`), which
+mirrors ``gemm_compiler``'s reference semantics op for op (wraparound
+included).  The commit is the §2.1 truncation, so a pallas serve is bit
+for bit the simulators' (pinned by ``tests/test_pallas_backend.py`` and
+``tests/test_staged_serving.py``).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import List, Optional, Tuple
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -101,12 +88,10 @@ class PallasPlan:
     fused: bool
     relu: bool
     shift: int
-    # (byte offset, byte size) per region; None when the program has none
-    inp: Tuple[int, int]
+    # (byte offset, byte size) of the WGT and ACC segments serving reads
+    # once; ``acc`` is None when the program has no X preload
     wgt: Tuple[int, int]
-    out: Tuple[int, int]
     acc: Optional[Tuple[int, int]]
-    res: Optional[Tuple[int, int]]
     # the pair ops' lattices, and the result rows (one image's input
     # positions) the pool reads: the pooling part of the epilogue
     pair_lattices: Tuple = dataclasses.field(default=(), compare=False)
@@ -181,13 +166,9 @@ def _lower(prog) -> PallasPlan:
         fused=fused is not None,
         relu=fused[0] if fused else False,
         shift=fused[1] if fused else 0,
-        inp=_span("inp", alpha * lam * rh * bs),
         wgt=_span("wgt", lam * beta * bs * bs),
-        out=_span("out", alpha * beta * rh * bs),
         acc=(_span("acc", alpha * beta * rh * bs * 4)
              if "acc" in prog.regions else None),
-        res=(_span("res", alpha * beta * rh * bs * 4)
-             if "res" in prog.regions else None),
         pair_lattices=tuple(pair_lattice(spec.op, spec.pairs)
                             for spec in pair_ops),
         # a vector index → its result row (block-major, §3.2)
@@ -325,7 +306,7 @@ def serving_lowering(layer) -> ServingLowering:
 
     def make():
         row = layer.program.dram_image()[None]
-        x = _decode_acc32(row, p, p.acc) if p.acc else None
+        x = _decode_acc32(row, p) if p.acc else None
         bias, fused = None, p.fused
         if x is not None and fused:
             m = p.valid_shape[0]
@@ -355,18 +336,9 @@ def serving_constants(layer) -> Tuple:
 
 
 # ---------------------------------------------------------------------------
-# §3.2 layout codecs over a (B, nbytes) DRAM stack (B = 1 for one image)
+# §3.2 layout: the program's WGT and ACC segments, read once per program,
+# and the result's block-major vectors
 # ---------------------------------------------------------------------------
-
-def _decode_inp(stack: np.ndarray, p: PallasPlan) -> np.ndarray:
-    """INP bytes → (B, α·rh, λ·bs) int8 padded A."""
-    start, size = p.inp
-    raw = stack[:, start:start + size].view(np.int8)
-    b = stack.shape[0]
-    blocks = raw.reshape(b, p.alpha, p.lam, p.row_height, p.block_size)
-    return blocks.transpose(0, 1, 3, 2, 4).reshape(
-        b, p.alpha * p.row_height, p.lam * p.block_size)
-
 
 def _decode_wgt(stack: np.ndarray, p: PallasPlan) -> np.ndarray:
     """WGT bytes (blocks stored transposed, §3.2) → (B, λ·bs, β·bs) int8."""
@@ -378,34 +350,14 @@ def _decode_wgt(stack: np.ndarray, p: PallasPlan) -> np.ndarray:
         b, p.lam * bs, p.beta * bs)
 
 
-def _decode_acc32(stack: np.ndarray, p: PallasPlan,
-                  span: Tuple[int, int]) -> np.ndarray:
-    """ACC/RES bytes → (B, α·rh, β·bs) int32 (X preload / residual)."""
-    start, size = span
+def _decode_acc32(stack: np.ndarray, p: PallasPlan) -> np.ndarray:
+    """ACC bytes → (B, α·rh, β·bs) int32 (the X preload)."""
+    start, size = p.acc
     raw = stack[:, start:start + size].view("<i4")
     b = stack.shape[0]
     blocks = raw.reshape(b, p.alpha, p.beta, p.row_height, p.block_size)
     return blocks.transpose(0, 1, 3, 2, 4).reshape(
         b, p.alpha * p.row_height, p.beta * p.block_size)
-
-
-def _same_in_every_row(stack: np.ndarray, span: Tuple[int, int]) -> bool:
-    """Whether every row of ``stack`` holds the same bytes in ``span``
-    (compared eight bytes at a time where the size allows)."""
-    start, size = span
-    raw = stack[:, start:start + size]
-    if size % 8 == 0:
-        raw = raw.view(np.uint64)
-    return bool((raw[1:] == raw[:1]).all())
-
-
-def _encode_out(stack: np.ndarray, p: PallasPlan, out: np.ndarray) -> None:
-    """(B, α·rh, β·bs) int8 result → OUT bytes, committed in place."""
-    start, size = p.out
-    b = stack.shape[0]
-    blocks = out.reshape(b, p.alpha, p.row_height, p.beta, p.block_size)
-    raw = np.ascontiguousarray(blocks.transpose(0, 1, 3, 2, 4))
-    stack[:, start:start + size] = raw.reshape(b, -1).view(np.uint8)
 
 
 def _to_vectors(mat: np.ndarray, p: PallasPlan) -> np.ndarray:
@@ -517,130 +469,20 @@ def _alu_ops(vec: np.ndarray, alu_ops, res_vec: Optional[np.ndarray],
 
 
 # ---------------------------------------------------------------------------
-# Execution
+# One served layer
 # ---------------------------------------------------------------------------
 
-def _kernel_gemm(a: np.ndarray, b: np.ndarray, bias: Optional[np.ndarray],
-                 *, relu: bool, shift: int, saturate: bool, out_dtype,
-                 gemm_backend: str) -> np.ndarray:
-    """One fused-kernel call (the MXU leg).  ``gemm_backend`` is forwarded
-    to ``ops.vta_matmul``: "pallas" (what serving uses) runs the real
-    kernel, compiled on the TPU and interpreted only on the CPU; "xla" the
-    semantically identical lowered reference; "auto" picks per platform."""
-    from repro.kernels import ops as kernel_ops
-    with span("vta.kernel"):
-        sent = a.nbytes + b.nbytes + (bias.nbytes if bias is not None else 0)
-        with span("vta.kernel.put", bytes=sent):
-            operands = jax.device_put((a, b, bias))
-        out = kernel_ops.vta_matmul(
-            *operands, relu=relu, shift=shift, saturate=saturate,
-            out_dtype=out_dtype, backend=gemm_backend)
-        with span("vta.kernel.fetch", bytes=out.size * out.dtype.itemsize):
-            return np.asarray(out)      # read-only, as jax buffers are
-
-
-def _commit_int8(acc: np.ndarray, saturate: bool) -> np.ndarray:
-    """ACC → OUT commit: §2.1 truncation, or the saturation upgrade."""
-    if saturate:
-        return np.clip(acc, -128, 127).astype(np.int8)
-    return truncate_int8(acc)
-
-
-def _execute_stack(prog, stack: np.ndarray, *, saturate: bool,
-                   gemm_backend: str) -> SimReport:
-    """Run ``prog`` over every DRAM row of ``stack``, writing OUT bytes in
-    place.  Weight-uniform batches collapse to a single stacked kernel
-    call; varied weights (conformance fuzz) fall back to a per-row GEMM."""
-    _require_pallas()
-    p = plan_pallas(prog)
-    b = stack.shape[0]
-    mp, np_ = p.padded_shape
-    m, n = p.valid_shape
-    with span("vta.decode"):
-        a = _decode_inp(stack, p)                   # (B, Mp, Kp)
-        # the weights and the preload are the same bytes in every row of
-        # a served stack: decode one row where they are
-        uniform_w = b == 1 or _same_in_every_row(stack, p.wgt)
-        w = _decode_wgt(stack[:1] if uniform_w else stack, p)
-        x = None
-        if p.acc:
-            uniform_x = b == 1 or _same_in_every_row(stack, p.acc)
-            x = _decode_acc32(stack[:1] if uniform_x else stack, p, p.acc)
-        res = _decode_acc32(stack, p, p.res) if p.res else None
-
-        # A row-broadcast preload (the bias form every compiled layer
-        # uses) fuses into the kernel.  The kernel broadcasts the bias to
-        # *every* row including the §3.2 padding rows, where the oracle
-        # adds the stored X pad rows instead — fusing therefore also
-        # requires A's pad rows to be zero (true for every compiled image;
-        # the conformance fuzz violates it with random bytes and takes the
-        # general path), so the pad rows' oracle value is exactly 0 and
-        # can be committed directly.  Pad *columns* need no special-casing
-        # in either form: the kernel computes them from the same decoded
-        # WGT/bias bytes the oracle reads.
-        bias = None
-        fuse_bias = x is None
-        if x is not None and p.fused:
-            rows_equal = bool((x[:, :m] == x[:, :1]).all())
-            x_pad_zero = bool((x[:, m:] == 0).all())
-            a_pad_zero = bool((a[:, m:] == 0).all())
-            if rows_equal and x_pad_zero and a_pad_zero:
-                bias, fuse_bias = x[:, 0], True
-        uniform_bias = bias is None or bool((bias == bias[0]).all())
-
-    if p.fused and fuse_bias:
-        # -- whole program inside the kernel --------------------------------
-        if uniform_w and uniform_bias:
-            out = _kernel_gemm(
-                a.reshape(b * mp, -1), w[0],
-                bias[0] if bias is not None else None,
-                relu=p.relu, shift=p.shift, saturate=saturate,
-                out_dtype=jnp.int8, gemm_backend=gemm_backend)
-            out = out.reshape(b, mp, np_)
-        else:
-            w = np.broadcast_to(w, (b,) + w.shape[1:])
-            if bias is not None:
-                bias = np.broadcast_to(bias, (b,) + bias.shape[1:])
-            out = np.stack([
-                _kernel_gemm(a[i], w[i],
-                             bias[i] if bias is not None else None,
-                             relu=p.relu, shift=p.shift, saturate=saturate,
-                             out_dtype=jnp.int8, gemm_backend=gemm_backend)
-                for i in range(b)])
-        if bias is not None and m < mp:
-            out = np.require(out, requirements="W")
-            out[:, m:, :] = 0          # oracle pad rows: 0·B + 0 preload
-    else:
-        # -- kernel GEMM + vectorised TensorAlu epilogue --------------------
-        if uniform_w:
-            acc = _kernel_gemm(a.reshape(b * mp, -1), w[0], None,
-                               relu=False, shift=0, saturate=False,
-                               out_dtype=jnp.int32,
-                               gemm_backend=gemm_backend).reshape(b, mp, np_)
-        else:
-            acc = np.stack([
-                _kernel_gemm(a[i], w[i], None, relu=False, shift=0,
-                             saturate=False, out_dtype=jnp.int32,
-                             gemm_backend=gemm_backend)
-                for i in range(b)])
-        out = _epilogue(acc, p, x, res, saturate)
-
-    with span("vta.encode"):
-        _encode_out(stack, p, out)
-    return _report(prog, b)
-
-
 def _epilogue(acc: np.ndarray, p: PallasPlan, x: Optional[np.ndarray],
-              res: Optional[np.ndarray], saturate: bool) -> np.ndarray:
+              res: Optional[np.ndarray]) -> np.ndarray:
     """The int32 GEMM result ``(B, α·rh, β·bs)`` through the X preload and
-    the TensorAlu program to the committed int8 result."""
+    the TensorAlu program to the committed int8 result (§2.1 truncation)."""
     with span("vta.epilogue"):
         if x is not None:                           # ACC preload (C = A·B+X)
             acc = _add32(acc, x)
         vec = _to_vectors(acc, p)
         res_vec = _to_vectors(res, p) if res is not None else None
         vec = apply_alu_epilogue(vec, p, res_vec)
-        return _commit_int8(_to_matrix(vec, p), saturate)
+        return truncate_int8(_to_matrix(vec, p))
 
 
 def _report(prog, b: int) -> SimReport:
@@ -679,83 +521,6 @@ def serve_layer(layer, x: np.ndarray, res: Optional[np.ndarray]
     out = out.reshape(b, call.rows, call.n)
     low = serving_lowering(layer)
     if not low.fused:
-        out = _epilogue(out, p, low.preload, res, saturate=False)
+        out = _epilogue(out, p, low.preload, res)
     m, n = p.valid_shape
     return out[:, :m, :n], _report(layer.program, b)
-
-
-# ---------------------------------------------------------------------------
-# Simulator-shaped engines (make_simulator / run_instructions dispatch)
-# ---------------------------------------------------------------------------
-
-class PallasSimulator:
-    """Drop-in engine for one DRAM image: ``.run_program(prog)`` executes
-    the compiled program on the fused kernel and commits OUT into
-    ``self.dram`` — the same observable contract as the simulators."""
-
-    is_batch = False
-
-    def __init__(self, cfg: VTAConfig, dram: np.ndarray, *,
-                 saturate: bool = False, gemm_backend: str = "pallas",
-                 copy_dram: bool = True, trace: bool = False,
-                 count_overflows: bool = False):
-        if trace or count_overflows:
-            raise ValueError(
-                "the pallas backend executes programs as fused kernel "
-                "calls; per-instruction trace/overflow accounting needs a "
-                "simulator backend (oracle/fast/batched)")
-        _require_pallas()
-        self.cfg = cfg
-        self.dram = np.array(dram, dtype=np.uint8, copy=copy_dram)
-        self.saturate = saturate
-        self.gemm_backend = gemm_backend
-
-    def run_program(self, prog, *, fault_hook=None) -> SimReport:
-        if fault_hook is not None:
-            raise ValueError(
-                "fault_hook requires per-instruction execution; the pallas "
-                "backend has no instruction stream to hook (use "
-                "backend='oracle'/'fast'/'batched' for injection)")
-        stack = self.dram.reshape(1, -1)
-        report = _execute_stack(prog, stack, saturate=self.saturate,
-                                gemm_backend=self.gemm_backend)
-        self.dram = stack.reshape(-1)
-        return report
-
-    def run(self, instructions, *, plan=None, fault_hook=None) -> SimReport:
-        raise CompileError(
-            "the pallas backend lowers compiled programs, not raw "
-            "instruction streams; call run_program(prog) (run_instructions "
-            "dispatches automatically when a program is passed)",
-            constraint="pallas-program-metadata")
-
-
-class BatchPallasSimulator(PallasSimulator):
-    """The batch-axis variant over a ``(batch, nbytes)`` DRAM stack —
-    weight-uniform batches execute as one stacked kernel call."""
-
-    is_batch = True
-
-    def __init__(self, cfg: VTAConfig, dram_stack: np.ndarray, **kw):
-        super().__init__(cfg, np.atleast_2d(dram_stack), **kw)
-
-    def run_program(self, prog, *, fault_hook=None) -> SimReport:
-        if fault_hook is not None:
-            raise ValueError(
-                "fault_hook requires per-instruction execution; the pallas "
-                "backend has no instruction stream to hook (use "
-                "backend='oracle'/'fast'/'batched' for injection)")
-        return _execute_stack(prog, self.dram, saturate=self.saturate,
-                              gemm_backend=self.gemm_backend)
-
-
-def run_program_pallas(prog, *, saturate: bool = False,
-                       gemm_backend: str = "pallas"
-                       ) -> Tuple[np.ndarray, SimReport]:
-    """Convenience driver: execute one compiled program on the pallas
-    backend; returns the decoded unpadded (M, N) result + report."""
-    from .simulator import decode_out_region
-    sim = PallasSimulator(prog.config, prog.dram_image(), saturate=saturate,
-                          gemm_backend=gemm_backend, copy_dram=False)
-    report = sim.run_program(prog)
-    return decode_out_region(prog, sim.dram), report

@@ -33,6 +33,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from . import isa
+from .errors import CompileError
 from .hwconfig import VTAConfig
 from .layout import truncate_int8
 from .program import VTAProgram
@@ -481,7 +482,16 @@ class FunctionalSimulator:
 # Backend selection + program-level drivers
 # ---------------------------------------------------------------------------
 
-BACKENDS = ("oracle", "fast", "batched", "pallas")
+BACKENDS = ("oracle", "fast", "batched")
+
+
+def _refuse_pallas(entry: str) -> CompileError:
+    """The typed refusal of ``backend="pallas"`` at an entry point that
+    interprets instruction streams: the kernel runs whole networks."""
+    return CompileError(
+        f"{entry} interprets instruction streams on {BACKENDS}; the pallas "
+        f'kernel serves compiled networks through NetworkProgram.serve('
+        f'backend="pallas")', constraint="simulator-backend")
 
 
 def make_simulator(cfg: VTAConfig, dram: np.ndarray, *,
@@ -495,10 +505,9 @@ def make_simulator(cfg: VTAConfig, dram: np.ndarray, *,
     oracle but executing each instruction as batched numpy ops.
     ``"batched"`` takes a ``(batch, nbytes)`` DRAM *stack* and executes the
     stream once over all images (DESIGN.md §Batching), bit-identical to
-    looping ``"oracle"`` over the stack's rows.  ``"pallas"`` executes
-    compiled programs as fused MXU kernel calls
-    (:mod:`repro.core.pallas_backend`; interpreted only on the CPU) —
-    bit-identical to the oracle on its default truncation path.
+    looping ``"oracle"`` over the stack's rows.  ``"pallas"`` is refused
+    with a :class:`CompileError`: the kernel runs compiled networks, through
+    ``NetworkProgram.serve(backend="pallas")``.
     """
     if backend == "oracle":
         return FunctionalSimulator(cfg, dram, trace=trace,
@@ -512,33 +521,21 @@ def make_simulator(cfg: VTAConfig, dram: np.ndarray, *,
         return BatchFastSimulator(cfg, dram, trace=trace,
                                   count_overflows=count_overflows)
     if backend == "pallas":
-        from .pallas_backend import (BatchPallasSimulator, PallasSimulator)
-        cls = BatchPallasSimulator if dram.ndim == 2 else PallasSimulator
-        return cls(cfg, dram, trace=trace, count_overflows=count_overflows)
+        raise _refuse_pallas("make_simulator")
     raise ValueError(f"unknown simulator backend {backend!r}; "
                      f"expected one of {BACKENDS}")
 
 
 def run_instructions(sim, instructions, *, program: Optional[VTAProgram] = None,
                      fault_hook=None) -> SimReport:
-    """Run an instruction stream on either backend.
+    """Run an instruction stream on any simulator backend.
 
-    On the fast backend, passing ``program`` reuses (or populates) the
+    On the fast backends, passing ``program`` reuses (or populates) the
     instruction plan cached on it, so repeated executions of the same
-    program (batch serving) skip plan compilation entirely.  On the pallas
-    backend ``program`` is required — the engine lowers the compiled
-    program itself, not the instruction stream.
+    program (batch serving) skip plan compilation entirely.
     ``fault_hook(sim, insn_idx)`` is forwarded to the backend's run loop.
     """
     from .fast_simulator import FastSimulator, plan_for
-    from .pallas_backend import PallasSimulator
-    if isinstance(sim, PallasSimulator):
-        if program is None:
-            raise ValueError(
-                "the pallas backend executes compiled programs; pass "
-                "program= to run_instructions (raw instruction streams "
-                "need a simulator backend)")
-        return sim.run_program(program, fault_hook=fault_hook)
     if isinstance(sim, FastSimulator) and program is not None:
         return sim.run(instructions, plan=plan_for(program),
                        fault_hook=fault_hook)
@@ -556,9 +553,8 @@ def run_program(prog: VTAProgram, *, trace: bool = False,
     ``backend="fast"`` selects the vectorised interpreter with the plan
     cached on ``prog``; ``backend="batched"`` routes through the batch
     engine with a batch of one (uniform dispatch — the real batched entry
-    point is :func:`run_program_batch`); ``backend="pallas"`` executes the
-    program as a fused MXU kernel call (truncation path — bit-identical to
-    the oracle; see :mod:`repro.core.pallas_backend`).
+    point is :func:`run_program_batch`).  ``backend="pallas"`` is refused
+    (:func:`make_simulator`).
     """
     if backend == "batched":
         outs, report = run_program_batch(prog, batch=1, trace=trace,
@@ -587,15 +583,14 @@ def run_program_batch(prog: VTAProgram, *, batch: Optional[int] = None,
     per-request INP regions staged in) — or just ``batch`` to replicate
     ``prog.dram_image()``.  The instruction plan is compiled once and
     cached on ``prog`` (:func:`~repro.core.fast_simulator.plan_for`), so
-    repeated calls pay only the array work.  ``backend="pallas"`` executes
-    the stack through the fused-kernel engine instead (one stacked MXU
-    call when the batch shares weights).  Returns the stacked decoded
+    repeated calls pay only the array work.  Returns the stacked decoded
     ``(batch, M, N)`` results and the batch-total report.
     """
-    if backend not in ("batched", "pallas"):
+    if backend == "pallas":
+        raise _refuse_pallas("run_program_batch")
+    if backend != "batched":
         raise ValueError(
-            f"run_program_batch supports backend='batched' or 'pallas', "
-            f"got {backend!r}")
+            f"run_program_batch supports backend='batched', got {backend!r}")
     if dram_stack is None:
         if batch is None:
             raise ValueError("pass either dram_stack or batch")

@@ -1,12 +1,13 @@
 """Jitted public wrappers around the Pallas kernels.
 
-Handles shape padding to block multiples (:func:`gemm_blocks`, inside the
-kernel's own jitted call), the staging of a served layer's GEMM input from
-its activations in that same call (:func:`stage_rows`), the one decision
-of whether a kernel runs compiled or interpreted (:func:`pallas_interpret`:
-compiled on the TPU, interpreted only on the CPU the tests run on), and
-the pure-JAX fallbacks used by the dry-run path (XLA lowers those for the
-roofline analysis; see DESIGN.md §2).
+Every ``vta_gemm`` call goes through one jitted program,
+:func:`_staged_vta_gemm`: the staging of a served layer's GEMM input from
+its activations (:func:`stage_rows`; the identity for a plain matrix),
+shape padding to block multiples (:func:`gemm_blocks`), the kernel and
+the slice back.  Also here: the one decision of whether a kernel runs
+compiled or interpreted (:func:`pallas_interpret`: compiled on the TPU,
+interpreted only on the CPU the tests run on), and the ``xla`` legs that
+call the pure-JAX references in ``kernels/ref.py``.
 """
 
 from __future__ import annotations
@@ -105,56 +106,19 @@ def vta_matmul(a: jax.Array, b: jax.Array,
     identical.
     """
     _check_backend(backend)
-    m, k = a.shape
-    k2, n = b.shape
-    if k != k2:
+    k = a.shape[1]
+    if b.shape[0] != k:
         raise CompileError(
             f"incompatible GEMM operand shapes {tuple(a.shape)} @ "
             f"{tuple(b.shape)}", constraint="kernel-gemm-shape")
     if backend == "xla" or (backend == "auto" and not _on_tpu()):
         return _ref.vta_gemm_ref(a, b, bias, relu=relu, shift=shift,
                                  saturate=saturate, out_dtype=out_dtype)
-    g = gemm_blocks(m, k, n, block_m=block_m, block_n=block_n,
-                    block_k=block_k)
-    with span("vta.kernel.dispatch", issued_macs=g.m * g.k * g.n):
-        return _padded_vta_gemm(a, b, bias, relu=relu, shift=shift,
-                                saturate=saturate, out_dtype=out_dtype,
-                                block_m=block_m, block_n=block_n,
-                                block_k=block_k,
-                                interpret=pallas_interpret())
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("relu", "shift", "saturate", "out_dtype",
-                     "block_m", "block_n", "block_k", "interpret"))
-def _padded_vta_gemm(a, b, bias, *, relu, shift, saturate, out_dtype,
-                     block_m, block_n, block_k, interpret):
-    """``vta_matmul``'s pallas leg as one program: pad the operands to
-    :func:`gemm_blocks`, run ``vta_gemm``, slice to ``(m, n)``.  One trip
-    into the runtime per call: each op run on its own would be another.
-    Its module (``jit__padded_vta_gemm``) names ``vta_gemm``, which is how
-    a device trace finds the kernel's time."""
-    return _pad_gemm_slice(a, b, bias, relu=relu, shift=shift,
-                           saturate=saturate, out_dtype=out_dtype,
-                           block_m=block_m, block_n=block_n, block_k=block_k,
-                           interpret=interpret)
-
-
-def _pad_gemm_slice(a, b, bias, *, relu, shift, saturate, out_dtype,
-                    block_m, block_n, block_k, interpret):
-    m, k = a.shape
-    n = b.shape[1]
-    g = gemm_blocks(m, k, n, block_m=block_m, block_n=block_n,
-                    block_k=block_k)
-    a_p = jnp.pad(a, ((0, g.m - m), (0, g.k - k)))
-    b_p = jnp.pad(b, ((0, g.k - k), (0, g.n - n)))
-    bias_p = jnp.pad(bias, (0, g.n - n)) if bias is not None else None
-    out = _vta_gemm(a_p, b_p, bias_p, relu=relu, shift=shift,
-                    saturate=saturate, out_dtype=out_dtype,
-                    block_m=g.block_m, block_n=g.block_n, block_k=g.block_k,
-                    interpret=interpret)
-    return out[:m, :n]
+    # a plain matrix is a batch of m one-row "activations": no staging
+    return staged_vta_matmul(a, None, b, bias, conv=None, rows=1, cols=k,
+                             relu=relu, shift=shift, saturate=saturate,
+                             out_dtype=out_dtype, block_m=block_m,
+                             block_n=block_n, block_k=block_k)
 
 
 def stage_rows(x, gather, *, conv, rows, cols):
@@ -233,14 +197,22 @@ def _staged_vta_gemm(x, gather, b, bias, *, conv, rows, cols, relu, shift,
                      saturate, out_dtype, block_m, block_n, block_k,
                      interpret):
     """:func:`staged_vta_matmul` as one program: stage A, pad it to
-    :func:`gemm_blocks`, run ``vta_gemm``, slice.  Its module
+    :func:`gemm_blocks`, run ``vta_gemm``, slice.  One trip into the
+    runtime per call: each op run on its own would be another.  Its module
     (``jit__staged_vta_gemm``) names ``vta_gemm``, which is how a device
     trace finds the kernel's time (the staging included)."""
     a = stage_rows(x, gather, conv=conv, rows=rows, cols=cols)
-    return _pad_gemm_slice(a, b, bias, relu=relu, shift=shift,
-                           saturate=saturate, out_dtype=out_dtype,
-                           block_m=block_m, block_n=block_n, block_k=block_k,
-                           interpret=interpret)
+    m, n = a.shape[0], b.shape[1]
+    g = gemm_blocks(m, cols, n, block_m=block_m, block_n=block_n,
+                    block_k=block_k)
+    a_p = jnp.pad(a, ((0, g.m - m), (0, g.k - cols)))
+    b_p = jnp.pad(b, ((0, g.k - cols), (0, g.n - n)))
+    bias_p = jnp.pad(bias, (0, g.n - n)) if bias is not None else None
+    out = _vta_gemm(a_p, b_p, bias_p, relu=relu, shift=shift,
+                    saturate=saturate, out_dtype=out_dtype,
+                    block_m=g.block_m, block_n=g.block_n, block_k=g.block_k,
+                    interpret=interpret)
+    return out[:m, :n]
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

@@ -35,8 +35,6 @@ from repro.core.gemm_compiler import (AluImmOp, AluIndexedImmOp, AluPairOp,
                                       compile_matmul)
 from repro.core.hwconfig import VTAConfig, vta_default
 from repro.core.layer_compiler import LayerSpec, compile_layer
-from repro.core.pallas_backend import (HAS_PALLAS, BatchPallasSimulator,
-                                       PallasSimulator)
 from repro.core.simulator import FunctionalSimulator
 from repro.harden.guards import validate_program
 
@@ -71,33 +69,10 @@ def varied_stack(prog, rng, batch, vary=("inp", "acc")):
     return stack
 
 
-def _out_region_rows(prog, dram) -> np.ndarray:
-    region = prog.regions["out"]
-    start = region.phys_addr - prog.allocator.offset
-    return np.atleast_2d(dram)[:, start:start + region.nbytes]
-
-
-def assert_pallas_leg(prog, stack, ref_dram) -> None:
-    """The pallas-backend conformance leg: execute the same compiled
-    program over the same varied DRAM stack on the kernel backend and
-    require its OUT bytes to equal the (already oracle-verified)
-    reference rows bit-for-bit.  No-op when jax is unavailable — the
-    simulator legs above still run everywhere."""
-    if not HAS_PALLAS:
-        return
-    psim = BatchPallasSimulator(prog.config, stack)   # defensive copy
-    psim.run_program(prog)
-    np.testing.assert_array_equal(
-        _out_region_rows(prog, psim.dram), _out_region_rows(prog, ref_dram),
-        err_msg="pallas backend OUT bytes diverged from the oracle")
-
-
 def assert_batch_matches_oracle_loop(cfg, instructions, stack, *,
-                                     plan=None, prog=None):
+                                     plan=None):
     """Run the batch engine once and the oracle per row; every observable
-    must match bit-for-bit.  When ``prog`` (the compiled program) is
-    given, the pallas backend runs the same stack as a third leg
-    (OUT-bytes equality).  Returns the batched report."""
+    must match bit-for-bit.  Returns the batched report."""
     bsim = BatchFastSimulator(cfg, stack, trace=True)
     rep_b = bsim.run(instructions, plan=plan)
     totals = {f: 0 for f in _SUM_FIELDS}
@@ -117,8 +92,6 @@ def assert_batch_matches_oracle_loop(cfg, instructions, stack, *,
             totals[f] += getattr(rep_o, f)
     for f in _SUM_FIELDS:            # batch totals == oracle-loop sums
         assert getattr(rep_b, f) == totals[f], f
-    if prog is not None:
-        assert_pallas_leg(prog, stack, bsim.dram)
     return rep_b
 
 
@@ -133,11 +106,6 @@ def _out_bytes_after(prog, backend):
         sim = BatchFastSimulator(prog.config, prog.dram_image()[None].copy())
         sim.run(prog.instructions, plan=plan_for(prog))
         dram = sim.dram[0]
-    elif backend == "pallas":
-        sim = PallasSimulator(prog.config, prog.dram_image(),
-                              copy_dram=False)
-        sim.run_program(prog)
-        dram = sim.dram
     else:
         cls = FunctionalSimulator if backend == "oracle" else FastSimulator
         sim = cls(prog.config, prog.dram_image())
@@ -159,9 +127,7 @@ def assert_pipelined_variant_conforms(prog_s, prog_p, rng, batch=3):
     assert_batch_matches_oracle_loop(prog_p.config, prog_p.instructions,
                                      stack, plan=plan_for(prog_p))
     ref = _out_bytes_after(prog_s, "oracle")
-    backends = ("oracle", "fast", "batched") + \
-        (("pallas",) if HAS_PALLAS else ())
-    for backend in backends:
+    for backend in ("oracle", "fast", "batched"):
         np.testing.assert_array_equal(
             _out_bytes_after(prog_p, backend), ref,
             err_msg=f"pipelined {backend} diverged from serialized")
@@ -199,8 +165,7 @@ def test_fuzz_random_programs_random_batch_sizes():
         batch = int(rng.integers(1, 17))
         stack = varied_stack(prog, rng, batch)
         assert_batch_matches_oracle_loop(prog.config, prog.instructions,
-                                         stack, plan=plan_for(prog),
-                                         prog=prog)
+                                         stack, plan=plan_for(prog))
         prog_p = compile_matmul(A, B, X=X, alu_ops=ops,
                                 schedule="pipelined")
         assert_pipelined_variant_conforms(prog, prog_p, rng)
@@ -219,8 +184,7 @@ def test_fuzz_varied_weights_drive_nonuniform_gemm():
         stack = varied_stack(prog, rng, int(rng.integers(2, 9)),
                              vary=("inp", "acc", "wgt"))
         assert_batch_matches_oracle_loop(prog.config, prog.instructions,
-                                         stack, plan=plan_for(prog),
-                                         prog=prog)
+                                         stack, plan=plan_for(prog))
         prog_p = compile_matmul(A, B, alu_ops=ops, schedule="pipelined")
         assert_pipelined_variant_conforms(prog, prog_p, rng)
 
@@ -244,8 +208,7 @@ def test_fuzz_multi_chunk_programs_batched():
         assert prog.chunk_plan.n_chunks > 1
         stack = varied_stack(prog, rng, int(rng.integers(2, 7)))
         assert_batch_matches_oracle_loop(prog.config, prog.instructions,
-                                         stack, plan=plan_for(prog),
-                                         prog=prog)
+                                         stack, plan=plan_for(prog))
         prog_p = compile_matmul(A, B, alu_ops=ops, cfg=_SMALL_CFG,
                                 schedule="pipelined")
         assert_pipelined_variant_conforms(prog, prog_p, rng)
@@ -276,8 +239,7 @@ def test_fuzz_uop_wave_streaming_batched():
         assert n_uop_loads > 1, "expected multi-wave streaming"
         stack = varied_stack(prog, rng, int(rng.integers(2, 7)))
         assert_batch_matches_oracle_loop(prog.config, prog.instructions,
-                                         stack, plan=plan_for(prog),
-                                         prog=prog)
+                                         stack, plan=plan_for(prog))
         prog_p = compile_matmul(A, B, cfg=cfg, alu_ops=ops,
                                 schedule="pipelined")
         assert_pipelined_variant_conforms(prog, prog_p, rng)
@@ -302,8 +264,7 @@ def test_padded_conv_and_pool_pairs_batched():
         prog = layer.program
         stack = varied_stack(prog, rng, 5)
         assert_batch_matches_oracle_loop(prog.config, prog.instructions,
-                                         stack, plan=plan_for(prog),
-                                         prog=prog)
+                                         stack, plan=plan_for(prog))
         prog_p = compile_layer(spec, inp, cfg=cfg,
                                schedule="pipelined").program
         assert_pipelined_variant_conforms(prog, prog_p, rng)
@@ -330,8 +291,7 @@ def test_fuzz_strided_conv_programs_batched():
         prog = layer.program
         stack = varied_stack(prog, rng, int(rng.integers(2, 7)))
         assert_batch_matches_oracle_loop(prog.config, prog.instructions,
-                                         stack, plan=plan_for(prog),
-                                         prog=prog)
+                                         stack, plan=plan_for(prog))
         prog_p = compile_layer(spec, inp, schedule="pipelined").program
         assert_pipelined_variant_conforms(prog, prog_p, rng)
 
@@ -366,8 +326,7 @@ def test_fuzz_gap_reduction_programs_batched():
         prog = layer.program
         stack = varied_stack(prog, rng, int(rng.integers(2, 7)))
         assert_batch_matches_oracle_loop(prog.config, prog.instructions,
-                                         stack, plan=plan_for(prog),
-                                         prog=prog)
+                                         stack, plan=plan_for(prog))
         prog_p = compile_layer(spec, inp, cfg=cfg,
                                schedule="pipelined").program
         assert_pipelined_variant_conforms(prog, prog_p, rng)
@@ -483,8 +442,7 @@ def test_extreme_values_at_f32_exactness_boundary():
         prog = compile_matmul(A, B)
         stack = varied_stack(prog, rng, 3)
         assert_batch_matches_oracle_loop(prog.config, prog.instructions,
-                                         stack, plan=plan_for(prog),
-                                         prog=prog)
+                                         stack, plan=plan_for(prog))
 
 
 # ---------------------------------------------------------------------------
@@ -538,8 +496,7 @@ if HAS_HYPOTHESIS:
         prog = compile_matmul(A, B, alu_ops=ops)
         stack = varied_stack(prog, rng, batch)
         assert_batch_matches_oracle_loop(prog.config, prog.instructions,
-                                         stack, plan=plan_for(prog),
-                                         prog=prog)
+                                         stack, plan=plan_for(prog))
         prog_p = compile_matmul(A, B, alu_ops=ops, schedule="pipelined")
         assert_pipelined_variant_conforms(prog, prog_p, rng)
 else:

@@ -98,7 +98,7 @@ def test_vta_gemm_compiles_for_v5e(net_name, rung, nets, one_chip):
 
 @pytest.mark.parametrize("rung", RUNGS)
 @pytest.mark.parametrize("net_name", NETS)
-def test_padded_vta_gemm_compiles_for_v5e(net_name, rung, nets, one_chip):
+def test_staged_vta_gemm_compiles_for_v5e(net_name, rung, nets, one_chip):
     """The program serving dispatches, staging, pads and slice included."""
     net, _ = nets[net_name]
     calls = {kernel_call(layer, rung)
@@ -110,7 +110,7 @@ def test_padded_vta_gemm_compiles_for_v5e(net_name, rung, nets, one_chip):
         assert "tpu_custom_call" in compiled.as_text(), call
 
 
-def test_padded_vta_gemm_keeps_the_kernels_names(nets, one_chip):
+def test_staged_vta_gemm_keeps_the_kernels_names(nets, one_chip):
     """The benchmark finds the kernel's time by ``vta_gemm`` in the module
     name and by the op ``%vta_gemm.N = ... custom-call``: the program that
     holds the staging, the pads and the slice must still read both."""
@@ -215,7 +215,7 @@ def test_resnet50_calls_from_shapes_are_what_the_program_compiles(rung):
 
 
 @pytest.mark.parametrize("rung", RESNET50_RUNGS)
-def test_resnet50_padded_vta_gemm_compiles_for_v5e(rung, one_chip):
+def test_resnet50_staged_vta_gemm_compiles_for_v5e(rung, one_chip):
     cfg = json.loads((Path(__file__).resolve().parents[1] / "bench" /
                       "configs" / "resnet50.json").read_text())
     calls = _resnet50_calls(cfg["layers"], cfg["requant_shifts"], rung)

@@ -1,16 +1,15 @@
-"""The kernel-call legs: ``ops.vta_matmul``'s pallas leg, which the
-simulator-shaped engines run, and ``ops.staged_vta_matmul``, which
-``NetworkProgram.serve(backend="pallas")`` runs.
+"""The kernel-call legs: ``ops.vta_matmul``'s pallas leg and
+``ops.staged_vta_matmul``, which ``NetworkProgram.serve(backend="pallas")``
+runs.  Both go through the one jitted program ``ops._staged_vta_gemm``
+(``vta_matmul`` with no staging: one row per input row).
 
-``vta_matmul``'s pads, ``vta_gemm`` and slice run as one jitted program
-(``ops._padded_vta_gemm``), so a call crosses into the runtime once.  Here,
-on the CPU (interpret mode), that leg is checked bit for bit against the
-``xla`` reference at the GEMM shape of every kernel call
-``NetworkProgram.serve`` makes for lenet5 and resnet8 at rungs 1, 4 and 8,
-in the fused int8 form (bias, ReLU, shift, truncating commit) and the bare
-int32 form, and its jaxpr is checked to be one jitted call holding one
-``pallas_call``.  The staged call must be one such program too, A's
-staging inside it.
+Its staging, pads, ``vta_gemm`` and slice run as one program, so a call
+crosses into the runtime once.  Here, on the CPU (interpret mode), the
+``vta_matmul`` leg is checked bit for bit against the ``xla`` reference at
+the GEMM shape of every kernel call ``NetworkProgram.serve`` makes for
+lenet5 and resnet8 at rungs 1, 4 and 8, in the fused int8 form (bias,
+ReLU, shift, truncating commit) and the bare int32 form, and the jaxpr of
+each leg is checked to be one jitted call holding one ``pallas_call``.
 """
 
 import jax

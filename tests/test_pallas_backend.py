@@ -1,4 +1,4 @@
-"""The Pallas backend contract (DESIGN.md §2, tests for PR 8).
+"""The Pallas backend contract (DESIGN.md §2).
 
 Three layers of pinning:
 
@@ -9,13 +9,14 @@ Three layers of pinning:
   ``saturate`` settings.  This is the differential test that pins the
   relu-vs-SHR order, the floor rounding of SHR, and the
   truncate-vs-saturate commit.
-* **program level** — ``run_program(backend="pallas")`` /
-  ``run_program_batch(backend="pallas")`` OUT bytes bit-identical to the
-  oracle on fused and general (pair/indexed/residual) programs; the
-  ``saturate=True`` upgrade equals ``clip`` of the pre-commit ACC.
+* **layer level** — one-layer networks served by
+  ``serve(backend="pallas")`` bit-identical to the oracle's ``verify`` and
+  to the batched interpreter, on a fused program (bias, ReLU, SHR in the
+  kernel), a general one (pool pairs and indexed SHR in the host
+  epilogue) and a multi-chunk one; the typed refusals of the simulator
+  entry points, which do not run the kernel.
 * **network level** — LeNet-5 and resnet8 served end-to-end on
-  ``backend="pallas"`` match the fast simulator bit for bit
-  (``serve_one``, ``run_functional``, batched ``serve``).
+  ``backend="pallas"`` match the simulators bit for bit.
 
 Skips cleanly when jax is unavailable (the backend itself degrades to a
 typed ``CompileError`` with constraint ``pallas-jax-missing``).
@@ -27,21 +28,18 @@ import pytest
 jax = pytest.importorskip("jax")
 jnp = pytest.importorskip("jax.numpy")
 
-from repro.core import isa                                      # noqa: E402
 from repro.core.dram import DramAllocator                       # noqa: E402
 from repro.core.errors import CompileError                      # noqa: E402
-from repro.core.gemm_compiler import (AluImmOp, AluIndexedImmOp,  # noqa: E402
-                                      AluPairOp, _wrap_int32,
-                                      compile_matmul)
+from repro.core.gemm_compiler import _wrap_int32                # noqa: E402
 from repro.core.hwconfig import VTAConfig, vta_default          # noqa: E402
+from repro.core.layer_compiler import LayerSpec                 # noqa: E402
 from repro.core.layout import truncate_int8                     # noqa: E402
-from repro.core.pallas_backend import (BatchPallasSimulator,    # noqa: E402
-                                       PallasSimulator, plan_pallas,
-                                       run_program_pallas)
+from repro.core.network_compiler import compile_network         # noqa: E402
+from repro.core.pallas_backend import (plan_pallas,             # noqa: E402
+                                       serving_lowering)
 from repro.core.program import VTAProgram                       # noqa: E402
 from repro.core.simulator import (BACKENDS, make_simulator,     # noqa: E402
-                                  run_program, run_program_batch,
-                                  verify_program)
+                                  run_program, run_program_batch)
 from repro.kernels import ops as kernel_ops                     # noqa: E402
 
 
@@ -118,117 +116,101 @@ def test_saturate_and_truncation_disagree_only_out_of_range():
 
 
 # ---------------------------------------------------------------------------
-# Program-level OUT-byte identity
+# Layer level: one-layer networks on the serving path
 # ---------------------------------------------------------------------------
 
-def _out_bytes(prog, dram):
-    region = prog.regions["out"]
-    start = region.phys_addr - prog.allocator.offset
-    return np.asarray(dram)[..., start:start + region.nbytes]
+# the tiny SRAM of test_batched_conformance: §3.3 multi-chunk programs
+_SMALL_CFG = VTAConfig(inp_buff_vectors=64, wgt_buff_matrices=4,
+                       acc_buff_vectors=64, out_buff_vectors=64,
+                       uop_buff_entries=32)
+
+
+def _fc_net(seed=810):
+    """An fc layer whose program is bias + ReLU + SHR: the fused form."""
+    rng = np.random.default_rng(seed)
+    spec = LayerSpec("fc", "fc",
+                     rng.integers(-128, 128, (34, 19)).astype(np.int8),
+                     rng.integers(-1000, 1000, (19,)).astype(np.int32),
+                     relu=True)
+    return compile_network([spec], rng.integers(-128, 128, (1, 34))
+                           .astype(np.int8))
+
+
+def _pool_net(seed, cfg=None):
+    """A padded conv whose 2×2 average pool lowers to pair ADDs and an
+    indexed SHR: the general form, finished in the host epilogue."""
+    rng = np.random.default_rng(seed)
+    spec = LayerSpec("c", "conv",
+                     rng.integers(-8, 8, (8, 3, 3, 3)).astype(np.int8),
+                     rng.integers(-100, 100, (8,)).astype(np.int32),
+                     padding=1, relu=True, pool="avg2x2")
+    return compile_network([spec], rng.integers(-32, 64, (1, 3, 12, 12))
+                           .astype(np.int8), cfg=cfg)
+
+
+def _images(net, seed, n=3):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(-64, 64, net.input_tensor.shape).astype(np.int8)
+            for _ in range(n)]
+
+
+def _served_three_ways(net, images):
+    """A pallas serve of the compile-time input and ``images``: the first
+    answer is the oracle's ``verify``, all are the batched interpreter's."""
+    want, _ = net.verify(backend="oracle")
+    batch = [net.input_tensor] + images
+    got, reports = net.serve(batch, backend="pallas")
+    np.testing.assert_array_equal(got[0], want)
+    ref, ref_reports = net.serve(batch, backend="batched")
+    np.testing.assert_array_equal(got, ref)
+    assert [r.gemm_loops for r in reports] == \
+        [r.gemm_loops for r in ref_reports]
+    return reports
 
 
 def test_fused_program_bit_identical_to_oracle():
     """A bias+relu+shr program — the whole epilogue fuses into the
-    kernel; OUT bytes equal the oracle's and the decode matches the
-    compiler's expected output."""
-    rng = np.random.default_rng(810)
-    A = rng.integers(-128, 128, (21, 34)).astype(np.int8)
-    B = rng.integers(-128, 128, (34, 19)).astype(np.int8)
-    X = np.broadcast_to(
-        rng.integers(-1000, 1000, (1, 19)).astype(np.int32), (21, 19)).copy()
-    prog = compile_matmul(A, B, X=X,
-                          alu_ops=[AluImmOp.relu(), AluImmOp.shr(4)])
-    assert plan_pallas(prog).fused
-    verify_program(prog, backend="pallas")
-    out_o, _ = run_program(prog, backend="oracle")
-    out_p, rep = run_program(prog, backend="pallas")
-    np.testing.assert_array_equal(out_p, out_o)
-    assert rep.gemm_loops == prog.gemm_loops()
+    kernel, and its answers equal the oracle's."""
+    net = _fc_net()
+    layer, = net.layers
+    p = plan_pallas(layer.program)
+    assert p.fused and p.relu and p.shift > 0
+    assert serving_lowering(layer).fused
+    reports = _served_three_ways(net, _images(net, 1))
+    assert reports[0].gemm_loops == 4 * layer.program.gemm_loops()
 
 
 def test_general_program_bit_identical_to_oracle():
     """Pair + indexed ops (the pool lowering shapes) force the
     kernel-GEMM + vectorised-TensorAlu path."""
-    rng = np.random.default_rng(811)
-    A = rng.integers(-128, 128, (16, 16)).astype(np.int8)
-    B = rng.integers(-128, 128, (16, 16)).astype(np.int8)
-    X = rng.integers(-(10 ** 6), 10 ** 6, (16, 16)).astype(np.int32)
-    pairs = tuple((d, d + 8) for d in range(8))
-    ops = [AluImmOp.relu(), AluPairOp(isa.AluOp.ADD, pairs),
-           AluIndexedImmOp(isa.AluOp.SHR, 3, tuple(range(8)))]
-    prog = compile_matmul(A, B, X=X, alu_ops=ops)
-    assert not plan_pallas(prog).fused
-    verify_program(prog, backend="pallas")
-    out_o, _ = run_program(prog, backend="oracle")
-    out_p, _ = run_program(prog, backend="pallas")
-    np.testing.assert_array_equal(out_p, out_o)
+    net = _pool_net(811)
+    layer, = net.layers
+    kinds = {type(op).__name__ for op in layer.program.alu_ops}
+    assert {"AluPairOp", "AluIndexedImmOp"} <= kinds
+    assert not plan_pallas(layer.program).fused
+    assert not serving_lowering(layer).fused
+    _served_three_ways(net, _images(net, 2))
 
 
 def test_multi_chunk_program_bit_identical():
     """Tiny SRAM → §3.3 multi-chunk instruction stream; the pallas
-    lowering works from the DRAM-level metadata, so the chunking must be
+    lowering works from the compiler's metadata, so the chunking must be
     invisible."""
-    cfg = VTAConfig(inp_buff_vectors=64, wgt_buff_matrices=4,
-                    acc_buff_vectors=64, out_buff_vectors=64,
-                    uop_buff_entries=32)
-    rng = np.random.default_rng(812)
-    A = rng.integers(-64, 64, (50, 40)).astype(np.int8)
-    B = rng.integers(-64, 64, (40, 33)).astype(np.int8)
-    prog = compile_matmul(A, B, alu_ops=[AluImmOp.relu(), AluImmOp.shr(2)],
-                          cfg=cfg)
-    assert prog.chunk_plan.n_chunks > 1
-    verify_program(prog, backend="pallas")
-
-
-def test_program_saturate_upgrade_clips_requant_acc():
-    """``saturate=True`` at the program level == clip of the requant ACC
-    (relu+shr applied, before the int8 commit) — and differs from the
-    truncation path on an overflowing program."""
-    rng = np.random.default_rng(813)
-    A = rng.integers(-128, 128, (8, 128)).astype(np.int8)
-    B = rng.integers(-128, 128, (128, 8)).astype(np.int8)
-    prog = compile_matmul(A, B, alu_ops=[AluImmOp.shr(2)])
-    acc = _wrap_int32(A.astype(np.int64) @ B.astype(np.int64))
-    acc = _wrap_int32(acc.astype(np.int64) >> 2)
-    out_sat, _ = run_program_pallas(prog, saturate=True)
-    np.testing.assert_array_equal(out_sat, np.clip(acc, -128, 127))
-    out_trunc, _ = run_program_pallas(prog, saturate=False)
-    np.testing.assert_array_equal(out_trunc, truncate_int8(acc))
-    assert not np.array_equal(out_sat, out_trunc)
+    net = _pool_net(812, cfg=_SMALL_CFG)
+    assert net.layers[0].n_chunks > 1
+    _served_three_ways(net, _images(net, 3))
 
 
 def test_run_program_batch_pallas_matches_batched():
-    """The batched entry point with per-row INP variation: pallas rows ==
-    batched-simulator rows, including the OUT bytes."""
-    rng = np.random.default_rng(814)
-    A = rng.integers(-64, 64, (24, 20)).astype(np.int8)
-    B = rng.integers(-64, 64, (20, 17)).astype(np.int8)
-    prog = compile_matmul(A, B, alu_ops=[AluImmOp.relu(), AluImmOp.shr(1)])
-    base = prog.dram_image()
-    stack = np.broadcast_to(base, (4, base.size)).copy()
-    region = prog.regions["inp"]
-    start = region.phys_addr - prog.allocator.offset
-    for r in range(1, 4):
-        stack[r, start:start + region.nbytes] = rng.integers(
-            0, 256, region.nbytes, dtype=np.uint8)
-    out_b, _ = run_program_batch(prog, dram_stack=stack.copy())
-    out_p, rep = run_program_batch(prog, dram_stack=stack.copy(),
-                                   backend="pallas")
-    np.testing.assert_array_equal(out_p, out_b)
-    assert rep.gemm_loops == 4 * prog.gemm_loops()
-
-
-def test_gemm_backend_xla_leg_equality():
-    """``gemm_backend="xla"`` routes the GEMM through the lowered
-    reference — same OUT bytes (the kernel and the reference share
-    semantics, so the backend choice is a deployment knob)."""
-    rng = np.random.default_rng(815)
-    A = rng.integers(-128, 128, (19, 23)).astype(np.int8)
-    B = rng.integers(-128, 128, (23, 31)).astype(np.int8)
-    prog = compile_matmul(A, B, alu_ops=[AluImmOp.relu(), AluImmOp.shr(3)])
-    out_k, _ = run_program_pallas(prog, gemm_backend="pallas")
-    out_x, _ = run_program_pallas(prog, gemm_backend="xla")
-    np.testing.assert_array_equal(out_k, out_x)
+    """Distinct images in one pallas serve: row for row the batched
+    interpreter's answers, and the batch-total counters."""
+    net = _fc_net(814)
+    images = _images(net, 4, n=4)
+    got, reports = net.serve(images, backend="pallas")
+    want, _ = net.serve(images, backend="batched")
+    np.testing.assert_array_equal(got, want)
+    assert len({g.tobytes() for g in got}) == len(images)
+    assert reports[0].gemm_loops == 4 * net.layers[0].program.gemm_loops()
 
 
 # ---------------------------------------------------------------------------
@@ -236,13 +218,32 @@ def test_gemm_backend_xla_leg_equality():
 # ---------------------------------------------------------------------------
 
 def test_make_simulator_dispatch():
-    assert "pallas" in BACKENDS
+    """The simulator entry points interpret instruction streams; each
+    refuses ``backend="pallas"`` with a typed error that names the one
+    pallas path."""
+    assert BACKENDS == ("oracle", "fast", "batched")
+    net = _fc_net()
+    prog = net.layers[0].program
     cfg = vta_default()
-    sim = make_simulator(cfg, np.zeros(1024, np.uint8), backend="pallas")
-    assert isinstance(sim, PallasSimulator) and not sim.is_batch
-    bsim = make_simulator(cfg, np.zeros((2, 1024), np.uint8),
-                          backend="pallas")
-    assert isinstance(bsim, BatchPallasSimulator) and bsim.is_batch
+    refusals = {
+        "make_simulator": lambda: make_simulator(
+            cfg, np.zeros(1024, np.uint8), backend="pallas"),
+        "make_simulator (stack)": lambda: make_simulator(
+            cfg, np.zeros((2, 1024), np.uint8), backend="pallas"),
+        "run_program": lambda: run_program(prog, backend="pallas"),
+        "run_program_batch": lambda: run_program_batch(
+            prog, batch=2, backend="pallas"),
+        "run_functional": lambda: net.run_functional(backend="pallas"),
+        "serve_one": lambda: net.serve_one(net.input_tensor,
+                                           backend="pallas"),
+    }
+    for entry, call in refusals.items():
+        with pytest.raises(CompileError,
+                           match=r'serve\(backend="pallas"\)') as exc:
+            call()
+        assert exc.value.constraint == (
+            "serve-one-backend" if entry == "serve_one"
+            else "simulator-backend"), entry
 
 
 def test_kernel_constraint_ids():
@@ -264,32 +265,19 @@ def test_kernel_constraint_ids():
 def test_non_compiler_program_raises_typed_error():
     """Hand-written streams carry no compiler metadata — the backend must
     refuse with the stable constraint id, not misexecute."""
-    cfg = vta_default()
-    prog = VTAProgram(config=cfg, allocator=DramAllocator())
-    sim = PallasSimulator(cfg, np.zeros(1024, np.uint8))
+    prog = VTAProgram(config=vta_default(), allocator=DramAllocator())
     with pytest.raises(CompileError) as exc:
-        sim.run_program(prog)
-    assert exc.value.constraint == "pallas-program-metadata"
-    with pytest.raises(CompileError) as exc:
-        sim.run([isa.FinishInsn()])
+        plan_pallas(prog)
     assert exc.value.constraint == "pallas-program-metadata"
 
 
 def test_unsupported_observability_raises():
-    """Per-instruction observability (trace, overflow counters, fault
-    hooks) has no meaning on a fused kernel call — loud errors, not
-    silent no-ops."""
-    cfg = vta_default()
-    rng = np.random.default_rng(816)
-    A = rng.integers(-8, 8, (4, 4)).astype(np.int8)
-    prog = compile_matmul(A, A, cfg=cfg)
-    with pytest.raises(ValueError, match="trace"):
-        PallasSimulator(cfg, prog.dram_image(), trace=True)
-    with pytest.raises(ValueError, match="trace"):
-        PallasSimulator(cfg, prog.dram_image(), count_overflows=True)
-    sim = PallasSimulator(cfg, prog.dram_image())
+    """Per-instruction fault hooks have no meaning on a fused kernel
+    call — a loud error, not a silent no-op."""
+    net = _fc_net()
     with pytest.raises(ValueError, match="fault_hook"):
-        sim.run_program(prog, fault_hook=lambda s, i: None)
+        net.serve(_images(net, 5, n=2), backend="pallas",
+                  fault_hook=lambda sim, k, i: None)
 
 
 # ---------------------------------------------------------------------------
@@ -313,11 +301,9 @@ def test_lenet5_serving_bit_identical():
     net = _compiled_lenet()
     rng = np.random.default_rng(817)
     img = rng.integers(0, 128, (1, 1, 32, 32)).astype(np.int8)
-    np.testing.assert_array_equal(net.serve_one(img, backend="pallas"),
+    out_p1, _ = net.serve([img], backend="pallas")
+    np.testing.assert_array_equal(out_p1[0],
                                   net.serve_one(img, backend="fast"))
-    out_f, _ = net.run_functional(backend="fast")
-    out_p, _ = net.run_functional(backend="pallas")
-    np.testing.assert_array_equal(out_p, out_f)
     batch = np.stack([rng.integers(0, 128, (1, 1, 32, 32)).astype(np.int8)
                       for _ in range(3)])
     out_b, _ = net.serve(batch)
@@ -339,9 +325,9 @@ def test_serve_rejects_bad_backend_and_guarded_pallas():
 
 def test_resnet8_serving_bit_identical():
     """Residual joins, stride-2 chunks and the GAP pair tree all ride the
-    general epilogue path end to end."""
+    general epilogue path end to end, at B = 1."""
     from repro.models.resnet8 import compile_resnet8, synthetic_image
     net, _ = compile_resnet8()
     img = synthetic_image(5)
-    np.testing.assert_array_equal(net.serve_one(img, backend="pallas"),
-                                  net.serve_one(img, backend="fast"))
+    out, _ = net.serve([img], backend="pallas")
+    np.testing.assert_array_equal(out[0], net.serve_one(img, backend="fast"))
