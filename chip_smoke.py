@@ -13,9 +13,11 @@ widths with seeded weights, it
 2. serves 16 requests through ``VTAServingEngine`` with two pallas workers,
    in bursts that use several rungs of the batch ladder, and checks every
    answer against a direct serve and the metrics audit;
-3. lowers one layer's kernel call as serving makes it (one program: A
-   staged from the activations, the pads, ``vta_gemm``, the slice) and
-   checks that it holds a compiled TPU kernel (``tpu_custom_call``).
+3. lowers two layers' kernel calls as serving makes them (each one
+   program: A staged from the activations, the pads, ``vta_gemm``, the
+   TensorAlu epilogue where the layer does not fuse, the valid rows) —
+   the first layer's and an unfused one's — and checks that each holds a
+   compiled TPU kernel (``tpu_custom_call``).
 
 JAX's persistent compilation cache lives where ``JAX_COMPILATION_CACHE_DIR``
 says, or else in ``.jax_cache/`` of the checkout; the compile lines report
@@ -173,21 +175,33 @@ def engine_phase(name, net, images, log) -> None:
 
 
 def kernel_phase(name, net) -> None:
-    """Lower the first layer's kernel call at batch 8 as serving makes it:
-    one program holding A's staging, the pads, ``vta_gemm`` and the
-    slice."""
+    """Lower two layers' kernel calls at batch 8 as serving makes them,
+    each one program: the first layer's, and that of the layer with the
+    longest TensorAlu program the kernel does not fuse (lenet5's pooled
+    ``l1_conv``, resnet8's ``head``, a join into the global pool), whose
+    epilogue runs after ``vta_gemm`` in the same program."""
     from repro.core.pallas_backend import kernel_call
     from repro.kernels.ops import _staged_vta_gemm, pallas_interpret
 
-    args, statics = kernel_call(net.layers[0], BATCH).matmul_args()
-    text = _staged_vta_gemm.lower(*args, interpret=pallas_interpret(),
-                                  **statics).as_text()
-    require("tpu_custom_call" in text,
-            f"{name}: the lowered kernel call holds no tpu_custom_call")
-    print(f"{name}: layer 0 kernel call, activations {args[0].shape} "
-          f"staged to A, A @ W {args[2].shape} (staging, pads, vta_gemm, "
-          f"slice) lowers to a tpu_custom_call (compiled Mosaic kernel, not "
-          f"interpreted)", flush=True)
+    calls = [kernel_call(layer, BATCH) for layer in net.layers]
+    unfused = max(range(len(calls)),
+                  key=lambda i: len(calls[i].epilogue or ()))
+    require(calls[unfused].epilogue is not None,
+            f"{name}: every layer fuses; no epilogue to lower")
+    for k in sorted({0, unfused}):
+        args, statics = calls[k].matmul_args()
+        text = _staged_vta_gemm.lower(*args, interpret=pallas_interpret(),
+                                      **statics).as_text()
+        require("tpu_custom_call" in text,
+                f"{name}: the lowered kernel call of layer {k} holds no "
+                f"tpu_custom_call")
+        steps = len(calls[k].epilogue or ())
+        print(f"{name}: layer {k} ({net.layers[k].spec.name}) kernel call, "
+              f"activations {args[0].shape} staged to A, A @ W "
+              f"{args[2].shape}, {steps} TensorAlu ops after the kernel "
+              f"(staging, pads, vta_gemm, epilogue, valid rows) lowers to a "
+              f"tpu_custom_call (compiled Mosaic kernel, not interpreted)",
+              flush=True)
 
 
 def main() -> None:

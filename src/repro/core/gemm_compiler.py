@@ -484,8 +484,8 @@ def apply_pairs(vec: np.ndarray, op, lat: PairLattice) -> np.ndarray:
     pair, in pair order, over int32 vectors whose second-to-last axis
     indexes them (``(n_vec, bs)``, or ``(B, n_vec, bs)`` for a batch);
     returns a new array.  int32 arithmetic wraps as the ALU's does.  The
-    one implementation of the pair semantics: the compiler's reference
-    and the pallas epilogue both run it."""
+    compiler's reference runs it; the pallas epilogue runs the same
+    lattice's passes in jnp (``kernels/ops.alu_epilogue``)."""
     out = vec.copy()
     if lat.sequential:
         for d, s in zip(lat.dst.tolist(), lat.src.tolist()):

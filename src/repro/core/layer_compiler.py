@@ -569,12 +569,14 @@ def decode_layer_output(layer: CompiledLayer, out_matrix: np.ndarray
     return decode_layer_output_batch(layer, out_matrix[None])[0]
 
 
-def decode_layer_output_batch(layer: CompiledLayer, out_matrices: np.ndarray
-                              ) -> np.ndarray:
+def decode_layer_output_batch(layer: CompiledLayer, out_matrices: np.ndarray,
+                              *, kept: bool = False) -> np.ndarray:
     """:func:`decode_layer_output` over a ``(B, M, N)`` stack of output
     matrices: ``(B, 1, F, H', W')`` for conv, ``(B, rows, F)`` for fc —
-    row ``b`` is ``decode_layer_output(layer, out_matrices[b])``."""
-    if layer.keep_rows is not None:
+    row ``b`` is ``decode_layer_output(layer, out_matrices[b])``.
+    ``kept``: the stack holds only the rows a pooled layer keeps, in
+    ``keep_rows``' order (what the pallas kernel call returns)."""
+    if layer.keep_rows is not None and not kept:
         out_matrices = out_matrices[:, list(layer.keep_rows)]
     if layer.spec.kind == "conv":
         b, _, f = out_matrices.shape

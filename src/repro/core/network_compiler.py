@@ -498,8 +498,8 @@ class NetworkProgram:
                     if stack is not None:
                         out_mats = decode_out_region_batch(layer.program,
                                                            stack)
-                    all_sems.append(decode_layer_output_batch(layer,
-                                                              out_mats))
+                    all_sems.append(decode_layer_output_batch(
+                        layer, out_mats, kept=stack is None))
         return np.array(all_sems[-1]), reports
 
     def _batched_layer(self, stack: np.ndarray, layer: CompiledLayer,
@@ -521,23 +521,17 @@ class NetworkProgram:
 
     def _pallas_layer(self, layer: CompiledLayer, src_sems, res_sems):
         """One layer on the pallas kernel: stack the layer's semantic
-        inputs (and its residual operand, zero-padded to the result's
-        block grid) and run it; the result comes back as ``(B, M, N)``."""
-        from .layer_compiler import residual_operand_matrix
-        from .pallas_backend import plan_pallas, serve_layer
+        inputs (and a join's skip activations) and run it; the result
+        comes back as ``(B, M, N)``, only the rows a pooled layer keeps."""
+        from .pallas_backend import serve_layer
         with span("vta.stage"):
             x = np.asarray(src_sems, dtype=np.int8)
             x = x[:, 0] if layer.spec.kind == "conv" else \
                 x.reshape(len(x), -1)
             res = None
             if res_sems is not None:
-                res = np.zeros((len(res_sems),)
-                               + plan_pallas(layer.program).padded_shape,
-                               np.int32)
-                m, n = layer.residual_matrix.shape
-                for r, sem in zip(res, res_sems):
-                    r[:m, :n] = residual_operand_matrix(layer.spec, sem,
-                                                        (m, n))
+                res = np.asarray(res_sems, dtype=np.int8)
+                res = res[:, 0] if res.ndim == 5 else res
         return serve_layer(layer, x, res)
 
 

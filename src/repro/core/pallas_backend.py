@@ -4,24 +4,24 @@ Where ``oracle``/``fast``/``batched`` *interpret* a compiled program's
 instruction stream, this backend runs the *semantics* the compiler
 recorded beside it (DESIGN.md §2): ``NetworkProgram.serve(backend=
 "pallas")`` calls :func:`serve_layer` for each layer.  It sends the
-layer's int8 activations, and the kernel's one jitted call
-(``ops.staged_vta_matmul``) stages A from them on the device and runs
-``kernels.vta_gemm`` — compiled on the TPU, interpreted on the CPU the
-tests run on, so they run the same kernel body.  W, the fused bias and the
-band gather are read once per program from its own segments
-(:func:`serving_lowering`) and put on the device once
-(:func:`serving_constants`).  No DRAM image is built or decoded per
-request.
+layer's int8 activations (and the skip's, at a join), and the kernel's one
+jitted call (``ops.staged_vta_matmul``) stages A from them on the device,
+runs ``kernels.vta_gemm`` — compiled on the TPU, interpreted on the CPU
+the tests run on, so they run the same kernel body — and returns the
+committed int8 rows the host reads.  W, the fused bias, the band gather
+and the epilogue's operands are read once per program from its own
+segments and ALU spec (:func:`serving_lowering`) and put on the device
+once (:func:`serving_constants`).  No DRAM image is built or decoded per
+request, and no int32 result comes back.
 
 When the layer's ALU program is exactly the fused-kernel form
 (``[relu?][shr?]`` with a row-broadcast bias) the whole layer runs inside
 ``vta_gemm``; richer programs (pool pair lattices, indexed SHR, residual
-ADD) run the GEMM on the kernel and the remaining TensorAlu ops as the
-vectorised int32 epilogue below (:func:`apply_alu_epilogue`), which
-mirrors ``gemm_compiler``'s reference semantics op for op (wraparound
-included).  The commit is the §2.1 truncation, so a pallas serve is bit
-for bit the simulators' (pinned by ``tests/test_pallas_backend.py`` and
-``tests/test_staged_serving.py``).
+ADD) run the GEMM on the kernel and the TensorAlu program after it in the
+same call (``ops.alu_epilogue``), op for op ``gemm_compiler``'s reference
+semantics (wraparound included).  The commit is the §2.1 truncation, so a
+pallas serve is bit for bit the simulators' (pinned by
+``tests/test_pallas_backend.py`` and ``tests/test_staged_serving.py``).
 """
 
 from __future__ import annotations
@@ -35,16 +35,15 @@ import numpy as np
 from . import isa
 from .errors import CompileError
 from .gemm_compiler import (AluImmOp, AluIndexedImmOp, AluPairOp,
-                            AluResidualOp, _wrap_int32, apply_pairs,
-                            pair_lattice)
+                            AluResidualOp, pair_lattice)
 from .hwconfig import VTAConfig
-from .layout import truncate_int8
 from .simulator import SimReport
 from repro.trace import span
 
 try:  # jax + the kernels layer are optional at import time (clean skips)
     import jax  # noqa: F401
     import jax.numpy as jnp
+    from repro.kernels.ops import AluStep
     HAS_PALLAS = True
     _IMPORT_ERROR = None
 except Exception as exc:  # pragma: no cover - exercised only without jax
@@ -71,7 +70,7 @@ def _require_pallas() -> None:
 
 @dataclasses.dataclass(frozen=True)
 class PallasPlan:
-    """Geometry + epilogue lowering for one compiled program.
+    """Geometry + ALU spec of one compiled program.
 
     ``fused`` marks ALU programs of the exact kernel-epilogue form
     (``[relu?][shr?]``): those run entirely inside ``vta_gemm``.  Region
@@ -92,10 +91,6 @@ class PallasPlan:
     # once; ``acc`` is None when the program has no X preload
     wgt: Tuple[int, int]
     acc: Optional[Tuple[int, int]]
-    # the pair ops' lattices, and the result rows (one image's input
-    # positions) the pool reads: the pooling part of the epilogue
-    pair_lattices: Tuple = dataclasses.field(default=(), compare=False)
-    pool_rows: int = 0
     # what serving keeps for this program: :func:`serving_lowering` and
     # its device copy, :func:`serving_constants`
     held: dict = dataclasses.field(default_factory=dict, compare=False,
@@ -154,11 +149,6 @@ def _lower(prog) -> PallasPlan:
         return region.phys_addr - prog.allocator.offset, nbytes
 
     fused = _fused_form(prog.alu_ops)
-    pair_ops = [spec for spec in prog.alu_ops
-                if isinstance(spec, AluPairOp)]
-    pooled = np.unique(np.concatenate(
-        [np.asarray(spec.pairs, np.int64).reshape(-1) for spec in pair_ops]
-        or [np.zeros(0, np.int64)]))
     return PallasPlan(
         alpha=alpha, lam=lam, beta=beta, row_height=rh, block_size=bs,
         valid_shape=tuple(prog.output_meta.valid_shape),
@@ -168,18 +158,17 @@ def _lower(prog) -> PallasPlan:
         shift=fused[1] if fused else 0,
         wgt=_span("wgt", lam * beta * bs * bs),
         acc=(_span("acc", alpha * beta * rh * bs * 4)
-             if "acc" in prog.regions else None),
-        pair_lattices=tuple(pair_lattice(spec.op, spec.pairs)
-                            for spec in pair_ops),
-        # a vector index → its result row (block-major, §3.2)
-        pool_rows=len(np.unique(pooled // (beta * rh) * rh + pooled % rh)))
+             if "acc" in prog.regions else None))
 
 
 @dataclasses.dataclass(frozen=True)
 class KernelCall:
     """One served layer's kernel call over a batch: the activation stack
-    it sends, how the device stages A from it (``ops.stage_rows``), and
-    the padded GEMM ``(m, k) @ (k, n)`` with its epilogue."""
+    it sends (and the skip's, at a join), how the device stages A from it
+    (``ops.stage_rows``), the padded GEMM ``(m, k) @ (k, n)``, the
+    TensorAlu epilogue after it where the kernel does not fuse the
+    layer's, and the rows and columns of each image's result that come
+    back."""
 
     x: Tuple[int, ...]            # (B, C, H, W) conv input, (B, D) fc
     conv: Optional[Tuple[int, int, int, int]]   # (kh, kw, stride, pad)
@@ -190,7 +179,13 @@ class KernelCall:
     bias: bool
     relu: bool
     shift: int
-    out_dtype: object
+    out_dtype: object             # the kernel's: int32 under an epilogue
+    valid: Tuple[int, int]        # each image's result (M, N)
+    keep: int = 0                 # rows a pooled layer keeps, 0 for all
+    res: Optional[Tuple[int, ...]] = None   # the skip activations' shape
+    preload: bool = False         # an X preload (rows, n) int32 operand
+    epilogue: Optional[Tuple] = None        # ``ops.AluStep``s, or fused
+    tables: Tuple[int, ...] = ()  # the epilogue's index tables' lengths
 
     @property
     def m(self) -> int:
@@ -201,17 +196,22 @@ class KernelCall:
         (serving's truncating commit, the default blocks)."""
         return dict(conv=self.conv, rows=self.rows, cols=self.k,
                     relu=self.relu, shift=self.shift, saturate=False,
-                    out_dtype=self.out_dtype)
+                    out_dtype=self.out_dtype, valid=self.valid,
+                    epilogue=self.epilogue)
 
     def matmul_args(self, sharding=None):
         """``(operand shapes, static kwargs)`` of the one jitted call
         serving makes (``ops._staged_vta_gemm``: staging, pads,
-        ``vta_gemm``, slice; ``interpret`` left to the caller) — what its
-        ``lower`` takes."""
+        ``vta_gemm``, the epilogue, the valid rows; ``interpret`` left to
+        the caller) — what its ``lower`` takes."""
         shapes = [(self.x, jnp.int8),
                   ((self.gather,), jnp.int32) if self.gather else None,
                   ((self.k, self.n), jnp.int8),
-                  ((self.n,), jnp.int32) if self.bias else None]
+                  ((self.n,), jnp.int32) if self.bias else None,
+                  ((self.rows, self.n), jnp.int32) if self.preload else None,
+                  (self.res, jnp.int8) if self.res else None,
+                  ((self.keep,), jnp.int32) if self.keep else None,
+                  *(((t,), jnp.int32) for t in self.tables)]
         return _shape_structs(shapes, sharding), dict(
             self.statics(), block_m=256, block_n=256, block_k=256)
 
@@ -247,39 +247,144 @@ def kernel_call(layer, batch: int) -> KernelCall:
     """The kernel call ``serve(backend="pallas")`` makes for the compiled
     ``layer`` over ``batch`` images: A staged on the device from the
     activations, the whole program inside the kernel when its epilogue
-    fuses, else the bare int32 GEMM that :func:`apply_alu_epilogue`
-    finishes.  The chip-compile rehearsal compiles exactly these calls."""
+    fuses, else the int32 GEMM and the layer's TensorAlu epilogue after it
+    (:func:`device_alu`), and only the rows the host reads back.  The
+    chip-compile rehearsal compiles exactly these calls."""
     p = plan_pallas(layer.program)
+    low = serving_lowering(layer)
     geo = layer.geometry
-    gather = _layer_gather(layer)
-    mp, np_ = p.padded_shape
+    m, n = p.valid_shape
     shape = dict(
         x=((batch, layer.input_matrix.shape[1]) if geo is None else
            (batch, geo.in_channels, geo.in_h, geo.in_w)),
         conv=None if geo is None else (geo.kh, geo.kw, geo.stride, geo.pad),
-        gather=0 if gather is None else len(gather),
-        rows=mp, k=p.lam * p.block_size, n=np_)
-    if serving_lowering(layer).fused:
-        return KernelCall(**shape, bias=p.acc is not None, relu=p.relu,
+        gather=0 if low.gather is None else len(low.gather),
+        rows=p.padded_shape[0], k=p.lam * p.block_size,
+        n=p.padded_shape[1], valid=(m, n),
+        keep=0 if low.keep is None else len(low.keep))
+    if low.fused:
+        return KernelCall(**shape, bias=low.bias is not None, relu=p.relu,
                           shift=p.shift, out_dtype=jnp.int8)
+    res = None
+    if layer.residual_matrix is not None:   # the skip's map, or its matrix
+        res = ((batch,) + layer.residual_matrix.shape if geo is None
+               else (batch, n, geo.out_h, geo.out_w))
     return KernelCall(**shape, bias=False, relu=False, shift=0,
-                      out_dtype=jnp.int32)
+                      out_dtype=jnp.int32, res=res,
+                      preload=low.preload is not None,
+                      epilogue=low.epilogue,
+                      tables=tuple(len(t) for t in low.tables))
+
+
+# ---------------------------------------------------------------------------
+# The TensorAlu program as the kernel's jitted call runs it
+# ---------------------------------------------------------------------------
+
+_ALU_OPS = {isa.AluOp.MIN: "min", isa.AluOp.MAX: "max",
+            isa.AluOp.ADD: "add", isa.AluOp.SHR: "shr"}
+
+
+def _alu_op(op) -> str:
+    if op not in _ALU_OPS:
+        raise CompileError(f"unsupported ALU op {op!r}",
+                           constraint="pallas-alu-op")
+    return _ALU_OPS[op]
+
+
+def _row_major(p: PallasPlan, vec: np.ndarray) -> np.ndarray:
+    """Block-major result-vector indices (§3.2) → indices of the same
+    vectors in the result's row-major view ``(α·rh·β, bs)``."""
+    block_row, rest = np.divmod(np.asarray(vec, np.int64),
+                                p.beta * p.row_height)
+    col_block, within = np.divmod(rest, p.row_height)
+    return (block_row * p.row_height + within) * p.beta + col_block
+
+
+def _whole_rows(p: PallasPlan, *tables) -> Optional[Tuple[np.ndarray, ...]]:
+    """Row-major vector tables of equal length as result rows, where they
+    name whole rows: taken in the order of the first, each holds the β
+    vectors of its rows in order, so entry ``t`` of one table sits beside
+    entry ``t`` of the others.  None where they do not."""
+    g = p.beta
+    vec = np.stack(tables)
+    vec = vec[:, np.argsort(vec[0], kind="stable")]
+    if vec.shape[1] % g:
+        return None
+    groups = vec.reshape(len(tables), -1, g)
+    if (groups[..., 0] % g).any() or \
+            (groups != groups[..., :1] + np.arange(g)).any():
+        return None
+    return tuple((groups[..., 0] // g).astype(np.int32))
+
+
+def _index_tables(p: PallasPlan, groups, rows: bool = True):
+    """``(lanes, tables)``: each group of block-major tables as row-major
+    index tables — whole rows of ``β·bs`` lanes where every group names
+    whole rows (and ``rows`` allows it), else vectors of ``bs``."""
+    vec = [tuple(_row_major(p, t) for t in group) for group in groups]
+    if rows:
+        whole = [_whole_rows(p, *group) for group in vec]
+        if all(w is not None for w in whole):
+            return p.beta * p.block_size, [t for w in whole for t in w]
+    return p.block_size, [t.astype(np.int32) for group in vec for t in group]
+
+
+def device_alu(p: PallasPlan) -> Tuple[Tuple, Tuple[np.ndarray, ...]]:
+    """``p``'s TensorAlu program as ``ops.alu_epilogue`` runs it: one
+    ``ops.AluStep`` per op (the jitted call's static description) and the
+    int32 index tables of the indexed and pair ops (its operands), made
+    once per program.  A lattice whose pairs are independent runs as its
+    passes; one that must run in pair order (``PairLattice.sequential``)
+    runs pair by pair."""
+    steps, tables = [], []
+    for spec in p.alu_ops:
+        op = _alu_op(spec.op)
+        if isinstance(spec, AluImmOp):
+            steps.append(AluStep("imm", op, spec.imm))
+        elif isinstance(spec, AluResidualOp):
+            steps.append(AluStep("residual", op, spec.pre_shift))
+        elif isinstance(spec, AluIndexedImmOp):
+            lanes, idx = _index_tables(p, [(np.unique(spec.indices),)])
+            steps.append(AluStep("indexed", op, spec.imm, lanes))
+            tables += idx
+        elif isinstance(spec, AluPairOp):
+            lat = pair_lattice(spec.op, spec.pairs)
+            if lat.sequential:
+                lanes, pairs = _index_tables(p, [(lat.dst, lat.src)],
+                                             rows=False)
+                steps.append(AluStep("pairs_in_order", op, 0, lanes))
+            else:
+                lanes, pairs = _index_tables(p, lat.passes)
+                steps.append(AluStep("pairs", op, len(lat.passes), lanes))
+            tables += pairs
+        else:
+            raise CompileError(f"unknown ALU spec {type(spec).__name__}",
+                               constraint="pallas-alu-op")
+    return tuple(steps), tuple(tables)
 
 
 @dataclasses.dataclass(frozen=True)
 class ServingLowering:
     """What every ``serve(backend="pallas")`` of one compiled layer uses
-    besides its activations, decoded once from the program's own WGT and
-    ACC segments: ``w`` ``(λ·bs, β·bs)`` int8, the fused
-    row-broadcast ``bias`` ``(β·bs,)`` int32 or None, the int32 X
-    ``preload`` ``(1, α·rh, β·bs)`` the host epilogue adds where the
-    layer does not run whole in the kernel, and the band ``gather``."""
+    besides its activations, made once from the program's own WGT and ACC
+    segments and ALU spec: ``w`` ``(λ·bs, β·bs)`` int8, the fused
+    row-broadcast ``bias`` ``(β·bs,)`` int32 or None and the band
+    ``gather``; where the layer does not run whole in the kernel, the int32
+    X ``preload`` ``(α·rh, β·bs)`` or None, the ``epilogue``
+    (:func:`device_alu`'s steps) and its index ``tables``; and ``keep``,
+    the result rows a pooled layer keeps."""
 
     w: np.ndarray
     bias: Optional[np.ndarray]
-    preload: Optional[np.ndarray]
     gather: Optional[np.ndarray]
-    fused: bool
+    preload: Optional[np.ndarray]
+    epilogue: Optional[Tuple]
+    tables: Tuple[np.ndarray, ...]
+    keep: Optional[np.ndarray]
+
+    @property
+    def fused(self) -> bool:
+        return self.epilogue is None
 
 
 def _held(p: PallasPlan, key: str, make):
@@ -301,34 +406,38 @@ def serving_lowering(layer) -> ServingLowering:
     and zero in the pad rows, and the activations staged on the device
     give A zero pad rows, so such a preload fuses into the kernel (its
     pad rows read 0·B + 0 as the oracle's do, and are sliced off).  A
-    preload of any other form stays with the host epilogue."""
+    preload of any other form is the epilogue's."""
     p = plan_pallas(layer.program)
 
     def make():
         row = layer.program.dram_image()[None]
-        x = _decode_acc32(row, p) if p.acc else None
+        x = _decode_acc32(row, p)[0] if p.acc else None
         bias, fused = None, p.fused
         if x is not None and fused:
             m = p.valid_shape[0]
-            if (x[:, :m] == x[:, :1]).all() and (x[:, m:] == 0).all():
-                bias, x = x[0, 0], None
-            else:
-                fused = False
+            fused = bool((x[:m] == x[:1]).all() and (x[m:] == 0).all())
+            if fused:
+                bias, x = x[0], None
+        epilogue, tables = (None, ()) if fused else device_alu(p)
         gather = _layer_gather(layer)
         return ServingLowering(
-            w=_decode_wgt(row, p)[0], bias=bias, preload=x,
+            w=_decode_wgt(row, p)[0], bias=bias,
             gather=None if gather is None else gather.astype(np.int32),
-            fused=fused)
+            preload=x, epilogue=epilogue, tables=tables,
+            keep=(None if layer.keep_rows is None
+                  else np.asarray(layer.keep_rows, np.int32)))
     return _held(p, "lowering", make)
 
 
 def serving_constants(layer) -> Tuple:
-    """``(w, bias, gather)`` of :func:`serving_lowering` on the device:
-    put once per program, inside a ``vta.const.put`` span."""
+    """``(w, bias, gather, preload, keep, *tables)`` of
+    :func:`serving_lowering` on the device: put once per program, inside a
+    ``vta.const.put`` span."""
     low = serving_lowering(layer)
 
     def make():
-        consts = (low.w, low.bias, low.gather)
+        consts = (low.w, low.bias, low.gather, low.preload, low.keep,
+                  *low.tables)
         sent = sum(c.nbytes for c in consts if c is not None)
         with span("vta.const.put", bytes=sent):
             return tuple(jax.device_put(consts))
@@ -336,8 +445,7 @@ def serving_constants(layer) -> Tuple:
 
 
 # ---------------------------------------------------------------------------
-# §3.2 layout: the program's WGT and ACC segments, read once per program,
-# and the result's block-major vectors
+# §3.2 layout: the program's WGT and ACC segments, read once per program
 # ---------------------------------------------------------------------------
 
 def _decode_wgt(stack: np.ndarray, p: PallasPlan) -> np.ndarray:
@@ -360,130 +468,9 @@ def _decode_acc32(stack: np.ndarray, p: PallasPlan) -> np.ndarray:
         b, p.alpha * p.row_height, p.beta * p.block_size)
 
 
-def _to_vectors(mat: np.ndarray, p: PallasPlan) -> np.ndarray:
-    """(B, H, W) → (B, n_vec, bs) block-major result vectors."""
-    b = mat.shape[0]
-    blocks = mat.reshape(b, p.alpha, p.row_height, p.beta, p.block_size)
-    return blocks.transpose(0, 1, 3, 2, 4).reshape(
-        b, p.alpha * p.beta * p.row_height, p.block_size)
-
-
-def _to_matrix(vec: np.ndarray, p: PallasPlan) -> np.ndarray:
-    b = vec.shape[0]
-    blocks = vec.reshape(b, p.alpha, p.beta, p.row_height, p.block_size)
-    return blocks.transpose(0, 1, 3, 2, 4).reshape(
-        b, p.alpha * p.row_height, p.beta * p.block_size)
-
-
-# ---------------------------------------------------------------------------
-# The TensorAlu epilogue, vectorised over the batch (oracle semantics)
-# ---------------------------------------------------------------------------
-
-def _imm_apply(sel64: np.ndarray, op: isa.AluOp, imm: int) -> np.ndarray:
-    if op == isa.AluOp.MIN:
-        return np.minimum(sel64, imm)
-    if op == isa.AluOp.MAX:
-        return np.maximum(sel64, imm)
-    if op == isa.AluOp.ADD:
-        return sel64 + imm
-    if op == isa.AluOp.SHR:
-        return sel64 >> imm
-    raise CompileError(f"unsupported ALU immediate op {op!r}",
-                       constraint="pallas-alu-op")
-
-
-def _add32(a: np.ndarray, b) -> np.ndarray:
-    """int32 addition with the ALU's wraparound, done in uint32 (whose
-    overflow is defined) — equal to the int64 sum wrapped to int32."""
-    return (a.view(np.uint32) + np.asarray(b, np.int32).view(np.uint32)) \
-        .view(np.int32)
-
-
-def _imm32(vec: np.ndarray, op: isa.AluOp, imm: int) -> np.ndarray:
-    """An immediate op over int32 vectors without widening them: MIN/MAX
-    and an arithmetic SHR are exact in int32 (a shift past 31 leaves the
-    sign, as in int64), ADD wraps as the ALU does; anything else takes
-    the int64 path."""
-    if op == isa.AluOp.ADD:
-        return _add32(vec, np.int64(imm).astype(np.int32))
-    if op in (isa.AluOp.MIN, isa.AluOp.MAX) and -2 ** 31 <= imm < 2 ** 31:
-        return (np.minimum if op == isa.AluOp.MIN else np.maximum)(
-            vec, np.int32(imm))
-    if op == isa.AluOp.SHR and imm >= 0:
-        return vec >> np.int32(min(imm, 31))
-    return _wrap_int32(_imm_apply(vec.astype(np.int64), op, imm))
-
-
-def apply_alu_epilogue(vec: np.ndarray, p: PallasPlan,
-                       res_vec: Optional[np.ndarray]) -> np.ndarray:
-    """``p``'s whole TensorAlu program over (B, n_vec, bs) int32 vectors —
-    op-for-op the semantics of ``gemm_compiler.reference_result``.  The
-    pooling part (the pair ops and what follows them) runs inside a
-    ``vta.pool`` span that counts the input positions it reads."""
-    ops = p.alu_ops
-    lattices = iter(p.pair_lattices)
-    first_pair = next((i for i, spec in enumerate(ops)
-                       if isinstance(spec, AluPairOp)), len(ops))
-    vec = _alu_ops(vec, ops[:first_pair], res_vec, lattices)
-    if first_pair < len(ops):
-        with span("vta.pool", positions=len(vec) * p.pool_rows):
-            vec = _alu_ops(vec, ops[first_pair:], res_vec, lattices)
-    return vec
-
-
-def _alu_ops(vec: np.ndarray, alu_ops, res_vec: Optional[np.ndarray],
-             lattices) -> np.ndarray:
-    for spec in alu_ops:
-        if isinstance(spec, AluImmOp):
-            vec = _imm32(vec, spec.op, spec.imm)
-        elif isinstance(spec, AluIndexedImmOp):
-            idx = np.asarray(spec.indices, dtype=np.int64)
-            vec = vec.copy()
-            vec[:, idx] = _imm32(vec[:, idx], spec.op, spec.imm)
-        elif isinstance(spec, AluPairOp):
-            vec = apply_pairs(vec, spec.op, next(lattices))
-        elif isinstance(spec, AluResidualOp):
-            if res_vec is None:
-                raise CompileError(
-                    "AluResidualOp requires a staged residual operand",
-                    constraint="residual-operand-missing")
-            r = res_vec
-            if spec.pre_shift:
-                r = _imm32(r, isa.AluOp.SHR, spec.pre_shift)
-            if spec.op == isa.AluOp.MIN:
-                vec = np.minimum(vec, r)
-            elif spec.op == isa.AluOp.MAX:
-                vec = np.maximum(vec, r)
-            elif spec.op == isa.AluOp.ADD:
-                vec = _add32(vec, r)
-            elif spec.op == isa.AluOp.SHR:
-                vec = vec >> (r & 31)
-            else:
-                raise CompileError(
-                    f"unsupported residual ALU op {spec.op!r}",
-                    constraint="pallas-alu-op")
-        else:
-            raise CompileError(f"unknown ALU spec {type(spec).__name__}",
-                               constraint="pallas-alu-op")
-    return vec
-
-
 # ---------------------------------------------------------------------------
 # One served layer
 # ---------------------------------------------------------------------------
-
-def _epilogue(acc: np.ndarray, p: PallasPlan, x: Optional[np.ndarray],
-              res: Optional[np.ndarray]) -> np.ndarray:
-    """The int32 GEMM result ``(B, α·rh, β·bs)`` through the X preload and
-    the TensorAlu program to the committed int8 result (§2.1 truncation)."""
-    with span("vta.epilogue"):
-        if x is not None:                           # ACC preload (C = A·B+X)
-            acc = _add32(acc, x)
-        vec = _to_vectors(acc, p)
-        res_vec = _to_vectors(res, p) if res is not None else None
-        vec = apply_alu_epilogue(vec, p, res_vec)
-        return truncate_int8(_to_matrix(vec, p))
-
 
 def _report(prog, b: int) -> SimReport:
     report = SimReport()
@@ -496,13 +483,15 @@ def serve_layer(layer, x: np.ndarray, res: Optional[np.ndarray]
                 ) -> Tuple[np.ndarray, SimReport]:
     """One layer of ``serve(backend="pallas")``, no DRAM image: ``x`` is
     the stacked int8 activations (``(B, C, H, W)``, or ``(B, D)`` for an fc
-    layer), ``res`` the int32 residual operand ``(B, α·rh, β·bs)`` or None.
-    The device stages A from ``x`` inside the kernel's jitted call; W, the
-    bias and the band gather are already there (:func:`serving_constants`).
-    Returns the committed ``(B, M, N)`` int8 result at its valid shape."""
+    layer), ``res`` the stacked int8 skip activations of a join
+    (``(B, C, H, W)``, or ``(B, M, N)``) or None; both go up in one put.
+    The device stages A from ``x``, runs the kernel and the layer's
+    epilogue inside the kernel's jitted call; W, the bias, the gather and
+    the epilogue's tables are already there (:func:`serving_constants`).
+    Returns the committed int8 result ``(B, M, N)`` at its valid shape,
+    ``M`` the kept rows of a pooled layer."""
     _require_pallas()
     from repro.kernels import ops as kernel_ops
-    p = plan_pallas(layer.program)
     b = x.shape[0]
     call = kernel_call(layer, b)
     if x.shape != call.x:
@@ -510,17 +499,21 @@ def serve_layer(layer, x: np.ndarray, res: Optional[np.ndarray]
             f"layer {layer.spec.name!r}: activations of shape {x.shape[1:]}, "
             f"compiled for {call.x[1:]} — request shape does not match the "
             f"compiled geometry")
-    w, bias, gather = serving_constants(layer)
+    if (None if res is None else res.shape) != call.res:
+        raise CompileError(
+            f"skip activations of shape "
+            f"{None if res is None else res.shape[1:]} for a layer whose "
+            f"join takes {None if call.res is None else call.res[1:]}",
+            layer=layer.spec.name, constraint="residual-shape")
+    w, bias, gather, preload, keep, *tables = serving_constants(layer)
+    sent = (x,) if res is None else (x, res)
     with span("vta.kernel"):
-        with span("vta.kernel.put", bytes=x.nbytes):
-            x = jax.device_put(x)
-        out = kernel_ops.staged_vta_matmul(x, gather, w, bias,
-                                           **call.statics())
+        with span("vta.kernel.put", bytes=sum(a.nbytes for a in sent)):
+            sent = jax.device_put(sent)
+        out = kernel_ops.staged_vta_matmul(
+            sent[0], gather, w, bias, preload=preload,
+            res=sent[1] if res is not None else None, keep=keep,
+            tables=tables, **call.statics())
         with span("vta.kernel.fetch", bytes=out.size * out.dtype.itemsize):
             out = np.asarray(out)       # read-only, as jax buffers are
-    out = out.reshape(b, call.rows, call.n)
-    low = serving_lowering(layer)
-    if not low.fused:
-        out = _epilogue(out, p, low.preload, res)
-    m, n = p.valid_shape
-    return out[:, :m, :n], _report(layer.program, b)
+    return out.reshape(b, -1, out.shape[1]), _report(layer.program, b)

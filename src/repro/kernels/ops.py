@@ -3,11 +3,13 @@
 Every ``vta_gemm`` call goes through one jitted program,
 :func:`_staged_vta_gemm`: the staging of a served layer's GEMM input from
 its activations (:func:`stage_rows`; the identity for a plain matrix),
-shape padding to block multiples (:func:`gemm_blocks`), the kernel and
-the slice back.  Also here: the one decision of whether a kernel runs
-compiled or interpreted (:func:`pallas_interpret`: compiled on the TPU,
-interpreted only on the CPU the tests run on), and the ``xla`` legs that
-call the pure-JAX references in ``kernels/ref.py``.
+shape padding to block multiples (:func:`gemm_blocks`), the kernel, the
+layer's TensorAlu epilogue where it does not fuse into the kernel
+(:func:`alu_epilogue`, then the int8 commit) and the slice to the rows
+and columns the host reads.  Also here: the one decision of whether a
+kernel runs compiled or interpreted (:func:`pallas_interpret`: compiled
+on the TPU, interpreted only on the CPU the tests run on), and the
+``xla`` legs that call the pure-JAX references in ``kernels/ref.py``.
 """
 
 from __future__ import annotations
@@ -160,17 +162,145 @@ def stage_rows(x, gather, *, conv, rows, cols):
     return a.reshape(b * rows, cols)
 
 
+class AluStep(NamedTuple):
+    """One TensorAlu op of a served layer's epilogue, as the jitted call's
+    static description (:func:`alu_epilogue`).  ``kind`` is ``"imm"``
+    (every element), ``"residual"`` (against the skip operand),
+    ``"indexed"`` (the vectors of one index table), ``"pairs"`` (a
+    lattice of ``arg`` passes, a destination and a source table each) or
+    ``"pairs_in_order"`` (pair by pair, a destination and a source table);
+    ``op`` is ``"min"``, ``"max"``, ``"add"`` or ``"shr"``; ``arg`` the
+    immediate, the residual's pre-shift or the pass count; ``lanes`` the
+    int32 elements of each vector an index table names.  Index tables are
+    operands of the call, never part of this description."""
+
+    kind: str
+    op: str
+    arg: int = 0
+    lanes: int = 0
+
+
+def _wrap32(v: int) -> int:
+    return (v + 2 ** 31) % 2 ** 32 - 2 ** 31
+
+
+def _shr_amount(s: int) -> int:
+    """An arithmetic shift past 31, or by a negative amount, leaves the
+    sign, as the int64 reference's shift of an int32 value does."""
+    return s if 0 <= s < 32 else 31
+
+
+def _imm(op: str, v, imm: int):
+    """``op(v, imm)`` over int32 ``v``, equal to the reference's int64 op
+    wrapped to int32."""
+    if op == "add":
+        return v + jnp.int32(_wrap32(imm))
+    if op == "shr":
+        return jax.lax.shift_right_arithmetic(v, jnp.int32(_shr_amount(imm)))
+    if -2 ** 31 <= imm < 2 ** 31:
+        return (jnp.minimum if op == "min" else jnp.maximum)(v, jnp.int32(imm))
+    if (imm > 0) == (op == "min"):      # every int32 is on the kept side
+        return v
+    return jnp.full_like(v, _wrap32(imm))
+
+
+def _pair(op: str, a, b):
+    """``op(a, b)`` of two int32 operands; ADD wraps, SHR takes ``b & 31``."""
+    if op == "min":
+        return jnp.minimum(a, b)
+    if op == "max":
+        return jnp.maximum(a, b)
+    if op == "add":
+        return a + b
+    return jax.lax.shift_right_arithmetic(a, b & 31)
+
+
+def _skip_matrix(res, rows: int, n: int):
+    """The skip activations as the residual's int32 ``(B, rows, n)``
+    operand: a ``(B, C, H, W)`` map in ``tensor2mat``'s ``(H·W, C)`` order,
+    a ``(B, M, N)`` matrix as it is, zero-padded."""
+    if res.ndim == 4:
+        b, c, h, w = res.shape
+        res = res.transpose(0, 2, 3, 1).reshape(b, h * w, c)
+    res = res.astype(jnp.int32)
+    return jnp.pad(res, ((0, 0), (0, rows - res.shape[1]),
+                         (0, n - res.shape[2])))
+
+
+def alu_epilogue(acc, preload, res, tables, *, steps):
+    """A layer's TensorAlu program over a batch of int32 GEMM results
+    ``acc`` ``(B, rows, n)``, op for op ``gemm_compiler.reference_result``:
+    the X ``preload`` ``(rows, n)`` added (wrapping), then ``steps``
+    (:class:`AluStep`) in order.  ``res`` is the skip operand
+    (:func:`_skip_matrix`) or None; ``tables`` the int32 index tables the
+    indexed and pair steps take, in step order.  Their indices name
+    vectors of ``lanes`` elements in the row-major view of ``acc``: where
+    a vector is a whole row they index rows.  Returns int32."""
+    b, rows, n = acc.shape
+    if preload is not None:
+        acc = acc + preload
+    if res is not None:
+        res = _skip_matrix(res, rows, n)
+    tables = iter(tables)
+    for step in steps:
+        if step.kind == "imm":
+            acc = _imm(step.op, acc, step.arg)
+            continue
+        if step.kind == "residual":
+            acc = _pair(step.op, acc, _imm("shr", res, step.arg))
+            continue
+        v = acc.reshape(b, -1, step.lanes)
+        if step.kind == "indexed":
+            idx = next(tables)
+            v = v.at[:, idx].set(_imm(step.op, v[:, idx], step.arg),
+                                 unique_indices=True)
+        elif step.kind == "pairs":
+            # no source is a destination, and no pass repeats one
+            for _ in range(step.arg):
+                dst, src = next(tables), next(tables)
+                v = v.at[:, dst].set(_pair(step.op, v[:, dst], v[:, src]),
+                                     unique_indices=True)
+        else:
+            dst, src = next(tables), next(tables)
+
+            def one(i, v, dst=dst, src=src, op=step.op):
+                a = jax.lax.dynamic_index_in_dim(v, dst[i], 1)
+                s = jax.lax.dynamic_index_in_dim(v, src[i], 1)
+                return jax.lax.dynamic_update_index_in_dim(
+                    v, _pair(op, a, s), dst[i], 1)
+
+            v = jax.lax.fori_loop(0, dst.shape[0], one, v)
+        acc = v.reshape(b, rows, n)
+    return acc
+
+
+def commit_int8(acc):
+    """The §2.1 commit: the low 8 bits of each int32, as ``vta_gemm``'s
+    ``saturate=False`` truncation."""
+    return jax.lax.shift_right_arithmetic(
+        jax.lax.shift_left(acc, jnp.int32(24)),
+        jnp.int32(24)).astype(jnp.int8)
+
+
 def staged_vta_matmul(x: jax.Array, gather: Optional[jax.Array],
                       b: jax.Array, bias: Optional[jax.Array] = None, *,
                       conv, rows: int, cols: int,
                       relu: bool = False, shift: int = 0,
                       saturate: bool = True, out_dtype=jnp.int8,
+                      valid=None, preload=None, res=None, keep=None,
+                      epilogue=None, tables=(),
                       block_m: int = 256, block_n: int = 256,
                       block_k: int = 256) -> jax.Array:
     """The fused GEMM of one served layer, its input staged on the device
-    (:func:`stage_rows`) in the kernel's own jitted call: ``x``'s
-    activations in, ``(B·rows, n)`` out.  The pallas leg only, compiled on
-    the TPU and interpreted on the CPU (:func:`pallas_interpret`)."""
+    (:func:`stage_rows`) and its TensorAlu epilogue run (:func:`alu_epilogue`)
+    in the kernel's own jitted call: ``x``'s activations in, ``(B·m', n')``
+    out.  ``valid`` is ``(m, n')``, the rows and columns of each image's
+    result kept (default: all ``rows`` and every column); ``keep``, an
+    int32 table, keeps those rows instead.  ``epilogue`` (a tuple of
+    :class:`AluStep`, or None) runs on the kernel's int32 result with the
+    ``preload``, the skip activations ``res`` and the index ``tables``, and
+    the call then commits int8.  The pallas leg only, compiled on the TPU
+    and interpreted on the CPU (:func:`pallas_interpret`)."""
     m, n = x.shape[0] * rows, b.shape[1]
     if b.shape[0] != cols:
         raise CompileError(
@@ -179,10 +309,12 @@ def staged_vta_matmul(x: jax.Array, gather: Optional[jax.Array],
     g = gemm_blocks(m, cols, n, block_m=block_m, block_n=block_n,
                     block_k=block_k)
     with span("vta.kernel.dispatch", issued_macs=g.m * g.k * g.n,
-              a_bytes=m * cols):
-        return _staged_vta_gemm(x, gather, b, bias, conv=conv, rows=rows,
-                                cols=cols, relu=relu, shift=shift,
-                                saturate=saturate, out_dtype=out_dtype,
+              a_bytes=m * cols, alu_ops=len(epilogue or ())):
+        return _staged_vta_gemm(x, gather, b, bias, preload, res, keep,
+                                *tables, conv=conv, rows=rows, cols=cols,
+                                relu=relu, shift=shift, saturate=saturate,
+                                out_dtype=out_dtype,
+                                valid=valid or (rows, n), epilogue=epilogue,
                                 block_m=block_m, block_n=block_n,
                                 block_k=block_k,
                                 interpret=pallas_interpret())
@@ -191,16 +323,18 @@ def staged_vta_matmul(x: jax.Array, gather: Optional[jax.Array],
 @functools.partial(
     jax.jit,
     static_argnames=("conv", "rows", "cols", "relu", "shift", "saturate",
-                     "out_dtype", "block_m", "block_n", "block_k",
-                     "interpret"))
-def _staged_vta_gemm(x, gather, b, bias, *, conv, rows, cols, relu, shift,
-                     saturate, out_dtype, block_m, block_n, block_k,
-                     interpret):
+                     "out_dtype", "valid", "epilogue", "block_m", "block_n",
+                     "block_k", "interpret"))
+def _staged_vta_gemm(x, gather, b, bias, preload, res, keep, *tables, conv,
+                     rows, cols, relu, shift, saturate, out_dtype, valid,
+                     epilogue, block_m, block_n, block_k, interpret):
     """:func:`staged_vta_matmul` as one program: stage A, pad it to
-    :func:`gemm_blocks`, run ``vta_gemm``, slice.  One trip into the
-    runtime per call: each op run on its own would be another.  Its module
+    :func:`gemm_blocks`, run ``vta_gemm``, the epilogue and the int8
+    commit, keep the valid rows and columns.  One trip into the runtime
+    per call: each op run on its own would be another.  Its module
     (``jit__staged_vta_gemm``) names ``vta_gemm``, which is how a device
-    trace finds the kernel's time (the staging included)."""
+    trace finds the kernel's time (the staging and the epilogue
+    included)."""
     a = stage_rows(x, gather, conv=conv, rows=rows, cols=cols)
     m, n = a.shape[0], b.shape[1]
     g = gemm_blocks(m, cols, n, block_m=block_m, block_n=block_n,
@@ -212,7 +346,15 @@ def _staged_vta_gemm(x, gather, b, bias, *, conv, rows, cols, relu, shift,
                     saturate=saturate, out_dtype=out_dtype,
                     block_m=g.block_m, block_n=g.block_n, block_k=g.block_k,
                     interpret=interpret)
-    return out[:m, :n]
+    out = out[:m, :n].reshape(x.shape[0], rows, n)
+    if epilogue is not None:
+        out = alu_epilogue(out, preload, res, tables, steps=epilogue)
+    vm, vn = valid
+    out = (out[:, :vm, :vn] if keep is None
+           else jnp.take(out[:, :, :vn], keep, axis=1))
+    if epilogue is not None:
+        out = commit_int8(out)
+    return out.reshape(-1, vn)
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

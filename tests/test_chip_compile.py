@@ -8,7 +8,9 @@ program serving runs (``ops._staged_vta_gemm``: A staged from the
 activations, pads, ``vta_gemm``, slice) and as the bare padded
 ``vta_gemm``: what the chip's compiler would refuse (a misaligned block,
 too much VMEM) fails here at no chip time.  The calls come from
-``kernel_call``;
+``kernel_call``, and a layer that does not fuse into the kernel brings its
+TensorAlu epilogue (preload, joins, pools, the int8 commit) and the
+operands it takes (the skip activations, the index and keep tables);
 ``test_rehearsal_calls_are_what_serving_runs`` pins that serving makes
 exactly those calls.  The topology is described inside a
 fixture, never at import, so every test worker collects the same tests.
@@ -26,9 +28,15 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from repro.core.pallas_backend import KernelCall, kernel_call
+from repro.core import isa
+from repro.core.gemm_compiler import AluImmOp, AluResidualOp
+from repro.core.layer_compiler import LayerSpec, _pool_alu_ops, pool_plan_for
+from repro.core.conv_lowering import ConvGeometry
+from repro.core.pallas_backend import (KernelCall, PallasPlan, device_alu,
+                                       kernel_call)
 from repro.kernels import ops
 from repro.kernels.vta_gemm import vta_gemm
 
@@ -125,6 +133,27 @@ def test_staged_vta_gemm_keeps_the_kernels_names(nets, one_chip):
                      r"tpu_custom_call", text)
 
 
+@pytest.mark.parametrize("net_name, layer_name",
+                         (("lenet5", "l1_conv"), ("resnet8", "head")))
+def test_unfused_layer_call_lowers_with_its_epilogue(net_name, layer_name,
+                                                     nets, one_chip):
+    """A layer whose ALU program does not fuse (lenet5's pooled
+    ``l1_conv``, resnet8's join into the GAP, ``head``) compiles as one
+    program: the kernel, its epilogue after it, the names the trace reads."""
+    net, _ = nets[net_name]
+    layer, = [l for l in net.layers if l.spec.name == layer_name]
+    call = kernel_call(layer, 8)
+    assert call.epilogue and call.out_dtype == jnp.int32 and call.keep
+    args, statics = call.matmul_args(sharding=one_chip)
+    assert len(args) == 7 + len(call.tables)
+    text = ops._staged_vta_gemm.lower(*args, interpret=False, **statics) \
+        .compile().as_text()
+    module, = re.findall(r"^HloModule (\S+),", text, re.M)
+    assert "vta_gemm" in module, module
+    assert re.search(r"%vta_gemm\.\d+ = [^\n]*custom-call\([^\n]*"
+                     r"tpu_custom_call", text)
+
+
 def test_vta_gemm_keeps_its_names_for_the_trace(nets, one_chip):
     """The benchmark finds the kernel in a chip trace by its module
     (``jit_vta_gemm``) and its op (``%vta_gemm.N = ... custom-call``); the
@@ -164,6 +193,17 @@ def _round_up(x, m):
     return -(-x // m) * m
 
 
+def _epilogue(alu_ops, rows, n, bs=16):
+    """The epilogue statics and table lengths of an ALU program over a
+    ``(rows, n)`` padded result, as ``device_alu`` lowers it."""
+    plan = PallasPlan(alpha=rows // bs, lam=0, beta=n // bs, row_height=bs,
+                      block_size=bs, valid_shape=(0, 0),
+                      alu_ops=tuple(alu_ops), fused=False, relu=False,
+                      shift=0, wgt=(0, 0), acc=None)
+    steps, tables = device_alu(plan)
+    return dict(epilogue=steps, tables=tuple(len(t) for t in tables))
+
+
 def _resnet50_calls(layers, shifts, rung, bs=16):
     """The kernel calls a pallas serve of ``rung`` images runs for a
     ResNet-50 of ``layers`` (``models/resnet50.py linear_layers``) with
@@ -171,8 +211,11 @@ def _resnet50_calls(layers, shifts, rung, bs=16):
     input tensor and the device stages A with the conv's kernel, stride
     and padding, the fc its flat input; the stem's rows are the padded
     3×3/s2 max pool's bands (a gather), its pool and the joins (``c``)
-    finish in the epilogue on an int32 GEMM, every other layer runs whole
-    in the kernel (bias, ReLU on ``a``/``b``, its requant shift)."""
+    run as the epilogue on an int32 GEMM — the stem ReLU, the pool's MAX
+    passes and its requant over the rows it keeps; a join its requant, the
+    skip's pre-shifted ADD, ReLU and requant, the last one's global pool
+    keeping one row — and every other layer runs whole in the kernel
+    (bias, ReLU on ``a``/``b``, its requant shift)."""
     calls = set()
     for layer in layers:
         name = layer["name"]
@@ -185,16 +228,42 @@ def _resnet50_calls(layers, shifts, rung, bs=16):
             out = (size + 2 * p - kk) // s + 1
             m, k, n = out * out, layer["in"] * kk * kk, layer["out"]
             x, conv = (rung, layer["in"], size, size), (kk, kk, s, p)
+            geo = ConvGeometry(layer["in"], size, size, kk, kk, s, p)
         gather = 0
         if name == "stem":
             m = gather = ((out - 1) // 2 + 1) * _round_up(3 * out, bs)
-        shape = dict(x=x, conv=conv, gather=gather,
-                     rows=_round_up(m, bs) if m > 1 else 1,
-                     k=_round_up(k, bs), n=_round_up(n, bs))
-        if name == "stem" or (layer["kind"] == "conv"
-                              and name.endswith("c")):
-            calls.add(KernelCall(**shape, bias=False, relu=False, shift=0,
-                                 out_dtype=jnp.int32))
+        rows = _round_up(m, bs) if m > 1 else 1
+        shape = dict(x=x, conv=conv, gather=gather, rows=rows,
+                     k=_round_up(k, bs), n=_round_up(n, bs), valid=(m, n))
+        a, b = np.empty((m, 1), np.int8), np.empty((1, n), np.int8)
+        if name == "stem":
+            pool = pool_plan_for(LayerSpec(name, "conv", None, relu=True,
+                                           pool="max3x3s2"), geo)
+            alu = [AluImmOp.relu()] + _pool_alu_ops(pool, a, b, bs,
+                                                    shifts["stem_q"])
+            calls.add(KernelCall(
+                **shape, bias=False, relu=False, shift=0,
+                out_dtype=jnp.int32, keep=len(pool.keep_rows), preload=True,
+                **_epilogue(alu, rows, shape["n"])))
+        elif layer["kind"] == "conv" and name.endswith("c"):
+            block = name[:-1]
+            branch_pre, skip_pre = shifts[f"{block}_join"]
+            post = shifts[f"{block}_q"]
+            pre_add = shifts[f"{name}_q"] + branch_pre
+            alu = [AluImmOp.shr(pre_add)] if pre_add else []
+            alu += [AluResidualOp(isa.AluOp.ADD, skip_pre), AluImmOp.relu()]
+            keep = 0
+            if block == layers[-2]["name"][:-1]:     # the join into the GAP
+                pool = pool_plan_for(LayerSpec(name, "conv", None,
+                                               pool="gap"), geo)
+                alu += _pool_alu_ops(pool, a, b, bs, post)
+                keep = len(pool.keep_rows)
+            elif post:
+                alu.append(AluImmOp.shr(post))
+            calls.add(KernelCall(
+                **shape, bias=False, relu=False, shift=0,
+                out_dtype=jnp.int32, keep=keep, res=(rung, n, out, out),
+                preload=True, **_epilogue(alu, rows, shape["n"])))
         else:
             calls.add(KernelCall(**shape, bias=True,
                                  relu=name.endswith(("a", "b")),
@@ -218,7 +287,8 @@ def test_resnet50_calls_from_shapes_are_what_the_program_compiles(rung):
 def test_resnet50_staged_vta_gemm_compiles_for_v5e(rung, one_chip):
     cfg = json.loads((Path(__file__).resolve().parents[1] / "bench" /
                       "configs" / "resnet50.json").read_text())
-    calls = _resnet50_calls(cfg["layers"], cfg["requant_shifts"], rung)
+    shifts = dict(cfg["requant_shifts"], **cfg["join_pre_shifts"])
+    calls = _resnet50_calls(cfg["layers"], shifts, rung)
     for call in calls:
         args, statics = call.matmul_args(sharding=one_chip)
         compiled = ops._staged_vta_gemm.lower(*args, interpret=False,

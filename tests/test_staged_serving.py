@@ -8,13 +8,18 @@ interpreted kernel, that A is checked bit for bit against the host's
 meets; whole pallas serves are checked bit for bit against the batched
 interpreter at rung 1, a partial rung and a full rung, and so are small
 networks of the lowering forms the conformance fuzz draws (stride-2
-convs, the GAP tree, a residual join, pools under a tiny SRAM); the host
-epilogue (``apply_alu_epilogue``) is checked against
+convs, the GAP tree, a residual join, pools under a tiny SRAM); the
+device epilogue (``ops.alu_epilogue`` over ``pallas_backend.device_alu``'s
+steps and tables, then ``ops.commit_int8``) is checked against
 ``gemm_compiler.reference_result`` over random ALU programs with an X
-preload and a residual operand; and a pallas serve is checked to build no
-DRAM stack and to put each program's constants on the device once.
+preload and a residual operand and at the edges of its semantics (shifts
+of 32 and more, int32 wraparound, every residual op, indexed ops, pair
+lattices by passes, by whole rows and in pair order); and a pallas serve
+is checked to fetch only committed int8 rows, to build no DRAM stack and
+to put each program's constants on the device once.
 """
 
+import dataclasses
 import threading
 
 import jax
@@ -24,14 +29,15 @@ import pytest
 
 from repro.core import isa, pallas_backend
 from repro.core.conv_lowering import gather_rows, im2row_batch
-from repro.core.gemm_compiler import (AluImmOp, AluResidualOp, _wrap_int32,
-                                      compile_matmul, reference_result)
+from repro.core.gemm_compiler import (AluImmOp, AluIndexedImmOp, AluPairOp,
+                                      AluResidualOp, _wrap_int32,
+                                      compile_matmul, pair_lattice,
+                                      reference_result)
 from repro.core.hwconfig import VTAConfig, vta_default
-from repro.core.layer_compiler import LayerSpec, compile_layer
-from repro.core.layout import truncate_int8
+from repro.core.layer_compiler import LayerSpec, _vec_index, compile_layer
 from repro.core.network_compiler import NetworkProgram, compile_network
-from repro.core.pallas_backend import (apply_alu_epilogue, kernel_call,
-                                       plan_pallas, serving_lowering)
+from repro.core.pallas_backend import (device_alu, kernel_call, plan_pallas,
+                                       serving_lowering)
 from repro.graph import GraphBuilder, compile_graph
 from repro.kernels import ops
 from repro.models.lenet import (lenet5_random_weights, lenet5_specs,
@@ -216,39 +222,171 @@ def _random_alu_program(rng):
     return ops
 
 
+def _padded(mat, shape, dtype):
+    out = np.zeros(shape, dtype)
+    out[:mat.shape[0], :mat.shape[1]] = mat
+    return out
+
+
+def _device_epilogue_is_the_reference(A, B, X, R, alu_ops, p):
+    """``A·B`` (wrapped to int32) through the device epilogue with ``X``
+    as the preload and ``R`` as the skip operand equals
+    ``reference_result``'s accumulator, and its commit the reference's
+    int8."""
+    (mp, np_), kp = p.padded_shape, p.lam * p.block_size
+    acc = _wrap_int32(_padded(A, (mp, kp), np.int64)
+                      @ _padded(B, (kp, np_), np.int64))
+    steps, tables = device_alu(p)
+    got = ops.alu_epilogue(
+        jnp.asarray(acc[None]),
+        None if X is None else jnp.asarray(_padded(X, (mp, np_), np.int32)),
+        None if R is None else jnp.asarray(R[None]),
+        tuple(jnp.asarray(t) for t in tables), steps=steps)
+    want_acc, want_out = reference_result(A, B, X, alu_ops, vta_default(),
+                                          row_height=p.row_height,
+                                          residual=R)
+    np.testing.assert_array_equal(np.asarray(got[0]), want_acc)
+    np.testing.assert_array_equal(np.asarray(ops.commit_int8(got)[0]),
+                                  want_out)
+    return steps
+
+
 @pytest.mark.parametrize("seed", range(6))
 def test_host_epilogue_is_the_compiler_reference_on_random_alu(seed):
-    """``apply_alu_epilogue`` over ``A·B + X`` with a residual operand, in
-    int32, equals ``reference_result``'s accumulator, and its truncation
-    the reference's committed int8."""
+    """The epilogue the kernel's jitted call runs (``ops.alu_epilogue``)
+    over ``A·B + X`` with a residual operand, in int32, equals
+    ``reference_result``'s accumulator, and its commit the reference's
+    committed int8."""
     rng = np.random.default_rng(400 + seed)
     m, k, n = (int(rng.integers(1, 50)) for _ in range(3))
     A = rng.integers(-128, 128, (m, k)).astype(np.int8)
     B = rng.integers(-128, 128, (k, n)).astype(np.int8)
     X = rng.integers(-10**6, 10**6, (m, n)).astype(np.int32)
     R = rng.integers(-10**6, 10**6, (m, n)).astype(np.int32)
-    ops = _random_alu_program(rng)
-    cfg = vta_default()
-    p = plan_pallas(compile_matmul(A, B, X=X, alu_ops=ops, residual=R,
-                                   cfg=cfg))
-    (mp, np_), kp = p.padded_shape, p.lam * p.block_size
+    alu_ops = _random_alu_program(rng)
+    p = plan_pallas(compile_matmul(A, B, X=X, alu_ops=alu_ops, residual=R,
+                                   cfg=vta_default()))
+    _device_epilogue_is_the_reference(A, B, X, R, alu_ops, p)
 
-    def padded(mat, shape, dtype):
-        out = np.zeros(shape, dtype)
-        out[:mat.shape[0], :mat.shape[1]] = mat
-        return out
 
-    acc = padded(A, (mp, kp), np.int64) @ padded(B, (kp, np_), np.int64)
-    acc = _wrap_int32(acc + padded(X, (mp, np_), np.int64))
-    res_vec = pallas_backend._to_vectors(padded(R, (mp, np_), np.int32)[None],
-                                         p)
-    got = pallas_backend._to_matrix(apply_alu_epilogue(
-        pallas_backend._to_vectors(acc[None], p), p, res_vec), p)[0]
-    want_acc, want_out = reference_result(A, B, X, ops, cfg,
-                                          row_height=p.row_height,
-                                          residual=R)
-    np.testing.assert_array_equal(got, want_acc)
-    np.testing.assert_array_equal(truncate_int8(got), want_out)
+# 40×20 @ 20×40: α = 3 block rows of 16, β = 3 block columns
+_M, _K, _N = 40, 20, 40
+_BETA, _RH = 3, 16
+_S = isa.AluOp
+
+
+def _rows(pairs):
+    """Row pairs as the vector pairs of every block column (a pool's)."""
+    return tuple((_vec_index(d, j, _BETA, _RH), _vec_index(s, j, _BETA, _RH))
+                 for d, s in pairs for j in range(_BETA))
+
+
+# name: (ALU program, X scale, residual, the steps' kinds and lanes)
+EDGES = {
+    "shr_by_31_32_40": ([AluImmOp(_S.ADD, 7), AluImmOp(_S.SHR, 32),
+                         AluImmOp(_S.ADD, -3), AluImmOp(_S.SHR, 40)],
+                        2**30, False, None),
+    "shr_by_31": ([AluImmOp(_S.SHR, 31)], 2**30, False, None),
+    "add_wraps_at_2_31": ([AluImmOp(_S.ADD, 2**31 - 1),
+                           AluImmOp(_S.ADD, -2**31),
+                           AluImmOp(_S.ADD, 2**31 + 5)], 2**30, False, None),
+    "min_max_past_int32": ([AluImmOp(_S.MIN, 2**31 + 9),
+                            AluImmOp(_S.MAX, -2**31 - 9),
+                            AluImmOp(_S.MAX, 2**32 + 3)], 2**30, False,
+                           None),
+    "residual_add_pre_shift": ([AluResidualOp(_S.ADD, 3)], 2**31 - 2**20,
+                               True, None),
+    "residual_min_pre_shift": ([AluResidualOp(_S.MIN, 2)], 2**20, True,
+                               None),
+    "residual_max_pre_shift": ([AluResidualOp(_S.MAX, 40)], 2**20, True,
+                               None),
+    "residual_shr_pre_shift": ([AluResidualOp(_S.SHR, 1)], 2**20, True,
+                               None),
+    "indexed_shr": ([AluIndexedImmOp(_S.SHR, 5, (0, 7, 7, 50, 143, 96))],
+                    2**20, False, ("indexed", 16)),
+    "indexed_shr_whole_rows": ([AluIndexedImmOp(
+        _S.SHR, 3, tuple(i for i, _ in _rows([(2, 0), (40, 0)])))],
+        2**20, False, ("indexed", 48)),
+    # three vectors in a row, but across two result rows: not whole rows
+    "indexed_shr_across_rows": ([AluIndexedImmOp(_S.SHR, 4, (
+        _vec_index(0, 1, _BETA, _RH), _vec_index(0, 2, _BETA, _RH),
+        _vec_index(1, 0, _BETA, _RH)))], 2**20, False, ("indexed", 16)),
+    "max_lattice": ([AluPairOp(_S.MAX, ((0, 5), (0, 6), (0, 9), (1, 5),
+                                        (48, 100), (48, 143)))],
+                    2**20, False, ("pairs", 16)),
+    # destinations fill row 0, sources come from rows 1 and 2: vectors
+    "max_lattice_mixed_rows": ([AluPairOp(_S.MAX, tuple(
+        (_vec_index(0, j, _BETA, _RH), _vec_index(2 if j == 1 else 1, j,
+                                                  _BETA, _RH))
+        for j in range(_BETA)))], 2**20, False, ("pairs", 16)),
+    "add_lattice": ([AluPairOp(_S.ADD, ((3, 4), (3, 50), (60, 4),
+                                        (143, 0)))],
+                    2**31 - 2**20, False, ("pairs", 16)),
+    "add_lattice_whole_rows": ([AluPairOp(_S.ADD, _rows(
+        [(0, 1), (0, 2), (0, 3), (20, 21), (39, 21)])),
+        AluIndexedImmOp(_S.SHR, 2, tuple(
+            i for i, _ in _rows([(0, 0), (20, 0), (39, 0)])))],
+        2**20, False, ("pairs", 48)),
+    "sequential_lattice": ([AluPairOp(_S.ADD, ((1, 0), (2, 1), (3, 2),
+                                               (3, 2), (50, 3)))],
+                           2**28, False, ("pairs_in_order", 16)),
+    "sequential_shr_lattice": ([AluPairOp(_S.SHR, ((4, 9), (4, 10)))],
+                               2**20, False, ("pairs_in_order", 16)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(EDGES))
+def test_device_epilogue_at_the_edges_is_the_reference(name):
+    alu_ops, scale, residual, form = EDGES[name]
+    rng = np.random.default_rng(len(name))
+    A = rng.integers(-128, 128, (_M, _K)).astype(np.int8)
+    B = rng.integers(-128, 128, (_K, _N)).astype(np.int8)
+    X = rng.integers(-scale, scale, (_M, _N), dtype=np.int64) \
+        .astype(np.int32)
+    R = (rng.integers(-2**31, 2**31, (_M, _N), dtype=np.int64)
+         .astype(np.int32) if residual else None)
+    p = dataclasses.replace(plan_pallas(compile_matmul(A, B)),
+                            alu_ops=tuple(alu_ops))
+    assert (p.alpha, p.beta, p.row_height) == (3, _BETA, _RH)
+    for spec in alu_ops:
+        if isinstance(spec, AluPairOp) and form[0] == "pairs_in_order":
+            assert pair_lattice(spec.op, spec.pairs).sequential
+    steps = _device_epilogue_is_the_reference(A, B, X, R, alu_ops, p)
+    if form is not None:
+        kind, lanes = form
+        assert (steps[0].kind, steps[0].lanes) == (kind, lanes)
+
+
+@pytest.mark.parametrize("net_name", ("lenet5", "resnet8"))
+def test_pallas_serve_fetches_only_committed_rows(net_name, nets,
+                                                  monkeypatch):
+    """Pools and joins run inside the kernel's jitted call: every layer
+    comes back as int8 at its valid shape (a pooled layer's kept rows
+    only), the skip activations go up with the layer's own, and the
+    serve is the batched interpreter's bit for bit."""
+    net, images = nets[net_name]
+    seen = []
+    real = pallas_backend.serve_layer
+
+    def spy(layer, x, res):
+        out, report = real(layer, x, res)
+        seen.append((layer, res is not None, out))
+        return out, report
+
+    monkeypatch.setattr(pallas_backend, "serve_layer", spy)
+    got, _ = net.serve(images[:3], backend="pallas")
+    want, _ = net.serve(images[:3], backend="batched")
+    np.testing.assert_array_equal(got, want)
+    kinds = set()
+    for layer, joined, out in seen:
+        m, n = layer.program.output_meta.valid_shape
+        rows = m if layer.keep_rows is None else len(layer.keep_rows)
+        assert out.dtype == np.int8 and out.shape == (3, rows, n)
+        assert joined == layer.spec.residual_add
+        call = kernel_call(layer, 3)
+        kinds |= {step.kind for step in call.epilogue or ()}
+    assert {"imm", "pairs", "indexed"} <= kinds
+    assert ("residual" in kinds) == (net_name == "resnet8")
 
 
 def _lenet():

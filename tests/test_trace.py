@@ -6,9 +6,10 @@ and one engine batch of 3 requests (padded to a rung of 4) record every
 span of the serving path, nested on one thread as the layers call each
 other, with the counts the benchmark's readers sum: ``issued_macs`` is
 the padded ``vta_gemm`` call's ``gemm_blocks`` product, ``a_bytes`` the
-A the device stages, ``useful_macs`` the layer's valid M·K·N per row,
-``bytes`` what each kernel call sends (the activations) and fetches,
-``positions`` what each pool reads, and ``rows``/``real``/
+A the device stages, ``alu_ops`` the TensorAlu ops the call runs after
+the kernel, ``useful_macs`` the layer's valid M·K·N per row, ``bytes``
+what each kernel call sends (the activations) and fetches (the committed
+int8 rows), and ``rows``/``real``/
 ``first_rid``/``worker`` of ``engine.execute`` those of the batch's
 ``RequestRecord``s.
 """
@@ -22,7 +23,7 @@ from jax.profiler import ProfileData
 
 from repro import trace
 from repro.core.network_compiler import compile_network
-from repro.core.pallas_backend import kernel_call, plan_pallas
+from repro.core.pallas_backend import kernel_call
 from repro.kernels.ops import gemm_blocks
 from repro.models.lenet import (lenet5_random_weights, lenet5_specs,
                                 synthetic_digit)
@@ -33,10 +34,9 @@ from repro.serving.vta import (BatchPolicy, VTAServingEngine, request_images,
 NESTING = {
     "engine.execute": {"vta.serve"},
     "vta.serve": {"vta.layer"},
-    "vta.layer": {"vta.stage", "vta.kernel", "vta.epilogue", "vta.readout"},
+    "vta.layer": {"vta.stage", "vta.kernel", "vta.readout"},
     "vta.kernel": {"vta.kernel.put", "vta.kernel.dispatch",
                    "vta.kernel.fetch"},
-    "vta.epilogue": {"vta.pool"},
 }
 SPANS = {"engine.batch_form"} | set(NESTING) | set().union(*NESTING.values())
 
@@ -156,36 +156,69 @@ def test_counts_are_the_kernel_calls_and_the_layers_work(traced, lenet):
             dispatch, = [n for n in layer_span.walk()
                          if n.name == "vta.kernel.dispatch"]
             assert dispatch.stats == {"issued_macs": g.m * g.k * g.n,
-                                      "a_bytes": call.m * call.k}
+                                      "a_bytes": call.m * call.k,
+                                      "alu_ops": len(call.epilogue or ())}
+            # the pooled layers' epilogues run in the call, the rest fuse
+            assert (call.epilogue is None) == (layer.spec.pool is None)
+            if call.epilogue is not None:
+                assert len(call.epilogue) == len(layer.program.alu_ops)
 
 
 def test_pool_positions_and_kernel_bytes(traced, lenet):
-    """``vta.pool`` counts the input positions each pooled layer's
-    epilogue reads (rows × the layer's pooled result rows); the kernel
-    call's put counts the activations it sends (the weights and bias are
-    on the device already), its fetch the result."""
+    """The pools run inside the kernel's jitted call, so no ``vta.pool``
+    (nor ``vta.epilogue``) span is left; the kernel call's put counts the
+    activations it sends (the weights, bias and epilogue tables are on
+    the device already), its fetch the committed int8 result: each
+    image's valid columns of the rows a pooled layer keeps."""
     threads, _ = traced
     serves = [n for roots in threads for r in roots for n in r.walk()
               if n.name == "vta.serve"]
     for serve in serves:
         rows = serve.stats["rows"]
         for layer_span, layer in zip(serve.children, lenet.layers):
-            p = plan_pallas(layer.program)
             call = kernel_call(layer, rows)
             put, = [n for n in layer_span.walk()
                     if n.name == "vta.kernel.put"]
             fetch, = [n for n in layer_span.walk()
                       if n.name == "vta.kernel.fetch"]
             assert put.stats == {"bytes": math.prod(call.x)}
-            assert fetch.stats == {"bytes": call.m * call.n * (
-                1 if call.out_dtype == jnp.int8 else 4)}
-            pools = [n for n in layer_span.walk() if n.name == "vta.pool"]
-            if layer.spec.pool is None:
-                assert not pools and p.pool_rows == 0
-            else:
-                pool, = pools
-                assert p.pool_rows == layer.input_matrix.shape[0] > 0
-                assert pool.stats == {"positions": rows * p.pool_rows}
+            m, n = call.valid
+            kept = call.keep or m
+            assert fetch.stats == {"bytes": rows * kept * n}
+            if layer.spec.pool is not None:
+                assert kept == len(layer.keep_rows) < m
+            assert not [node for node in layer_span.walk()
+                        if node.name in ("vta.pool", "vta.epilogue")]
+
+
+def test_skip_activations_are_counted_in_the_put(tmp_path):
+    """At a join the skip's int8 activations go up with the layer's own,
+    in the same put, and its bytes count both."""
+    import numpy as np
+    from repro.graph import GraphBuilder, compile_graph
+    rng = np.random.default_rng(3)
+    w = lambda *shape: rng.integers(-4, 5, shape).astype(np.int8)
+    bld = GraphBuilder("join")
+    x = bld.input("x", shape=(1, 8, 6, 6))
+    v = bld.requant("a_q", bld.conv("a", x, w(8, 8, 3, 3), padding=1))
+    v = bld.requant("j_q", bld.relu("j_r", bld.add("j", v, x)))
+    bld.output(v)
+    images = [rng.integers(-64, 64, (1, 8, 6, 6)).astype(np.int8)
+              for _ in range(2)]
+    net = compile_graph(bld.build(), images[0], calib=images)
+    net.serve(images, backend="pallas")
+    with jax.profiler.trace(str(tmp_path)):
+        net.serve(images, backend="pallas")
+    path, = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    puts = [n for roots in _threads(path) for r in roots for n in r.walk()
+            if n.name == "vta.kernel.put"]
+    joins = [layer for layer in net.layers if layer.spec.residual_add]
+    assert joins and len(puts) == len(net.layers)
+    for put, layer in zip(puts, net.layers):
+        call = kernel_call(layer, 2)
+        skip = math.prod(call.res) if call.res else 0
+        assert put.stats == {"bytes": math.prod(call.x) + skip}
+        assert bool(skip) == layer.spec.residual_add
 
 
 def test_engine_spans_match_the_request_records(traced):
