@@ -80,7 +80,7 @@ def main():
     for l in res_layers:
         print(f"  join @{l.spec.name}: on-VTA ADD, skip pre-shift "
               f"{l.spec.residual_pre_shift}, post-add requant "
-              f"{l.residual_shift}")
+              f"{l.post_shift}")
 
     print("verifying the network (fast backend)...")
     out_fast, _ = net.verify(backend="fast")

@@ -104,6 +104,43 @@ def ker2col(weights: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(weights.reshape(f, -1).T)
 
 
+def depthwise_ker2col(weights: np.ndarray) -> np.ndarray:
+    """Depthwise weights ``(C, 1, kh, kw)`` → the ``(C·kh·kw) × C`` weight
+    matrix of the same convolution over ``im2row``'s channel-major
+    patches: column ``c`` holds channel ``c``'s taps in rows
+    ``c·kh·kw … (c+1)·kh·kw − 1`` and zeros elsewhere, so the channels of
+    a ``bs``-wide output block read only their own ``kh·kw`` blocks of
+    ``bs`` rows (the band of DESIGN.md §Graph)."""
+    if weights.ndim != 4 or weights.shape[1] != 1:
+        raise ValueError(f"expected (C, 1, kh, kw) weights, got "
+                         f"{weights.shape}")
+    c, _, kh, kw = weights.shape
+    taps = kh * kw
+    out = np.zeros((c * taps, c), dtype=weights.dtype)
+    rows = np.arange(c)[:, None] * taps + np.arange(taps)
+    out[rows, np.arange(c)[:, None]] = weights.reshape(c, taps)
+    return out
+
+
+def depthwise_conv(tensor: np.ndarray, weights: np.ndarray, stride: int = 1,
+                   pad: int = 0) -> np.ndarray:
+    """Depthwise convolution ``(B, C, H, W)`` with ``(C, 1, kh, kw)``
+    weights (channel ``c`` convolved with its own kernel), zero padding;
+    int64 ``(B, C, H', W')``: one shifted multiply-add per tap."""
+    b, c, h, w = tensor.shape
+    _, _, kh, kw = weights.shape
+    geo = ConvGeometry(c, h, w, kh, kw, stride, pad)
+    x = _pad_spatial(tensor, pad).astype(np.int64)
+    wt = weights.astype(np.int64)[None, :, 0]
+    out = np.zeros((b, c, geo.out_h, geo.out_w), dtype=np.int64)
+    for i in range(kh):
+        for j in range(kw):
+            out += x[:, :, i:i + stride * geo.out_h:stride,
+                     j:j + stride * geo.out_w:stride] \
+                * wt[:, :, i, j][..., None, None]
+    return out
+
+
 def mat2tensor(mat: np.ndarray, out_h: int, out_w: int) -> np.ndarray:
     """Output matrix ``(H'·W', F)`` → output tensor ``(1, F, H', W')``."""
     if mat.ndim != 2 or mat.shape[0] != out_h * out_w:

@@ -46,8 +46,9 @@ from . import cycle_model, isa, pipeline_schedule
 from .dram import DramAllocator
 from .errors import CompileError
 from .hwconfig import VTAConfig, vta_default
-from .layout import (exact_matmul, matrix_padding, matrix_splitting,
-                     binarize_blocks, should_pad_height, pad_to_multiple)
+from .layout import (band_matmul, exact_matmul, matrix_padding,
+                     matrix_splitting, binarize_blocks, should_pad_height,
+                     pad_to_multiple)
 from .program import OutputMeta, VTAProgram
 
 
@@ -152,6 +153,11 @@ class ChunkPlan:
     # Planned against halved buffer budgets so loads/stores can ping-pong
     # between buffer halves (schedule="pipelined", DESIGN.md §Pipeline).
     double_buffer: bool = False
+    # A grouped GEMM's band (``compile_matmul(band=...)``): output block
+    # column j reads only K blocks [band·j, band·(j+1)), so a chunk of
+    # ``b_c`` block columns loads band·b_c K blocks and WGT matrices; 0 for
+    # a dense GEMM.
+    band: int = 0
 
     @property
     def n_chunks(self) -> int:
@@ -207,7 +213,8 @@ def plan_chunks(cfg: VTAConfig, alpha: int, lam: int, beta: int,
                 acc_copies: int = 1,
                 double_buffer: bool = False,
                 max_lam_c: Optional[int] = None,
-                max_alpha_c: Optional[int] = None) -> ChunkPlan:
+                max_alpha_c: Optional[int] = None,
+                band: int = 0) -> ChunkPlan:
     """Greedy deterministic tiling honouring every buffer capacity.
 
     ``row_groups``/``col_groups`` are inclusive block-row/block-col
@@ -223,7 +230,10 @@ def plan_chunks(cfg: VTAConfig, alpha: int, lam: int, beta: int,
     at ``acc_buff/2``, shrinking the OUT budget accordingly.
     ``max_lam_c``/``max_alpha_c`` cap the tile sizes below the buffer
     limits — the makespan-driven planner uses them to generate split
-    candidates (more load groups / more chunks = more overlap)."""
+    candidates (more load groups / more chunks = more overlap).
+
+    ``band`` plans a grouped GEMM (:class:`ChunkPlan`): λ is no longer
+    tiled on its own, each chunk's λ being ``band`` times its β tile."""
     div = 2 if double_buffer else 1
     uop_reserve = div
     inp_budget = cfg.inp_buff_vectors // div
@@ -236,10 +246,18 @@ def plan_chunks(cfg: VTAConfig, alpha: int, lam: int, beta: int,
     def tiles(lam_c: int, rows: int):
         """``(beta_c, alpha_c)`` for ``lam_c``, β tiles narrow enough
         that ``rows`` block rows fit the ACC/OUT/UOP budgets."""
-        beta_c = max(1, min(beta, wgt_budget // lam_c,
-                            acc_budget // (row_height * rows),
-                            out_budget // (row_height * rows),
-                            uop_budget // rows))
+        if band:
+            beta_c = max(1, min(beta, wgt_budget // band,
+                                inp_budget // (row_height * band * rows),
+                                acc_budget // (row_height * rows),
+                                out_budget // (row_height * rows),
+                                uop_budget // rows))
+            lam_c = band * beta_c
+        else:
+            beta_c = max(1, min(beta, wgt_budget // lam_c,
+                                acc_budget // (row_height * rows),
+                                out_budget // (row_height * rows),
+                                uop_budget // rows))
         alpha_c = max(1, min(alpha,
                              inp_budget // (row_height * lam_c),
                              acc_budget // (row_height * beta_c),
@@ -257,17 +275,20 @@ def plan_chunks(cfg: VTAConfig, alpha: int, lam: int, beta: int,
     # that fits no tiling is refused by _segment below.
     need = _widest_group(row_groups)
     if alpha_c < min(need, alpha):
-        for lc in range(lam_c, 0, -1):
+        for lc in ((lam_c,) if band else range(lam_c, 0, -1)):
             b_c, a_c = tiles(lc, need)
             if a_c >= min(need, alpha):
                 lam_c, beta_c, alpha_c = lc, b_c, a_c
                 break
+    if band:
+        lam_c = band * beta_c
     if max_alpha_c is not None:
         alpha_c = max(1, min(alpha_c, max_alpha_c))
     plan = ChunkPlan(alpha, lam, beta, alpha_c, lam_c, beta_c, row_height,
                      alpha_segs=_segment(alpha, alpha_c, row_groups),
                      beta_segs=_segment(beta, beta_c, col_groups),
-                     acc_copies=acc_copies, double_buffer=double_buffer)
+                     acc_copies=acc_copies, double_buffer=double_buffer,
+                     band=band)
     _validate_plan(cfg, plan)
     return plan
 
@@ -290,7 +311,8 @@ def _validate_plan(cfg: VTAConfig, p: ChunkPlan) -> None:
     div = 2 if p.double_buffer else 1
     odd_out_base = cfg.acc_buff_vectors // 2 if p.double_buffer else 0
     assert p.alpha_c * p.row_height * p.lam_c <= cfg.inp_buff_vectors // div
-    assert p.lam_c * p.beta_c <= cfg.wgt_buff_matrices // div
+    assert p.lam_c * (1 if p.band else p.beta_c) \
+        <= cfg.wgt_buff_matrices // div
     assert (p.alpha_c * p.row_height * p.beta_c * p.acc_copies
             <= cfg.acc_buff_vectors // div)
     assert (odd_out_base + p.alpha_c * p.row_height * p.beta_c
@@ -343,16 +365,19 @@ def _alu_chunk_groups(alu_ops: Sequence, beta: int, row_height: int
 def reference_result(A: np.ndarray, B: np.ndarray, X: Optional[np.ndarray],
                      alu_ops: Sequence, cfg: VTAConfig,
                      row_height: Optional[int] = None,
-                     residual: Optional[np.ndarray] = None
-                     ) -> Tuple[np.ndarray, np.ndarray]:
+                     residual: Optional[np.ndarray] = None,
+                     band: int = 0) -> Tuple[np.ndarray, np.ndarray]:
     """Bit-accurate reference: returns ``(acc_int32, out_int8)`` on the
-    *padded* geometry (block-major semantics are layout-only)."""
+    *padded* geometry (block-major semantics are layout-only).  ``band``:
+    B is zero outside each output block's band of K blocks, and only the
+    band is multiplied (:func:`~repro.core.layout.band_matmul`)."""
     bs = cfg.block_size
     if row_height is None:
         row_height = bs if should_pad_height(A) else 1
     Ap = matrix_padding(A, bs, pad_height=row_height > 1)
     Bp = matrix_padding(B, bs, pad_height=True)
-    acc = exact_matmul(Ap, Bp)    # int32 wraparound applied below
+    acc = (band_matmul(Ap, Bp, band * bs, bs) if band
+           else exact_matmul(Ap, Bp))    # int32 wraparound applied below
     if X is not None:
         Xp = np.zeros(acc.shape, dtype=np.int64)
         Xp[:X.shape[0], :X.shape[1]] = X.astype(np.int64)
@@ -526,8 +551,8 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
                    name: str = "matmul",
                    dram_offset: int = 0,
                    allocator: Optional[DramAllocator] = None,
-                   schedule: str = pipeline_schedule.SERIALIZED
-                   ) -> VTAProgram:
+                   schedule: str = pipeline_schedule.SERIALIZED,
+                   band: int = 0) -> VTAProgram:
     """Compile ``C = A·B (+X|+bias)`` + element-wise post-ops to a VTA program.
 
     ``A`` int8 (M,K); ``B`` int8 (K,N); ``X`` int32 (M,N) accumulator preload
@@ -554,6 +579,15 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
     makespan (DESIGN.md §Pipeline).  When the buffers are too small to
     double-buffer the compile falls back to the serialized scheme
     (``prog.schedule`` records what was actually emitted).
+
+    ``band`` — a grouped GEMM (a depthwise convolution, DESIGN.md
+    §Graph): output block column ``j`` reads only K blocks ``[band·j,
+    band·(j+1))``, and B must be zero outside them.  Each chunk then loads
+    its own columns' band of A and only the band·β weight blocks that are
+    not zero (WGT holds those alone, in K-block order), and one GEMM
+    instruction per chunk runs ``band`` K blocks per output block, so the
+    issued work is the band's and not the dense matrix's.  Band programs
+    are emitted serialized.
     """
     cfg = cfg or vta_default()
     bs = cfg.block_size
@@ -596,6 +630,22 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
     alpha, lam = a_split.block_rows, a_split.block_cols
     beta = b_split.block_cols
     assert b_split.block_rows == lam, "K-padding mismatch"
+    if band:
+        if lam != band * beta:
+            raise CompileError(
+                f"a band of {band} K blocks per output block needs "
+                f"{band * beta} K blocks, A has {lam}", layer=name,
+                constraint="gemm-band-shape")
+        own = np.repeat(np.arange(beta), band)[:, None] == np.arange(beta)
+        if Bp.reshape(lam, bs, beta, bs).any(axis=(1, 3))[~own].any():
+            raise CompileError(
+                "B has weights outside the band of its output blocks",
+                layer=name, constraint="gemm-band-weights")
+        # WGT keeps the band's blocks alone: K block k = band·j + t of
+        # column j sits at WGT index k
+        b_split = dataclasses.replace(
+            b_split, blocks=[b_split.block(k, k // band) for k in range(lam)],
+            block_rows=lam, block_cols=1)
 
     inp_bin = binarize_blocks(a_split, cfg.inp_dtype)
     wgt_bin = binarize_blocks(b_split, cfg.wgt_dtype, transpose=True)
@@ -639,8 +689,8 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
             f"{pipeline_schedule.SCHEDULES}", layer=name,
             constraint="schedule-unknown")
     if (schedule == pipeline_schedule.PIPELINED
-            and not pipeline_schedule.pipelinable(cfg, row_height,
-                                                  acc_copies)):
+            and (band or not pipeline_schedule.pipelinable(cfg, row_height,
+                                                           acc_copies))):
         # Buffers too small (or UOP fields too narrow) to ping-pong
         # halves: fall back to the conservative scheme rather than fail.
         schedule = pipeline_schedule.SERIALIZED
@@ -650,7 +700,7 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
         return plan_chunks(cfg, alpha, lam, beta, row_height,
                            row_groups=row_groups, col_groups=col_groups,
                            acc_copies=acc_copies,
-                           double_buffer=double_buffer, **caps)
+                           double_buffer=double_buffer, band=band, **caps)
 
     # ---------------- UOPs + emission (per candidate plan) ----------------
     capacity = cfg.uop_buff_entries
@@ -660,17 +710,27 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
         ``sched``.  Returns ``(uop_dram, emit)`` where ``emit(log)`` is
         re-callable — candidate plans are timed with stubbed DRAM bases
         (``log = lambda r: 0``) before any region exists."""
-        lam_segs = list(_ranges(lam, plan.lam_c))
+        dense_segs = list(_ranges(lam, plan.lam_c))
         chunk_list = [(i0, a_c, j0, b_c)
                       for i0, a_c in plan.alpha_segs
                       for j0, b_c in plan.beta_segs]
-        gpc = len(lam_segs)                    # load groups per chunk
+        gpc = 1 if band else len(dense_segs)   # load groups per chunk
+
+        def lam_segs_of(j0: int, b_c: int) -> List[Tuple[int, int]]:
+            """A chunk's K-block load groups: all of λ in ``lam_c`` runs,
+            or a band program's one run of its columns' bands."""
+            return [(band * j0, band * b_c)] if band else dense_segs
+
+        # a band program's block column j reads its own band of the INP
+        # run and of WGT; a dense one every K block against matrix j
+        k_step, w_step = (band * row_height, band) if band else (0, 1)
 
         def _gemm_uops(a_c: int, b_c: int, l_c: int, inp_off: int,
                        wgt_off: int, acc_off: int) -> List[isa.Uop]:
             return [isa.Uop(acc_idx=acc_off + (i * b_c + j) * row_height,
-                            inp_idx=inp_off + i * l_c * row_height,
-                            wgt_idx=wgt_off + j)
+                            inp_idx=inp_off + i * l_c * row_height
+                            + j * k_step,
+                            wgt_idx=wgt_off + j * w_step)
                     for i in range(a_c) for j in range(b_c)]
 
         def _alu_chunk_uops(spec, i0: int, a_c: int, j0: int, b_c: int,
@@ -726,9 +786,8 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
         gemm_keys: List[Tuple[int, int, int, int, int]] = []
         for ci, (i0, a_c, j0, b_c) in enumerate(chunk_list):
             q = sched.chunk_phase(ci)
-            for ki in range(gpc):
-                key = (a_c, b_c, lam_segs[ki][1],
-                       sched.load_phase(ci * gpc + ki), q)
+            for ki, (_, l_c) in enumerate(lam_segs_of(j0, b_c)):
+                key = (a_c, b_c, l_c, sched.load_phase(ci * gpc + ki), q)
                 if key not in gemm_keys:
                     gemm_keys.append(key)
 
@@ -765,9 +824,9 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
             for ci, (i0, a_c, j0, b_c) in enumerate(chunk_list):
                 q = sched.chunk_phase(ci)
                 gemm_use.append([
-                    (None, gemm_start[(a_c, b_c, lam_segs[ki][1],
+                    (None, gemm_start[(a_c, b_c, l_c,
                                        sched.load_phase(ci * gpc + ki), q)])
-                    for ki in range(gpc)])
+                    for ki, (_, l_c) in enumerate(lam_segs_of(j0, b_c))])
                 uses: List[Optional[List[Tuple[Optional[int], int,
                                                int]]]] = []
                 for lst in chunk_alu_uops[ci]:
@@ -814,8 +873,8 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
                 assert a_c * b_c <= cap_w, "planner exceeded the uop buffer"
                 q = sched.chunk_phase(ci)
                 row: List[Tuple[Optional[int], int]] = []
-                for ki in range(gpc):
-                    key = (a_c, b_c, lam_segs[ki][1],
+                for ki, (_, l_c) in enumerate(lam_segs_of(j0, b_c)):
+                    key = (a_c, b_c, l_c,
                            sched.load_phase(ci * gpc + ki), q)
                     row.append(_place(key, _uops_for(key)))
                 gemm_use.append(row)
@@ -886,7 +945,7 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
                         pre.dep.pop_next = 1
                         store_wait = False
                     insns.append(pre)
-                for ki, (k0, l_c) in enumerate(lam_segs):
+                for ki, (k0, l_c) in enumerate(lam_segs_of(j0, b_c)):
                     li = isa.MemInsn(
                         isa.Opcode.LOAD, isa.MemId.INP,
                         sram_base=sched.inp_base(group),
@@ -895,16 +954,23 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
                         x_stride=lam * row_height)
                     if sched.load_pops_release(group):
                         li.dep.pop_next = 1  # wait for buffer-half release
-                    lw = isa.MemInsn(
-                        isa.Opcode.LOAD, isa.MemId.WGT,
-                        sram_base=sched.wgt_base(group),
-                        dram_base=log("wgt") + k0 * beta + j0,
-                        y_size=l_c, x_size=b_c, x_stride=beta)
+                    if band:     # the band's l_c matrices, contiguous
+                        lw = isa.MemInsn(
+                            isa.Opcode.LOAD, isa.MemId.WGT,
+                            sram_base=sched.wgt_base(group),
+                            dram_base=log("wgt") + k0, y_size=1,
+                            x_size=l_c, x_stride=l_c)
+                    else:
+                        lw = isa.MemInsn(
+                            isa.Opcode.LOAD, isa.MemId.WGT,
+                            sram_base=sched.wgt_base(group),
+                            dram_base=log("wgt") + k0 * beta + j0,
+                            y_size=l_c, x_size=b_c, x_stride=beta)
                     lw.dep.push_next = 1     # load group complete
                     insns.extend([li, lw])
                     group += 1
 
-                    if not has_x and k0 == 0:
+                    if not has_x and ki == 0:
                         # no X preload: zero the chunk accumulator
                         rg = isa.GemInsn(
                             reset=1, uop_bgn=slot, uop_end=slot + 1,
@@ -918,10 +984,11 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
                     _ensure_wave(wave)
                     g = isa.GemInsn(
                         uop_bgn=start, uop_end=start + a_c * b_c,
-                        iter_out=l_c, iter_in=row_height,
+                        iter_out=band or l_c, iter_in=row_height,
                         acc_factor_out=0, acc_factor_in=1,
                         inp_factor_out=row_height, inp_factor_in=1,
-                        wgt_factor_out=b_c, wgt_factor_in=0)
+                        wgt_factor_out=1 if band else b_c,
+                        wgt_factor_in=0)
                     g.dep.pop_prev = 1       # consume load group
                     g.dep.push_prev = 1      # release INP/WGT half
                     insns.append(g)
@@ -1048,7 +1115,7 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
         offset=dram_offset, page_bytes=cfg.page_bytes)
     pfx = f"{name}:" if allocator is not None else ""
     n_inp_vec = alpha * lam * row_height
-    n_wgt_mat = lam * beta
+    n_wgt_mat = lam if band else lam * beta
     n_res_vec = alpha * beta * row_height
     regions = {
         "inp": alloc.alloc(pfx + "inp", "inp", cfg.inp_elem_bytes, n_inp_vec),
@@ -1081,7 +1148,7 @@ def compile_matmul(A: np.ndarray, B: np.ndarray, *,
     # ---------------- expected output (oracle) ----------------
     acc_ref, out_ref = reference_result(A, B, X, alu_ops, cfg,
                                         row_height=row_height,
-                                        residual=residual)
+                                        residual=residual, band=band)
     prog.expected_out = out_ref
     prog.output_meta = OutputMeta(block_rows=alpha, block_cols=beta,
                                   row_height=row_height,

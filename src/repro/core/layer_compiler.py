@@ -1,12 +1,14 @@
 """One CNN layer → one VTA program (paper §4.2, Fig. 11).
 
 A *layer* (paper §4.1) = one dense linear operation (convolution — valid or
-zero-padded "same", stride 1 or 2 (DESIGN.md §Strided-lowering) — or fully
-connected) + subsequent non-linear operations (ReLU on TensorAlu; average
-pooling as an ALU ADD/SHR program; max pooling — 2×2/s2, or the padded
-3×3/s2 of ResNet's stem over banded GEMM rows — as an ALU MAX pair
-program; global average pooling as an ALU ADD-pair tree reduction + one
-SHR, also after a residual join; static power-of-2 requantisation).  Layers
+zero-padded "same", stride 1 or 2 (DESIGN.md §Strided-lowering), dense or
+depthwise as a grouped GEMM (DESIGN.md §Graph) — or fully connected) +
+subsequent non-linear operations (ReLU, or ReLU6 as MAX then MIN, on
+TensorAlu; average pooling as an ALU ADD/SHR program; max pooling —
+2×2/s2, or the padded 3×3/s2 of ResNet's stem over banded GEMM rows — as
+an ALU MAX pair program; global average pooling as an ALU ADD-pair tree
+reduction + one SHR, also after a residual join or a ReLU6; static
+power-of-2 requantisation).  Layers
 whose matrices exceed the SRAM compile to multi-chunk programs — the GEMM
 compiler re-indexes the pool/requant uops against each chunk's local ACC
 window (DESIGN.md §3), so nothing here is limited to single-chunk results.
@@ -33,16 +35,16 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from .conv_lowering import (ConvGeometry, PoolPlan, avgpool2x2_plan,
-                            flatten_tensor, gather_rows, global_avgpool_plan,
-                            im2row, ker2col, maxpool2x2_plan,
-                            maxpool3x3s2_plan, tensor2mat)
+                            depthwise_ker2col, flatten_tensor, gather_rows,
+                            global_avgpool_plan, im2row, ker2col,
+                            maxpool2x2_plan, maxpool3x3s2_plan, tensor2mat)
 from .dram import DramAllocator
 from .errors import CompileError
 from .gemm_compiler import (AluImmOp, AluIndexedImmOp, AluPairOp,
                             AluResidualOp, compile_matmul)
 from .hwconfig import VTAConfig, vta_default
-from .layout import (exact_matmul, pad_to_multiple, should_pad_height,
-                     truncate_int8)
+from .layout import (band_matmul, exact_matmul, pad_to_multiple,
+                     should_pad_height, truncate_int8)
 from .program import VTAProgram
 from . import isa
 
@@ -51,7 +53,8 @@ from . import isa
 class LayerSpec:
     """Hardware-agnostic description of one layer.
 
-    conv: ``weights`` is ``(F, C, kh, kw)`` int8; input is a ``(1, C, H, W)``
+    conv: ``weights`` is ``(F, C, kh, kw)`` int8 — ``(C, 1, kh, kw)``
+    with ``groups = C`` for a depthwise conv; input is a ``(1, C, H, W)``
     int8 tensor.  fc: ``weights`` is ``(D, F)`` int8; input is a ``(1, D)``
     int8 matrix (or a tensor, flattened NCHW).
     """
@@ -70,13 +73,20 @@ class LayerSpec:
     # connection — after the GEMM result is requantised (``requant_shift``)
     # the skip operand is ACC-loaded and merged on the VTA with an ALU
     # vector-vector ADD (``residual_pre_shift`` equalises its scale), then
-    # ``relu`` applies *post-add* and ``residual_shift`` requantises the
+    # ``relu`` applies *post-add* and ``post_shift`` requantises the
     # sum.  ``compile_layer`` must then receive the skip activation via
     # its ``residual=`` argument.  Of the pools only "gap" fuses with a
     # residual: it then sums the requantised join (ResNet's last block).
     residual_add: bool = False
     residual_pre_shift: int = 0
-    residual_shift: Optional[int] = None  # None = choose statically
+    # The requant after a join or a ReLU6 (the pool's division excluded);
+    # None = choose statically.
+    post_shift: Optional[int] = None
+    groups: int = 1                # 1, or C: depthwise (DESIGN.md §Graph)
+    # ReLU6 (DESIGN.md §Graph): after ``requant_shift`` the value is
+    # clipped to [0, clip] (MAX 0, MIN clip) in the accumulator; a "gap"
+    # pool then sums the clipped rows and ``post_shift`` requantises it.
+    clip: Optional[int] = None
 
     def out_features(self) -> int:
         return (self.weights.shape[0] if self.kind == "conv"
@@ -98,9 +108,9 @@ class CompiledLayer:
     out_w: Optional[int] = None
     ref_output_matrix: Optional[np.ndarray] = None  # int8 (rows×F) post-reshape
     # Residual layers: the reference skip operand (int32 (M, N), add-time
-    # scale) and the post-add requant shift actually compiled in.
+    # scale); joins and ReLU6 layers: the requant after them compiled in.
     residual_matrix: Optional[np.ndarray] = None
-    residual_shift: Optional[int] = None
+    post_shift: Optional[int] = None
     # Rows of one image's GEMM at its valid shape (the conv's output
     # positions) where ``input_matrix`` holds more: the padded 3×3/s2 max
     # pool's bands repeat halo rows and pad each band with zero rows.
@@ -116,9 +126,11 @@ class CompiledLayer:
     @property
     def macs(self) -> int:
         """Multiply-accumulates of one image's GEMM at its valid shape
-        (M·K·N, M counted before any pooling)."""
+        (M·K·N, M counted before any pooling); a depthwise conv's are its
+        taps', M·kh·kw·C (K = kh·kw·C), not its band matrix's."""
         m, k = self.input_matrix.shape
-        return (self.gemm_rows or m) * k * self.weight_matrix.shape[1]
+        n = 1 if self.spec.groups > 1 else self.weight_matrix.shape[1]
+        return (self.gemm_rows or m) * k * n
 
     @property
     def n_chunks(self) -> int:
@@ -273,6 +285,13 @@ def layer_matrices(spec: LayerSpec, inp: np.ndarray, *, row_align: int = 16
             raise CompileError(f"padding must be >= 0, got {spec.padding}",
                                layer=spec.name, constraint="conv-padding")
         f, c, kh, kw = spec.weights.shape
+        if spec.groups != 1 and (spec.groups != f or c != 1):
+            raise CompileError(
+                f"groups={spec.groups} with {spec.weights.shape} weights: a "
+                f"conv is dense (groups=1) or depthwise (groups = channels, "
+                f"weights (C, 1, kh, kw))", layer=spec.name,
+                constraint="conv-groups")
+        c *= spec.groups
         if inp.shape[1] != c:
             raise CompileError(
                 f"channel mismatch: input has {inp.shape[1]} channels, "
@@ -291,7 +310,8 @@ def layer_matrices(spec: LayerSpec, inp: np.ndarray, *, row_align: int = 16
         gather = row_gather(spec, geo, row_align)
         if gather is not None:
             A = gather_rows(A, gather)
-        B = ker2col(spec.weights)
+        B = (depthwise_ker2col(spec.weights) if spec.groups > 1
+             else ker2col(spec.weights))
         return A, B, geo
     if spec.kind == "fc":
         A = flatten_tensor(inp) if inp.ndim == 4 else np.asarray(inp)
@@ -324,12 +344,12 @@ def row_gather(spec: LayerSpec, geo: ConvGeometry,
     return pool_plan_for(spec, geo, row_align=row_align).row_gather
 
 
-def reference_layer_acc(A: np.ndarray, B: np.ndarray,
-                        bias: Optional[np.ndarray], relu: bool,
-                        pool_plan: Optional[PoolPlan]) -> np.ndarray:
-    """int64 accumulator right before the final SHR — used for the static
+def reference_layer_acc(acc: np.ndarray, bias: Optional[np.ndarray],
+                        relu: bool, pool_plan: Optional[PoolPlan]
+                        ) -> np.ndarray:
+    """int64 accumulator right before the final SHR, from the layer's
+    exact GEMM ``acc`` (:func:`layer_gemm`) — used for the static
     requant-shift choice and overflow check."""
-    acc = exact_matmul(A, B)
     if bias is not None:
         acc = acc + bias.astype(np.int64)[None, :]
     if relu:
@@ -374,61 +394,98 @@ def residual_operand_matrix(spec: LayerSpec, residual: np.ndarray,
     return R.astype(np.int32)
 
 
-def _compile_residual_layer(spec: LayerSpec, A: np.ndarray, B: np.ndarray,
-                            geo: Optional[ConvGeometry],
-                            residual: Optional[np.ndarray], cfg: VTAConfig,
-                            allocator: Optional[DramAllocator],
-                            schedule: str = "serialized") -> CompiledLayer:
-    """The residual-closing layer (DESIGN.md §Graph): GEMM → SHR(requant)
-    → on-VTA vector-vector ADD with the ACC-loaded skip operand →
-    optional ReLU → [global-pool ADD tree] → SHR(post-add requant, with
-    the pool's division folded in)."""
+def layer_band(spec: LayerSpec, cfg: VTAConfig) -> int:
+    """K blocks per output block of the layer's grouped GEMM
+    (``compile_matmul(band=...)``): kh·kw for a depthwise conv, whose
+    ``bs`` output channels read only their own kh·kw blocks of ``bs``
+    patch rows (``depthwise_ker2col``); 0 for a dense layer.  A depthwise
+    conv needs whole blocks of channels."""
+    if spec.groups == 1:
+        return 0
+    c = spec.weights.shape[0]
+    if c % cfg.block_size:
+        raise CompileError(
+            f"a depthwise conv lowers in blocks of {cfg.block_size} "
+            f"channels; {c} channels is not a whole number of them",
+            layer=spec.name, constraint="depthwise-channel-blocks")
+    return spec.weights.shape[2] * spec.weights.shape[3]
+
+
+def layer_gemm(A: np.ndarray, B: np.ndarray, band: int,
+               cfg: VTAConfig) -> np.ndarray:
+    """The layer's exact int64 ``A @ B``; a grouped GEMM multiplies its
+    bands alone."""
+    if band:
+        return band_matmul(A, B, band * cfg.block_size, cfg.block_size)
+    return exact_matmul(A, B)
+
+
+def _compile_post_shift_layer(spec: LayerSpec, A: np.ndarray, B: np.ndarray,
+                              geo: Optional[ConvGeometry],
+                              residual: Optional[np.ndarray], cfg: VTAConfig,
+                              allocator: Optional[DramAllocator],
+                              schedule: str, band: int) -> CompiledLayer:
+    """A layer whose requant is not its last ALU op (DESIGN.md §Graph):
+    GEMM → SHR(requant) → then either the on-VTA vector-vector ADD with
+    the ACC-loaded skip operand and an optional ReLU (a residual join), or
+    MAX 0 and MIN cap (a ReLU6) → [global-pool ADD tree] → SHR(post-shift,
+    with the pool's division folded in)."""
+    join = "residual" if spec.residual_add else "relu6"
     if spec.pool not in (None, "gap"):
         raise CompileError(
-            f"only the global avg pool fuses with a residual add, not "
+            f"only the global avg pool fuses after a {join}, not "
             f"{spec.pool!r} (downsample with a strided conv instead)",
-            layer=spec.name, constraint="residual-no-pool")
-    if residual is None:
-        raise CompileError(
-            "residual_add layer compiled without a residual operand",
-            layer=spec.name, constraint="residual-operand-missing")
-    if spec.residual_pre_shift < 0:
-        raise CompileError(
-            f"residual pre-shift must be >= 0, got "
-            f"{spec.residual_pre_shift}", layer=spec.name,
-            constraint="residual-pre-shift")
+            layer=spec.name, constraint=f"{join}-no-pool")
     M, N = A.shape[0], B.shape[1]
-    R = residual_operand_matrix(spec, residual, (M, N))
+    R = None
+    if spec.residual_add:
+        if residual is None:
+            raise CompileError(
+                "residual_add layer compiled without a residual operand",
+                layer=spec.name, constraint="residual-operand-missing")
+        if spec.residual_pre_shift < 0:
+            raise CompileError(
+                f"residual pre-shift must be >= 0, got "
+                f"{spec.residual_pre_shift}", layer=spec.name,
+                constraint="residual-pre-shift")
+        R = residual_operand_matrix(spec, residual, (M, N))
 
     pool_plan = pool_plan_for(spec, geo, row_align=cfg.block_size)
     pool_div = pool_divisor(pool_plan)
 
-    acc = exact_matmul(A, B)
+    acc = layer_gemm(A, B, band, cfg)
     if spec.bias is not None:
         acc = acc + spec.bias.astype(np.int64)[None, :]
     s_conv = (spec.requant_shift if spec.requant_shift is not None
               else choose_requant_shift(acc))
-    t = (acc >> s_conv) + (R.astype(np.int64) >> spec.residual_pre_shift)
-    if spec.relu:
+    t = acc >> s_conv
+    if R is not None:
+        t = t + (R.astype(np.int64) >> spec.residual_pre_shift)
+    if spec.relu or spec.clip is not None:
         t = np.maximum(t, 0)
+    if spec.clip is not None:
+        t = np.minimum(t, spec.clip)
     if pool_plan is not None:
         t = t.sum(axis=0, keepdims=True)    # the GAP: every row into row 0
-    s_add = (spec.residual_shift if spec.residual_shift is not None
+    s_add = (spec.post_shift if spec.post_shift is not None
              else choose_requant_shift(t, already_shifted=pool_div))
     final = t >> (pool_div + s_add)
     if np.abs(final).max(initial=0) > 127:
         raise CompileError(
-            f"post-add requant shift {s_add} leaves values outside int8 — "
-            f"increase residual_shift", layer=spec.name,
+            f"post-{join} requant shift {s_add} leaves values outside int8 "
+            f"— increase post_shift", layer=spec.name,
             constraint="requant-int8-range")
 
     alu_ops: List[object] = []
     if s_conv > 0:
         alu_ops.append(AluImmOp.shr(s_conv))
-    alu_ops.append(AluResidualOp(isa.AluOp.ADD,
-                                 pre_shift=spec.residual_pre_shift))
-    if spec.relu:
+    if R is not None:
+        alu_ops.append(AluResidualOp(isa.AluOp.ADD,
+                                     pre_shift=spec.residual_pre_shift))
+    if spec.relu or spec.clip is not None:
         alu_ops.append(AluImmOp.relu())
+    if spec.clip is not None:
+        alu_ops.append(AluImmOp(isa.AluOp.MIN, spec.clip))
     if pool_plan is not None:
         alu_ops.extend(_pool_alu_ops(pool_plan, A, B, cfg.block_size,
                                      pool_div + s_add))
@@ -437,7 +494,7 @@ def _compile_residual_layer(spec: LayerSpec, A: np.ndarray, B: np.ndarray,
 
     prog = compile_matmul(A, B, bias=spec.bias, alu_ops=alu_ops, residual=R,
                           cfg=cfg, name=spec.name, allocator=allocator,
-                          schedule=schedule)
+                          schedule=schedule, band=band)
     out_h = geo.out_h if geo is not None else None
     out_w = geo.out_w if geo is not None else None
     if pool_plan is not None:
@@ -448,7 +505,7 @@ def _compile_residual_layer(spec: LayerSpec, A: np.ndarray, B: np.ndarray,
                                     if pool_plan is not None else None),
                          out_h=out_h, out_w=out_w,
                          ref_output_matrix=truncate_int8(final),
-                         residual_matrix=R, residual_shift=s_add,
+                         residual_matrix=R, post_shift=s_add,
                          geometry=geo)
 
 
@@ -495,18 +552,20 @@ def compile_layer(spec: LayerSpec, inp: np.ndarray, *,
     becomes the program's second ACC operand, merged on the VTA."""
     cfg = cfg or vta_default()
     A, B, geo = layer_matrices(spec, inp, row_align=cfg.block_size)
-    if spec.residual_add:
-        return _compile_residual_layer(spec, A, B, geo, residual, cfg,
-                                       allocator, schedule=schedule)
-    if residual is not None:
+    band = layer_band(spec, cfg)
+    if residual is not None and not spec.residual_add:
         raise CompileError(
             "residual operand passed to a layer without residual_add",
             layer=spec.name, constraint="residual-unexpected-operand")
+    if spec.residual_add or spec.clip is not None:
+        return _compile_post_shift_layer(spec, A, B, geo, residual, cfg,
+                                         allocator, schedule, band)
     # ---- pooling plan (indices in matrix-row space) ----
     pool_plan = pool_plan_for(spec, geo, row_align=cfg.block_size)
 
     # ---- static requant shift (+ overflow check) ----
-    acc_pre_shift = reference_layer_acc(A, B, spec.bias, spec.relu, pool_plan)
+    acc_pre_shift = reference_layer_acc(layer_gemm(A, B, band, cfg),
+                                        spec.bias, spec.relu, pool_plan)
     pool_div = pool_divisor(pool_plan)
     shift = (spec.requant_shift if spec.requant_shift is not None
              else choose_requant_shift(acc_pre_shift, already_shifted=pool_div))
@@ -529,7 +588,7 @@ def compile_layer(spec: LayerSpec, inp: np.ndarray, *,
 
     prog = compile_matmul(A, B, bias=spec.bias, alu_ops=alu_ops, cfg=cfg,
                           name=spec.name, allocator=allocator,
-                          schedule=schedule)
+                          schedule=schedule, band=band)
 
     # ---- reference post-reshape output matrix (int8) ----
     ref = truncate_int8(final)

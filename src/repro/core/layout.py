@@ -41,6 +41,19 @@ def exact_matmul(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     return a.astype(np.int64) @ b.astype(np.int64)
 
 
+def band_matmul(a: np.ndarray, b: np.ndarray, band: int, width: int
+                ) -> np.ndarray:
+    """``exact_matmul(a, b)`` where column group ``j`` of ``b`` (``width``
+    columns) is zero outside rows ``[band·j, band·(j+1))``: each group
+    multiplies its own rows alone (a grouped GEMM's band, as a depthwise
+    convolution lowers, DESIGN.md §Graph)."""
+    groups = b.shape[1] // width
+    return np.concatenate(
+        [exact_matmul(a[..., band * j:band * (j + 1)],
+                      b[band * j:band * (j + 1), width * j:width * (j + 1)])
+         for j in range(groups)], axis=-1)
+
+
 def _magnitude(x: np.ndarray) -> int:
     """max |x| as a Python int (``np.abs`` of int8 -128 stays -128)."""
     return max(-int(x.min(initial=0)), int(x.max(initial=0)))

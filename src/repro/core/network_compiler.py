@@ -561,6 +561,7 @@ def calibrate_network(specs: Sequence[LayerSpec],
     from .layer_compiler import (choose_requant_shift, layer_matrices,
                                  pool_divisor, pool_plan_for,
                                  reference_layer_acc)
+    from .layout import exact_matmul
     from .layout import requant_int8
 
     shifts: List[int] = []
@@ -574,7 +575,8 @@ def calibrate_network(specs: Sequence[LayerSpec],
             A, B, geo = layer_matrices(spec, cur)
             plan = pool_plan_for(spec, geo)
             pool_div = pool_divisor(plan)
-            accs.append(reference_layer_acc(A, B, spec.bias, spec.relu, plan))
+            accs.append(reference_layer_acc(exact_matmul(A, B), spec.bias,
+                                            spec.relu, plan))
             geos.append((geo, plan))
         if spec.requant_shift is not None:
             shift = spec.requant_shift

@@ -17,10 +17,13 @@ request, and no int32 result comes back.
 When the layer's ALU program is exactly the fused-kernel form
 (``[relu?][shr?]`` with a row-broadcast bias) the whole layer runs inside
 ``vta_gemm``; richer programs (pool pair lattices, indexed SHR, residual
-ADD) run the GEMM on the kernel and the TensorAlu program after it in the
-same call (``ops.alu_epilogue``), op for op ``gemm_compiler``'s reference
-semantics (wraparound included).  The commit is the §2.1 truncation, so a
-pallas serve is bit for bit the simulators' (pinned by
+ADD, ReLU6's MIN) run the GEMM on the kernel and the TensorAlu program
+after it in the same call (``ops.alu_epilogue``), op for op
+``gemm_compiler``'s reference semantics (wraparound included).  A
+depthwise layer's grouped GEMM runs as ``kernels.vta_dwconv`` on the VPU,
+from the layer's taps, with its bias, its epilogue after it.  The commit
+is the §2.1 truncation, so a pallas serve is bit for bit the simulators'
+(pinned by
 ``tests/test_pallas_backend.py`` and ``tests/test_staged_serving.py``).
 """
 
@@ -91,6 +94,7 @@ class PallasPlan:
     # once; ``acc`` is None when the program has no X preload
     wgt: Tuple[int, int]
     acc: Optional[Tuple[int, int]]
+    band: int = 0                   # a grouped GEMM's (ChunkPlan.band)
     # what serving keeps for this program: :func:`serving_lowering` and
     # its device copy, :func:`serving_constants`
     held: dict = dataclasses.field(default_factory=dict, compare=False,
@@ -156,9 +160,10 @@ def _lower(prog) -> PallasPlan:
         fused=fused is not None,
         relu=fused[0] if fused else False,
         shift=fused[1] if fused else 0,
-        wgt=_span("wgt", lam * beta * bs * bs),
+        wgt=_span("wgt", prog.regions["wgt"].nbytes),
         acc=(_span("acc", alpha * beta * rh * bs * 4)
-             if "acc" in prog.regions else None))
+             if "acc" in prog.regions else None),
+        band=cp.band)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -186,6 +191,7 @@ class KernelCall:
     preload: bool = False         # an X preload (rows, n) int32 operand
     epilogue: Optional[Tuple] = None        # ``ops.AluStep``s, or fused
     tables: Tuple[int, ...] = ()  # the epilogue's index tables' lengths
+    depthwise: bool = False       # ``vta_dwconv`` on (k, n) taps
 
     @property
     def m(self) -> int:
@@ -197,7 +203,7 @@ class KernelCall:
         return dict(conv=self.conv, rows=self.rows, cols=self.k,
                     relu=self.relu, shift=self.shift, saturate=False,
                     out_dtype=self.out_dtype, valid=self.valid,
-                    epilogue=self.epilogue)
+                    epilogue=self.epilogue, depthwise=self.depthwise)
 
     def matmul_args(self, sharding=None):
         """``(operand shapes, static kwargs)`` of the one jitted call
@@ -218,8 +224,11 @@ class KernelCall:
     def vta_gemm_args(self, sharding=None):
         """``(operand shapes, static kwargs)`` of the padded ``vta_gemm``
         call inside :meth:`matmul_args`'s program — what ``vta_gemm.lower``
-        takes."""
+        takes.  A depthwise call has no ``vta_gemm``."""
         from repro.kernels.ops import gemm_blocks
+        if self.depthwise:
+            raise CompileError("a depthwise layer runs vta_dwconv, not "
+                               "vta_gemm", constraint="kernel-depthwise")
         g = gemm_blocks(self.m, self.k, self.n)
         shapes = [((g.m, g.k), jnp.int8), ((g.k, g.n), jnp.int8),
                   ((g.n,), jnp.int32) if self.bias else None]
@@ -248,8 +257,10 @@ def kernel_call(layer, batch: int) -> KernelCall:
     ``layer`` over ``batch`` images: A staged on the device from the
     activations, the whole program inside the kernel when its epilogue
     fuses, else the int32 GEMM and the layer's TensorAlu epilogue after it
-    (:func:`device_alu`), and only the rows the host reads back.  The
-    chip-compile rehearsal compiles exactly these calls."""
+    (:func:`device_alu`), and only the rows the host reads back; a
+    depthwise layer ``vta_dwconv`` over its ``(kh·kw, C)`` taps, its
+    epilogue always after it.  The chip-compile rehearsal compiles exactly
+    these calls."""
     p = plan_pallas(layer.program)
     low = serving_lowering(layer)
     geo = layer.geometry
@@ -259,7 +270,7 @@ def kernel_call(layer, batch: int) -> KernelCall:
            (batch, geo.in_channels, geo.in_h, geo.in_w)),
         conv=None if geo is None else (geo.kh, geo.kw, geo.stride, geo.pad),
         gather=0 if low.gather is None else len(low.gather),
-        rows=p.padded_shape[0], k=p.lam * p.block_size,
+        rows=p.padded_shape[0], k=low.w.shape[0],
         n=p.padded_shape[1], valid=(m, n),
         keep=0 if low.keep is None else len(low.keep))
     if low.fused:
@@ -269,11 +280,12 @@ def kernel_call(layer, batch: int) -> KernelCall:
     if layer.residual_matrix is not None:   # the skip's map, or its matrix
         res = ((batch,) + layer.residual_matrix.shape if geo is None
                else (batch, n, geo.out_h, geo.out_w))
-    return KernelCall(**shape, bias=False, relu=False, shift=0,
-                      out_dtype=jnp.int32, res=res,
+    return KernelCall(**shape, bias=low.bias is not None, relu=False,
+                      shift=0, out_dtype=jnp.int32, res=res,
                       preload=low.preload is not None,
                       epilogue=low.epilogue,
-                      tables=tuple(len(t) for t in low.tables))
+                      tables=tuple(len(t) for t in low.tables),
+                      depthwise=bool(p.band))
 
 
 # ---------------------------------------------------------------------------
@@ -367,7 +379,8 @@ def device_alu(p: PallasPlan) -> Tuple[Tuple, Tuple[np.ndarray, ...]]:
 class ServingLowering:
     """What every ``serve(backend="pallas")`` of one compiled layer uses
     besides its activations, made once from the program's own WGT and ACC
-    segments and ALU spec: ``w`` ``(λ·bs, β·bs)`` int8, the fused
+    segments and ALU spec: ``w`` ``(λ·bs, β·bs)`` int8 (a depthwise
+    layer's ``(kh·kw, C)`` taps, from its weight matrix), the fused
     row-broadcast ``bias`` ``(β·bs,)`` int32 or None and the band
     ``gather``; where the layer does not run whole in the kernel, the int32
     X ``preload`` ``(α·rh, β·bs)`` or None, the ``epilogue``
@@ -406,22 +419,25 @@ def serving_lowering(layer) -> ServingLowering:
     and zero in the pad rows, and the activations staged on the device
     give A zero pad rows, so such a preload fuses into the kernel (its
     pad rows read 0·B + 0 as the oracle's do, and are sliced off).  A
-    preload of any other form is the epilogue's."""
+    preload of any other form is the epilogue's.  ``vta_dwconv`` adds
+    such a bias itself and never fuses the epilogue."""
     p = plan_pallas(layer.program)
 
     def make():
         row = layer.program.dram_image()[None]
         x = _decode_acc32(row, p)[0] if p.acc else None
-        bias, fused = None, p.fused
-        if x is not None and fused:
+        bias, fused = None, p.fused and not p.band
+        if x is not None and (fused or p.band):
             m = p.valid_shape[0]
-            fused = bool((x[:m] == x[:1]).all() and (x[m:] == 0).all())
-            if fused:
+            if (x[:m] == x[:1]).all() and (x[m:] == 0).all():
                 bias, x = x[0], None
+            else:
+                fused = False
         epilogue, tables = (None, ()) if fused else device_alu(p)
         gather = _layer_gather(layer)
         return ServingLowering(
-            w=_decode_wgt(row, p)[0], bias=bias,
+            w=_depthwise_taps(layer) if p.band else _decode_wgt(row, p)[0],
+            bias=bias,
             gather=None if gather is None else gather.astype(np.int32),
             preload=x, epilogue=epilogue, tables=tables,
             keep=(None if layer.keep_rows is None
@@ -456,6 +472,17 @@ def _decode_wgt(stack: np.ndarray, p: PallasPlan) -> np.ndarray:
     blocks = raw.reshape(b, p.lam, p.beta, bs, bs)   # each block is Bᵀ
     return blocks.transpose(0, 1, 4, 2, 3).reshape(
         b, p.lam * bs, p.beta * bs)
+
+
+def _depthwise_taps(layer) -> np.ndarray:
+    """A depthwise layer's ``(kh·kw, C)`` int8 taps, read off its band
+    weight matrix (``conv_lowering.depthwise_ker2col``): tap ``t`` of
+    channel ``c`` is row ``c·kh·kw + t`` of column ``c``."""
+    w = layer.weight_matrix
+    c = w.shape[1]
+    taps = w.shape[0] // c
+    rows = np.arange(c)[None, :] * taps + np.arange(taps)[:, None]
+    return np.ascontiguousarray(w[rows, np.arange(c)[None, :]])
 
 
 def _decode_acc32(stack: np.ndarray, p: PallasPlan) -> np.ndarray:

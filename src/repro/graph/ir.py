@@ -10,8 +10,14 @@ certifies the whole structure is a DAG before any pass runs):
     input(shape)                 — a graph input (int8 activation)
     conv(x; W, b, stride, pad)   — dense linear (weights (F, C, kh, kw));
                                    stride 2 downsamples (§Strided-lowering)
+    conv(x; W, b, groups=C)      — depthwise: channel c convolved with its
+                                   own kernel (weights (C, 1, kh, kw));
+                                   lowered as a grouped GEMM
     fc(x; W, b)                  — dense linear (weights (D, F))
     relu(x)                      — MAX(x, 0)
+    relu6(x; cap)                — MIN(MAX(x, 0), cap) on a requantised
+                                   value: ReLU6 with 6 at the value's scale
+                                   (cap = 6·2^e, None = planned)
     pool(x; "max2x2"|"avg2x2")   — 2×2/stride-2 window; avg produces the
                                    window *sum* (÷4 lives in the requant)
     pool(x; "max3x3s2")          — 3×3/stride-2 max, padding 1 (ResNet's
@@ -48,7 +54,7 @@ from repro.core.errors import CompileError
 
 # kind -> number of value inputs
 NODE_ARITY = {
-    "input": 0, "conv": 1, "fc": 1, "relu": 1, "pool": 1,
+    "input": 0, "conv": 1, "fc": 1, "relu": 1, "relu6": 1, "pool": 1,
     "global_avg_pool": 1, "requant": 1, "add": 2, "flatten": 1,
 }
 POOL_MODES = ("max2x2", "avg2x2", "max3x3s2")
@@ -71,6 +77,7 @@ class Node:
     bias: Optional[np.ndarray] = None
     stride: int = 1
     padding: int = 0
+    groups: int = 1                 # 1, or the channel count (depthwise)
     # Fixed-point scale of the stored int8 weights: they represent real
     # coefficients ``W · 2^-weight_exp`` (standard weight quantisation).
     # Bookkeeping only — it never changes the integer arithmetic, it
@@ -81,6 +88,8 @@ class Node:
     mode: Optional[str] = None
     # requant
     shift: Optional[int] = None
+    # relu6: the int8 cap, 6 at the value's scale (filled by plan_requant)
+    cap: Optional[int] = None
     # add: per-operand scale-equalising SHR (filled by plan_requant)
     pre_shifts: Optional[Tuple[int, int]] = None
     # input
@@ -187,6 +196,13 @@ def _verify_attrs(node: Node) -> None:
         if node.padding < 0:
             raise CompileError(f"padding must be >= 0, got {node.padding}",
                                layer=node.name, constraint="conv-padding")
+        f, c_per_group = node.weights.shape[:2]
+        if node.groups != 1 and (node.groups != f or c_per_group != 1):
+            raise CompileError(
+                f"groups={node.groups} with (F, C/groups) = {(f, c_per_group)}"
+                f" weights: a conv is dense (groups=1) or depthwise (groups ="
+                f" channels, weights (C, 1, kh, kw))", layer=node.name,
+                constraint="conv-groups")
     elif node.kind == "fc":
         if node.weights is None or node.weights.ndim != 2:
             raise CompileError("fc needs (D, F) weights", layer=node.name,
@@ -196,6 +212,11 @@ def _verify_attrs(node: Node) -> None:
             raise CompileError(
                 f"pool mode must be one of {POOL_MODES}, got {node.mode!r}",
                 layer=node.name, constraint="pool-kind")
+    elif node.kind == "relu6":
+        if node.cap is not None and not 1 <= node.cap <= 127:
+            raise CompileError(f"relu6 cap must lie in [1, 127] (int8), got "
+                               f"{node.cap}", layer=node.name,
+                               constraint="relu6-cap-range")
     elif node.kind == "requant":
         if node.shift is not None and node.shift < 0:
             raise CompileError(f"shift must be >= 0, got {node.shift}",
@@ -239,10 +260,10 @@ class GraphBuilder:
 
     def conv(self, name: str, x: str, weights: np.ndarray,
              bias: Optional[np.ndarray] = None, *, stride: int = 1,
-             padding: int = 0, weight_exp: int = 0) -> str:
+             padding: int = 0, weight_exp: int = 0, groups: int = 1) -> str:
         return self._add(Node(name, "conv", (x,), weights=weights, bias=bias,
                               stride=stride, padding=padding,
-                              weight_exp=weight_exp))
+                              weight_exp=weight_exp, groups=groups))
 
     def fc(self, name: str, x: str, weights: np.ndarray,
            bias: Optional[np.ndarray] = None, *,
@@ -252,6 +273,9 @@ class GraphBuilder:
 
     def relu(self, name: str, x: str) -> str:
         return self._add(Node(name, "relu", (x,)))
+
+    def relu6(self, name: str, x: str, cap: Optional[int] = None) -> str:
+        return self._add(Node(name, "relu6", (x,), cap=cap))
 
     def pool(self, name: str, x: str, mode: str) -> str:
         return self._add(Node(name, "pool", (x,), mode=mode))

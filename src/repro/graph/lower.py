@@ -37,7 +37,7 @@ def step_to_spec(step: Step) -> LayerSpec:
         pool=step.pool, requant_shift=step.requant_shift,
         residual_add=step.residual_source is not None,
         residual_pre_shift=step.residual_pre_shift,
-        residual_shift=step.residual_shift)
+        post_shift=step.post_shift, groups=step.groups, clip=step.clip)
 
 
 def compile_graph(graph: Graph, input_tensor: np.ndarray, *,

@@ -6,7 +6,8 @@ directly (`tests/test_graph_passes.py`):
 * :func:`infer_shapes`   — forward shape inference.  Invariant: every
   value has a resolved shape; add operands agree; conv kernels fit.
 * :func:`plan_requant`   — static requant-shift planning over a
-  calibration set (§4.2 discipline), *including branch joins*: a
+  calibration set (§4.2 discipline), ReLU6 caps included, and
+  *branch joins*: a
   power-of-2 scale exponent is tracked per value, and at every ``add``
   the operand with the larger exponent receives an on-device pre-shift
   equal to the difference.  Invariant: both operands of every join land
@@ -28,8 +29,8 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
-from repro.core.conv_lowering import (ConvGeometry, im2row, ker2col,
-                                      mat2tensor, pooled_extent)
+from repro.core.conv_lowering import (ConvGeometry, depthwise_conv, im2row,
+                                      ker2col, mat2tensor, pooled_extent)
 from repro.core.errors import CompileError
 from repro.core.layer_compiler import (check_gap_geometry,
                                        check_maxpool3x3_relu,
@@ -43,6 +44,9 @@ from .ir import Graph, Node
 # ``layer_shift >= 0`` (DESIGN.md §2), so the requant node after an
 # avg-pool must shift by at least the pool's ÷4.
 AVG_POOL_DIV = 2
+# ReLU6's cap is 6·2^e at its value's scale e; e = 4 (96) is the largest
+# whose cap is an int8
+RELU6_MAX_EXP = 4
 
 
 # ---------------------------------------------------------------------------
@@ -73,6 +77,7 @@ def _node_shape(node: Node, ins: List[Tuple[int, ...]]) -> Tuple[int, ...]:
             raise CompileError(f"conv input must be (1, C, H, W), got {s}",
                                layer=node.name, constraint="conv-input-rank")
         f, c, kh, kw = node.weights.shape
+        c *= node.groups
         if s[1] != c:
             raise CompileError(
                 f"channel mismatch: input has {s[1]}, weights expect {c}",
@@ -96,7 +101,7 @@ def _node_shape(node: Node, ins: List[Tuple[int, ...]]) -> Tuple[int, ...]:
             raise CompileError(f"fc dimension mismatch: {s} @ {(d, f)}",
                                layer=node.name, constraint="fc-shape")
         return (s[0], f)
-    if node.kind in ("relu", "requant"):
+    if node.kind in ("relu", "relu6", "requant"):
         return ins[0]
     if node.kind == "pool":
         s = ins[0]
@@ -184,13 +189,16 @@ def _eval_node(node: Node, ins: List[np.ndarray], refs: Tuple[str, ...],
         _check_int8(node, refs[0], ins[0], "conv input")
         x = ins[0].astype(np.int8)
         f, c, kh, kw = node.weights.shape
+        bias = (0 if node.bias is None
+                else node.bias.astype(np.int64)[None, :, None, None])
+        if node.groups > 1:
+            return depthwise_conv(x, node.weights, node.stride,
+                                  node.padding) + bias
         acc = exact_matmul(im2row(x, kh, kw, node.stride, node.padding),
                            ker2col(node.weights))
-        if node.bias is not None:
-            acc = acc + node.bias.astype(np.int64)[None, :]
         _, _, h, w = ins[0].shape
         geo = ConvGeometry(c, h, w, kh, kw, node.stride, node.padding)
-        return mat2tensor(acc, geo.out_h, geo.out_w)
+        return mat2tensor(acc, geo.out_h, geo.out_w) + bias
     if node.kind == "fc":
         _check_int8(node, refs[0], ins[0], "fc input")
         acc = exact_matmul(ins[0], node.weights)
@@ -199,6 +207,11 @@ def _eval_node(node: Node, ins: List[np.ndarray], refs: Tuple[str, ...],
         return acc
     if node.kind == "relu":
         return np.maximum(ins[0], 0)
+    if node.kind == "relu6":
+        if node.cap is None:
+            raise CompileError("relu6 cap unplanned — run plan_requant",
+                               layer=node.name, constraint="requant-planned")
+        return np.minimum(np.maximum(ins[0], 0), node.cap)
     if node.kind == "pool":
         t = ins[0]
         if node.mode == "max3x3s2":
@@ -261,6 +274,8 @@ class RequantPlan:
     shifts: Dict[str, int]                      # requant node → shift
     pre_shifts: Dict[str, Tuple[int, int]]      # add node → (pa, pb)
     exps: Dict[str, int]                        # value → scale exponent
+    caps: Dict[str, int] = dataclasses.field(default_factory=dict)
+    #                                             relu6 node → cap
 
 
 def plan_requant(graph: Graph, calib: Sequence[np.ndarray], *,
@@ -275,7 +290,8 @@ def plan_requant(graph: Graph, calib: Sequence[np.ndarray], *,
     (+ margin; ≥ 2 after an avg-pool — the device folds the ÷4 into the
     same SHR).  At each add the larger-exponent operand gets a pre-shift
     equal to the exponent difference, so both residual operands reach the
-    TensorAlu ADD in the same fixed-point scale.
+    TensorAlu ADD in the same fixed-point scale.  A ReLU6's cap is 6 at
+    its value's scale (:func:`_plan_cap`).
 
     ``on_linear(node, input_exp)`` — optional hook invoked on every
     conv/fc node right before its first evaluation, with the planner's
@@ -297,10 +313,19 @@ def plan_requant(graph: Graph, calib: Sequence[np.ndarray], *,
     exps: Dict[str, int] = {}
     shifts: Dict[str, int] = {}
     pre_shifts: Dict[str, Tuple[int, int]] = {}
+    caps: Dict[str, int] = {}
 
     for name in graph.topo_order():
         node = graph.node(name)
         refs = node.inputs
+        if node.kind == "relu6":
+            if node.cap is None:
+                node.cap = _plan_cap(graph, node, vals[refs[0]], exps)
+            caps[name] = node.cap
+            exps[name] = exps[refs[0]]
+            vals[name] = [np.minimum(np.maximum(v, 0), node.cap)
+                          for v in vals[refs[0]]]
+            continue
         if node.kind == "requant":
             if node.shift is None:
                 m = max(int(np.abs(v).max(initial=0))
@@ -350,7 +375,36 @@ def plan_requant(graph: Graph, calib: Sequence[np.ndarray], *,
                 exps[name] = exps[refs[0]] + _gap_div(shapes[refs[0]])
             else:
                 exps[name] = exps[refs[0]]
-    return RequantPlan(shifts=shifts, pre_shifts=pre_shifts, exps=exps)
+    return RequantPlan(shifts=shifts, pre_shifts=pre_shifts, exps=exps,
+                       caps=caps)
+
+
+def _plan_cap(graph: Graph, node: Node, values: List[np.ndarray],
+              exps: Dict[str, int]) -> int:
+    """An unpinned ReLU6's cap, 6·2^e.  The scale e is the largest (at
+    most :data:`RELU6_MAX_EXP`, so that the cap is an int8) whose cap
+    lies below the largest value the calibration set brings, so that the
+    cap clips; with no such e, e = 0.  ReLU6 then fixes the real scale of
+    its layer, as it does when a BatchNorm-folded conv is quantised for
+    it: the conv or fc behind the requant takes the ``weight_exp`` that
+    puts the requant's value at scale e (bookkeeping only; the integers
+    do not change)."""
+    q = graph.node(node.inputs[0])
+    linear = graph.node(q.inputs[0]) if q.kind == "requant" else None
+    if linear is None or linear.kind not in ("conv", "fc"):
+        raise CompileError(
+            "relu6 must follow the requant of a conv or fc (its cap is "
+            "planned with that layer's scale)", layer=node.name,
+            constraint="relu6-after-requant")
+    top = max(int(v.max(initial=0)) for v in values)
+    e = 0
+    while e < RELU6_MAX_EXP and 6 << (e + 1) < top:
+        e += 1
+    delta = e - exps[q.name]
+    linear.weight_exp += delta
+    exps[linear.name] += delta
+    exps[q.name] += delta
+    return 6 << e
 
 
 def _gap_div(in_shape: Tuple[int, ...]) -> int:
@@ -398,7 +452,9 @@ class Step:
     requant_shift: int               # LayerSpec shift (pool ÷4 excluded)
     residual_source: Optional[str] = None
     residual_pre_shift: int = 0
-    residual_shift: Optional[int] = None
+    post_shift: Optional[int] = None   # after a join or a ReLU6
+    groups: int = 1
+    clip: Optional[int] = None         # ReLU6's cap
 
 
 def linearize(graph: Graph) -> List[Step]:
@@ -408,6 +464,8 @@ def linearize(graph: Graph) -> List[Step]:
 
         conv → [relu] → [pool|global_avg_pool] → requant       (linear)
         fc   → [relu] → requant                                (linear)
+        conv|fc → requant → relu6 → [global_avg_pool → requant]
+                                                               (ReLU6)
         conv|fc → requant → add(·, skip) → [relu]
                 → [global_avg_pool] → requant                  (residual)
 
@@ -499,15 +557,19 @@ def linearize(graph: Graph) -> List[Step]:
                 else "gap-min-shift")
 
         # ---- residual continuation: requant feeding exactly one add
-        # whose other operand is already materialized ----
+        # whose other operand is already materialized; or a ReLU6 ----
         step = None
         if not relu and pool is None and len(cons[q.name]) == 1:
-            maybe_add = graph.node(cons[q.name][0])
-            if maybe_add.kind == "add":
-                other = [r for r in maybe_add.inputs if r != q.name]
+            after_q = graph.node(cons[q.name][0])
+            if after_q.kind == "relu6":
+                step = _relu6_step(graph, cons, shapes, node, chain,
+                                   in_value, q_shift, after_q, single,
+                                   shift_of)
+            elif after_q.kind == "add":
+                other = [r for r in after_q.inputs if r != q.name]
                 if len(other) == 1 and other[0] in materialized:
                     step = _residual_step(graph, cons, shapes, node, chain,
-                                          in_value, q_shift, maybe_add,
+                                          in_value, q_shift, after_q,
                                           other[0], single, shift_of)
         if step is None:
             step = Step(name=name, kind=node.kind,
@@ -515,7 +577,8 @@ def linearize(graph: Graph) -> List[Step]:
                         output_value=q.name, weights=node.weights,
                         bias=node.bias, stride=node.stride,
                         padding=node.padding, relu=relu, pool=pool,
-                        requant_shift=q_shift - pool_div)
+                        requant_shift=q_shift - pool_div,
+                        groups=node.groups)
         covered.update(step.node_names)
         materialized.add(step.output_value)
         steps.append(step)
@@ -582,4 +645,41 @@ def _residual_step(graph: Graph, cons, shapes, linear: Node,
                 # the pre-add requant: (x >> q) >> pre == x >> (q + pre)
                 requant_shift=q_shift + branch_pre,
                 residual_source=skip, residual_pre_shift=skip_pre,
-                residual_shift=post_shift - pool_div)
+                post_shift=post_shift - pool_div, groups=linear.groups)
+
+
+def _relu6_step(graph: Graph, cons, shapes, linear: Node, chain: List[str],
+                in_value: str, q_shift: int, relu6: Node, single,
+                shift_of) -> Step:
+    """Fuse ``linear → requant → relu6 → [global_avg_pool → requant]``:
+    the cap clips the requantised value in the accumulator, and a global
+    pool after it sums the clipped values before its own requant."""
+    if relu6.cap is None:
+        raise CompileError("relu6 cap unplanned — run plan_requant",
+                           layer=relu6.name, constraint="requant-planned")
+    chain = chain + [relu6.name]
+    out, pool, post = relu6.name, None, 0
+    if len(cons[relu6.name]) == 1 \
+            and graph.node(cons[relu6.name][0]).kind == "global_avg_pool":
+        gap = graph.node(cons[relu6.name][0])
+        pool_div = _gap_div(shapes[relu6.name])
+        q = graph.node(single(gap.name, "pool result must fuse"))
+        if q.kind != "requant":
+            raise CompileError(
+                f"a global pool must be requantised before any other "
+                f"consumer (found {q.kind} {q.name!r})", layer=gap.name,
+                constraint="requant-required")
+        post = shift_of(q.name)
+        if post < pool_div:
+            raise CompileError(
+                f"requant after a pooled reduction must shift by >= "
+                f"{pool_div} (the fused division), got {post}",
+                layer=q.name, constraint="gap-min-shift")
+        chain += [gap.name, q.name]
+        out, pool, post = q.name, "gap", post - pool_div
+    return Step(name=linear.name, kind=linear.kind, node_names=tuple(chain),
+                input_value=in_value, output_value=out,
+                weights=linear.weights, bias=linear.bias,
+                stride=linear.stride, padding=linear.padding, relu=False,
+                pool=pool, requant_shift=q_shift, post_shift=post,
+                groups=linear.groups, clip=relu6.cap)

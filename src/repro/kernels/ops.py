@@ -6,10 +6,12 @@ its activations (:func:`stage_rows`; the identity for a plain matrix),
 shape padding to block multiples (:func:`gemm_blocks`), the kernel, the
 layer's TensorAlu epilogue where it does not fuse into the kernel
 (:func:`alu_epilogue`, then the int8 commit) and the slice to the rows
-and columns the host reads.  Also here: the one decision of whether a
-kernel runs compiled or interpreted (:func:`pallas_interpret`: compiled
-on the TPU, interpreted only on the CPU the tests run on), and the
-``xla`` legs that call the pure-JAX references in ``kernels/ref.py``.
+and columns the host reads.  A depthwise layer runs the same program with
+``vta_dwconv`` in the GEMM's place (``kernels/vta_dwconv.py``).  Also
+here: the one decision of whether a kernel runs compiled or interpreted
+(:func:`pallas_interpret`: compiled on the TPU, interpreted only on the
+CPU the tests run on), and the ``xla`` legs that call the pure-JAX
+references in ``kernels/ref.py``.
 """
 
 from __future__ import annotations
@@ -26,6 +28,7 @@ from repro.trace import span
 
 from . import ref as _ref
 from .flash_attention import flash_attention as _flash
+from .vta_dwconv import dw_blocks, vta_dwconv as _vta_dwconv
 from .vta_gemm import vta_gemm as _vta_gemm
 
 _BACKENDS = ("auto", "pallas", "xla")
@@ -288,7 +291,7 @@ def staged_vta_matmul(x: jax.Array, gather: Optional[jax.Array],
                       relu: bool = False, shift: int = 0,
                       saturate: bool = True, out_dtype=jnp.int8,
                       valid=None, preload=None, res=None, keep=None,
-                      epilogue=None, tables=(),
+                      epilogue=None, tables=(), depthwise: bool = False,
                       block_m: int = 256, block_n: int = 256,
                       block_k: int = 256) -> jax.Array:
     """The fused GEMM of one served layer, its input staged on the device
@@ -300,53 +303,72 @@ def staged_vta_matmul(x: jax.Array, gather: Optional[jax.Array],
     :class:`AluStep`, or None) runs on the kernel's int32 result with the
     ``preload``, the skip activations ``res`` and the index ``tables``, and
     the call then commits int8.  The pallas leg only, compiled on the TPU
-    and interpreted on the CPU (:func:`pallas_interpret`)."""
+    and interpreted on the CPU (:func:`pallas_interpret`).
+
+    ``depthwise``: ``b`` is a depthwise conv's ``(kh·kw, C)`` taps and
+    ``vta_dwconv`` runs in ``vta_gemm``'s place, ``bias`` its own, the
+    epilogue (a tuple, maybe empty) after it; ``x`` is the conv's map and
+    ``cols`` its tap count."""
     m, n = x.shape[0] * rows, b.shape[1]
     if b.shape[0] != cols:
         raise CompileError(
             f"staged GEMM input of {cols} columns @ {tuple(b.shape)}",
             constraint="kernel-gemm-shape")
+    kw = dict(conv=conv, rows=rows, cols=cols, relu=relu, shift=shift,
+              saturate=saturate, out_dtype=out_dtype,
+              valid=valid or (rows, n), epilogue=epilogue,
+              depthwise=depthwise, block_m=block_m, block_n=block_n,
+              block_k=block_k, interpret=pallas_interpret())
+    operands = (x, gather, b, bias, preload, res, keep, *tables)
+    if depthwise:
+        bsz, c, h, w = x.shape
+        d = dw_blocks(bsz, c, h, w, *conv)
+        with span("vta.kernel.dispatch", issued_macs=d.issued_macs,
+                  a_bytes=x.size, alu_ops=len(epilogue)), \
+                span("vta.kernel.dwconv",
+                     useful_macs=bsz * d.oh * d.ow * cols * c,
+                     issued_macs=d.issued_macs, bytes=d.bytes):
+            return _staged_vta_gemm(*operands, **kw)
     g = gemm_blocks(m, cols, n, block_m=block_m, block_n=block_n,
                     block_k=block_k)
     with span("vta.kernel.dispatch", issued_macs=g.m * g.k * g.n,
               a_bytes=m * cols, alu_ops=len(epilogue or ())):
-        return _staged_vta_gemm(x, gather, b, bias, preload, res, keep,
-                                *tables, conv=conv, rows=rows, cols=cols,
-                                relu=relu, shift=shift, saturate=saturate,
-                                out_dtype=out_dtype,
-                                valid=valid or (rows, n), epilogue=epilogue,
-                                block_m=block_m, block_n=block_n,
-                                block_k=block_k,
-                                interpret=pallas_interpret())
+        return _staged_vta_gemm(*operands, **kw)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("conv", "rows", "cols", "relu", "shift", "saturate",
-                     "out_dtype", "valid", "epilogue", "block_m", "block_n",
-                     "block_k", "interpret"))
+                     "out_dtype", "valid", "epilogue", "depthwise",
+                     "block_m", "block_n", "block_k", "interpret"))
 def _staged_vta_gemm(x, gather, b, bias, preload, res, keep, *tables, conv,
                      rows, cols, relu, shift, saturate, out_dtype, valid,
-                     epilogue, block_m, block_n, block_k, interpret):
+                     epilogue, depthwise, block_m, block_n, block_k,
+                     interpret):
     """:func:`staged_vta_matmul` as one program: stage A, pad it to
-    :func:`gemm_blocks`, run ``vta_gemm``, the epilogue and the int8
-    commit, keep the valid rows and columns.  One trip into the runtime
-    per call: each op run on its own would be another.  Its module
-    (``jit__staged_vta_gemm``) names ``vta_gemm``, which is how a device
-    trace finds the kernel's time (the staging and the epilogue
-    included)."""
-    a = stage_rows(x, gather, conv=conv, rows=rows, cols=cols)
-    m, n = a.shape[0], b.shape[1]
-    g = gemm_blocks(m, cols, n, block_m=block_m, block_n=block_n,
-                    block_k=block_k)
-    a_p = jnp.pad(a, ((0, g.m - m), (0, g.k - cols)))
-    b_p = jnp.pad(b, ((0, g.k - cols), (0, g.n - n)))
-    bias_p = jnp.pad(bias, (0, g.n - n)) if bias is not None else None
-    out = _vta_gemm(a_p, b_p, bias_p, relu=relu, shift=shift,
-                    saturate=saturate, out_dtype=out_dtype,
-                    block_m=g.block_m, block_n=g.block_n, block_k=g.block_k,
-                    interpret=interpret)
-    out = out[:m, :n].reshape(x.shape[0], rows, n)
+    :func:`gemm_blocks`, run ``vta_gemm`` (or ``vta_dwconv`` on the map,
+    for a depthwise layer), the epilogue and the int8 commit, keep the
+    valid rows and columns.  One trip into the runtime per call: each op
+    run on its own would be another.  Its module (``jit__staged_vta_gemm``)
+    names ``vta_gemm``, which is how a device trace finds the kernels'
+    time (the staging and the epilogue included)."""
+    n = b.shape[1]
+    if depthwise:
+        out = _vta_dwconv(x, b, bias, conv=conv, interpret=interpret)
+        out = jnp.pad(out, ((0, 0), (0, rows - out.shape[1]), (0, 0)))
+    else:
+        a = stage_rows(x, gather, conv=conv, rows=rows, cols=cols)
+        m = a.shape[0]
+        g = gemm_blocks(m, cols, n, block_m=block_m, block_n=block_n,
+                        block_k=block_k)
+        a_p = jnp.pad(a, ((0, g.m - m), (0, g.k - cols)))
+        b_p = jnp.pad(b, ((0, g.k - cols), (0, g.n - n)))
+        bias_p = jnp.pad(bias, (0, g.n - n)) if bias is not None else None
+        out = _vta_gemm(a_p, b_p, bias_p, relu=relu, shift=shift,
+                        saturate=saturate, out_dtype=out_dtype,
+                        block_m=g.block_m, block_n=g.block_n,
+                        block_k=g.block_k, interpret=interpret)
+        out = out[:m, :n].reshape(x.shape[0], rows, n)
     if epilogue is not None:
         out = alu_epilogue(out, preload, res, tables, steps=epilogue)
     vm, vn = valid

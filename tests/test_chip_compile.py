@@ -19,7 +19,10 @@ ResNet-50's calls at the benchmark's rungs 1, 2, 4 and 8 are compiled too,
 as the program serving runs; they are derived from its layer shapes and the
 configuration's shifts (:func:`_resnet50_calls`), so that the whole net
 is never compiled on the CPU, and the derivation is checked against the
-calls a small compiled ResNet-50 makes.
+calls a small compiled ResNet-50 makes.  So are two of MobileNetV2's
+depthwise calls at published widths (stride 1 and stride 2, rungs 1 and
+8), which run ``vta_dwconv`` in the same program
+(:func:`_mobilenet_dw_call`).
 """
 
 import json
@@ -294,3 +297,54 @@ def test_resnet50_staged_vta_gemm_compiles_for_v5e(rung, one_chip):
         compiled = ops._staged_vta_gemm.lower(*args, interpret=False,
                                               **statics).compile()
         assert "tpu_custom_call" in compiled.as_text(), call
+
+
+def _mobilenet_dw_call(layer, shift, cap, rung, bs=16):
+    """The kernel call a pallas serve of ``rung`` images runs for one
+    MobileNetV2 depthwise layer (``models/mobilenet_v2.py
+    linear_layers``) from its shape, requant shift and ReLU6 cap: the map
+    goes up, ``vta_dwconv`` runs over its (9, C) taps with the bias, and
+    the epilogue requantises and clips (SHR, MAX 0, MIN cap)."""
+    size, c, s = layer["in_size"], layer["in"], layer["stride"]
+    out = (size - 1) // s + 1
+    m = out * out
+    rows = _round_up(m, bs) if m > 1 else 1
+    alu = ([AluImmOp.shr(shift)] if shift else []) + [
+        AluImmOp.relu(), AluImmOp(isa.AluOp.MIN, cap)]
+    return KernelCall(x=(rung, c, size, size), conv=(3, 3, s, 1), gather=0,
+                      rows=rows, k=9, n=c, valid=(m, c), bias=True,
+                      relu=False, shift=0, out_dtype=jnp.int32,
+                      depthwise=True, **_epilogue(alu, rows, c))
+
+
+def test_mobilenet_dw_calls_from_shapes_are_what_the_program_compiles():
+    from repro.models import mobilenet_v2 as mb
+    geometry = dict(width=0.5, image_size=32)
+    net, graph = mb.compile_mobilenet_v2(**geometry)
+    params = mb.graph_params(graph)
+    layers = {l["name"]: l for l in mb.linear_layers(**geometry)}
+    for layer in net.layers:
+        if layer.spec.groups == 1:
+            continue
+        name = layer.spec.name
+        assert kernel_call(layer, 8) == _mobilenet_dw_call(
+            layers[name], params[f"{name}_q"], params[f"{name}_r6"], 8)
+
+
+@pytest.mark.parametrize("rung", (1, 8))
+@pytest.mark.parametrize("name", ("b3dw", "b2dw"))   # stride 1, stride 2
+def test_mobilenet_dw_call_compiles_for_v5e(name, rung, one_chip):
+    """Published widths: b3dw is 144 channels at 56×56, stride 1; b2dw 96
+    channels at 112×112, stride 2 — the largest map of the net."""
+    cfg = json.loads((Path(__file__).resolve().parents[1] / "bench" /
+                      "configs" / "mobilenetv2.json").read_text())
+    layer, = [l for l in cfg["layers"] if l["name"] == name]
+    call = _mobilenet_dw_call(layer, 7, 48, rung)
+    args, statics = call.matmul_args(sharding=one_chip)
+    text = ops._staged_vta_gemm.lower(*args, interpret=False, **statics) \
+        .compile().as_text()
+    module, = re.findall(r"^HloModule (\S+),", text, re.M)
+    assert "vta_gemm" in module, module
+    assert re.search(r"%vta_dwconv\.\d+ = [^\n]*custom-call\([^\n]*"
+                     r"tpu_custom_call", text)
+    assert "%vta_gemm." not in text

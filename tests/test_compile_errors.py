@@ -196,7 +196,7 @@ def test_residual_pairing_diagnostics():
     w = rng.integers(-4, 5, (4, 2, 3, 3)).astype(np.int8)
     t = rng.integers(-32, 33, (1, 2, 8, 8)).astype(np.int8)
     res_spec = LayerSpec("r", "conv", w, padding=1, requant_shift=8,
-                         residual_add=True, residual_shift=1)
+                         residual_add=True, post_shift=1)
     _raises("residual-operand-missing", compile_layer, res_spec, t)
     _raises("residual-no-pool", compile_layer,
             LayerSpec("r", "conv", w, padding=1, pool="max2x2",
@@ -207,3 +207,13 @@ def test_residual_pairing_diagnostics():
             residual=np.zeros((1, 4, 8, 8), np.int8))
     _raises("residual-shape", compile_layer, res_spec, t,
             residual=np.zeros((1, 4, 4, 4), np.int8))
+
+
+def test_band_gemm_refuses_a_matrix_that_is_not_banded():
+    rng = np.random.default_rng(19)
+    A = rng.integers(-8, 8, (16, 9 * 32)).astype(np.int8)
+    B = np.zeros((9 * 32, 32), np.int8)
+    B[0, 31] = 1                # a weight of column block 1 in block 0's band
+    _raises("gemm-band-weights", compile_matmul, A, B, band=9)
+    _raises("gemm-band-shape", compile_matmul, A[:, :8 * 32], B[:8 * 32],
+            band=9)

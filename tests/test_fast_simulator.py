@@ -446,3 +446,30 @@ def test_lenet5_chain_fast_backend():
     assert [r.dram_bytes_total for r in reps_o] == \
         [r.dram_bytes_total for r in reps_f]
     net.verify(backend="fast")
+
+
+@pytest.mark.parametrize("channels, rows, cfg", [
+    (48, 100, vta_default()),
+    (64, 40, VTAConfig(inp_buff_vectors=512, wgt_buff_matrices=32,
+                       acc_buff_vectors=64, out_buff_vectors=64,
+                       uop_buff_entries=128))])
+def test_band_programs_run_identically(channels, rows, cfg):
+    """A grouped GEMM (a depthwise conv's band, ``compile_matmul(band=9)``)
+    is ordinary VTA instructions: both backends agree with the oracle,
+    and it issues the band's GEMM loops alone."""
+    from repro.core.conv_lowering import depthwise_ker2col
+    rng = np.random.default_rng(channels)
+    A = rng.integers(-128, 128, (rows, 9 * channels)).astype(np.int8)
+    W = rng.integers(-8, 9, (channels, 1, 3, 3)).astype(np.int8)
+    B = depthwise_ker2col(W)
+    bias = rng.integers(-500, 500, channels).astype(np.int32)
+    ops = [AluImmOp.shr(5), AluImmOp.relu(), AluImmOp(isa.AluOp.MIN, 48)]
+    prog = compile_matmul(A, B, bias=bias, alu_ops=ops, cfg=cfg, band=9)
+    rep = assert_backends_identical(prog)
+    verify_program(prog, backend="oracle")
+    verify_program(prog, backend="fast")
+    plan = prog.chunk_plan
+    assert rep.gemm_loops == plan.alpha * plan.row_height * 9 * plan.beta
+    dense = compile_matmul(A, B, bias=bias, alu_ops=ops, cfg=cfg)
+    assert dense.gemm_loops() == rep.gemm_loops * plan.beta
+    np.testing.assert_array_equal(prog.expected_out, dense.expected_out)

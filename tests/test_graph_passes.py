@@ -207,7 +207,7 @@ def test_linearize_respects_data_dependencies_and_covers_every_node():
     res = [s for s in steps if s.residual_source is not None]
     assert [s.name for s in res] == ["b1b"]
     assert res[0].residual_source == "s1_q"
-    assert res[0].relu and res[0].residual_shift is not None
+    assert res[0].relu and res[0].post_shift is not None
 
 
 def test_linearize_folds_branch_pre_shift_into_requant():

@@ -112,3 +112,47 @@ def test_staged_call_is_one_jitted_call_with_one_pallas_call(conv):
     assert "vta_gemm" in eqn.params["name"], eqn.params["name"]
     assert _count(closed.jaxpr, "pallas_call") == 1
     assert eqn.outvars[0].aval.shape == (2 * rows, 6)
+
+
+@pytest.mark.parametrize("channels, size, stride", [
+    (48, 9, 1), (200, 10, 2), (130, 7, 1), (32, 8, 2)])
+def test_vta_dwconv_matches_a_jnp_depthwise_conv(channels, size, stride):
+    """``vta_dwconv`` (interpreted) against ``conv_general_dilated`` with
+    one feature group per channel, at strides 1 and 2 and at channel
+    counts that are not a multiple of the 128 lanes; int8 extremes, so
+    every product and sum is exercised."""
+    from repro.kernels.vta_dwconv import vta_dwconv
+    rng = np.random.default_rng(channels + stride)
+    x = rng.integers(-128, 128, (3, channels, size, size), dtype=np.int8)
+    taps = rng.integers(-128, 128, (9, channels), dtype=np.int8)
+    bias = rng.integers(-2**20, 2**20, channels, dtype=np.int32)
+    got = jax.jit(lambda x, t, b: vta_dwconv(
+        x, t, b, conv=(3, 3, stride, 1), interpret=True))(x, taps, bias)
+    w = jnp.asarray(taps.T.reshape(channels, 1, 3, 3), jnp.int32)
+    want = jax.lax.conv_general_dilated(
+        jnp.asarray(x, jnp.int32), w, (stride, stride), ((1, 1), (1, 1)),
+        dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        feature_group_count=channels) + bias[None, :, None, None]
+    want = np.asarray(want).transpose(0, 2, 3, 1).reshape(3, -1, channels)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_depthwise_call_is_one_jitted_call_with_no_vta_gemm():
+    """A depthwise layer's call: the same jitted program (its module still
+    named for ``vta_gemm``), holding ``vta_dwconv`` as its one
+    ``pallas_call`` and no MXU kernel."""
+    x = jnp.zeros((2, 32, 8, 8), jnp.int8)
+    taps = jnp.zeros((9, 32), jnp.int8)
+    bias = jnp.zeros((32,), jnp.int32)
+    closed = jax.make_jaxpr(
+        lambda x, t, b: ops.staged_vta_matmul(
+            x, None, t, b, conv=(3, 3, 2, 1), rows=16, cols=9,
+            saturate=False, out_dtype=jnp.int32, valid=(16, 32),
+            epilogue=(), depthwise=True))(x, taps, bias)
+    eqn, = closed.jaxpr.eqns
+    assert "vta_gemm" in eqn.params["name"], eqn.params["name"]
+    assert _count(closed.jaxpr, "pallas_call") == 1
+    names = str(closed.jaxpr)
+    assert "vta_dwconv" in names and "name=vta_gemm" not in names
+    assert eqn.outvars[0].aval.shape == (32, 32)

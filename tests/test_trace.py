@@ -233,3 +233,39 @@ def test_engine_spans_match_the_request_records(traced):
     worker, = [roots for roots in threads
                if any(r.name == "engine.execute" for r in roots)]
     assert "engine.batch_form" in {r.name for r in worker}
+
+
+def test_depthwise_calls_record_their_own_span_inside_the_dispatch(tmp_path):
+    """A depthwise layer's call (``vta_dwconv``) records
+    ``vta.kernel.dwconv`` inside ``vta.kernel.dispatch``, with the layer's
+    useful multiply-adds per row, what the kernel issues (channels padded
+    to the 128 lanes; also the dispatch's ``issued_macs``) and the bytes
+    it moves."""
+    import numpy as np
+    from repro.graph import GraphBuilder, compile_graph
+    from repro.kernels.vta_dwconv import dw_blocks
+    rng = np.random.default_rng(3)
+    bld = GraphBuilder("dw")
+    x = bld.input("x", shape=(1, 48, 9, 9))
+    v = bld.requant("dw_q", bld.conv(
+        "dw", x, rng.integers(-4, 5, (48, 1, 3, 3)).astype(np.int8),
+        stride=2, padding=1, groups=48))
+    bld.output(bld.relu6("dw_r6", v))
+    images = [rng.integers(-64, 64, (1, 48, 9, 9)).astype(np.int8)
+              for _ in range(3)]
+    net = compile_graph(bld.build(), images[0], calib=images)
+    net.serve(images, backend="pallas")
+    with jax.profiler.trace(str(tmp_path)):
+        net.serve(images, backend="pallas")
+    path, = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    roots = [r for t in _threads(path) for r in t]
+    dispatch, = [n for r in roots for n in r.walk()
+                 if n.name == "vta.kernel.dispatch"]
+    dw, = dispatch.children
+    want = dw_blocks(3, 48, 9, 9, 3, 3, 2, 1)
+    assert dw.name == "vta.kernel.dwconv"
+    assert dw.stats["useful_macs"] == 3 * net.layers[0].macs \
+        == 3 * 25 * 9 * 48
+    assert dw.stats["issued_macs"] == dispatch.stats["issued_macs"] \
+        == want.issued_macs == 3 * 25 * 9 * 128
+    assert dw.stats["bytes"] == want.bytes
